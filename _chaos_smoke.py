@@ -39,7 +39,7 @@ def _free_port() -> int:
 
 def _spawn_server(port: int, ckdir: str, hostmap: str,
                   journal_dir: str = ""):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GYT_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     cmd = [sys.executable, "-m", "gyeeta_tpu", "serve",
            "--host", "127.0.0.1", "--port", str(port),
            "--checkpoint-dir", ckdir, "--hostmap", hostmap,

@@ -22,8 +22,8 @@ subprocesses, one replica behind a wedge-capable chaos proxy):
   uninterrupted control subscription on the serve tier.
 
 **Phase B — process tier under combined load** (a REAL ``serve
---shards 2 --ingest-procs 2`` subprocess, fresh scoped XLA cache, the
-PR-12 subprocess methodology):
+--shards 2 --ingest-procs 2`` subprocess, the PR-12 subprocess
+methodology):
 
 - ingest worker SIGKILL mid-feed (targeted from OUTSIDE via the new
   ``gyt_ingest_proc_pid`` gauge) while a subscription streams: the
@@ -420,12 +420,11 @@ N_SHARDS = 2
 N_PROCS = 2
 
 
-def _serve_env(tmp, cache="xla_serve"):
+def _serve_env():
     return dict(
-        os.environ, JAX_PLATFORMS="cpu", GYT_PLATFORM="cpu",
+        os.environ, JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count="
                   f"{N_SHARDS}",
-        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, cache),
         GYT_N_HOSTS="16", GYT_SVC_CAPACITY="256",
         GYT_TASK_CAPACITY="256", GYT_CONN_BATCH="256",
         GYT_RESP_BATCH="512", GYT_LISTENER_BATCH="64", GYT_FOLD_K="2",
@@ -446,7 +445,7 @@ async def phase_b(tmp: str) -> None:
 
     port = _free_port()
     waldir = os.path.join(tmp, "wal")
-    env = _serve_env(tmp)
+    env = _serve_env()
     cmd = [sys.executable, "-m", "gyeeta_tpu", "serve",
            "--host", "127.0.0.1", "--port", str(port),
            "--shards", str(N_SHARDS), "--ingest-procs", str(N_PROCS),
@@ -460,9 +459,8 @@ async def phase_b(tmp: str) -> None:
     tasks: list = []
 
     async def query(req, deadline_s=300.0):
-        # fresh conn per call, retried against a DEADLINE: the
-        # fresh-cache serve loop blocks for minutes at a stretch
-        # while mesh programs compile on a contended 1-core box, so
+        # fresh conn per call, retried against a DEADLINE: a cold-
+        # cache serve loop blocks while mesh programs compile, so
         # individual requests time out without anything being wrong
         # — a shared conn would also desync after the first timeout
         last = None
@@ -634,13 +632,13 @@ async def phase_b(tmp: str) -> None:
         base = [sys.executable, "-m", "gyeeta_tpu", "compact", "run",
                 "--journal-dir", waldir, "--shard-dir", shdir,
                 "--procs", str(N_PROCS), "--window-ticks", "4"]
-        env_die = dict(_serve_env(tmp, cache="xla_c1"),
+        env_die = dict(_serve_env(),
                        GYT_COMPACT_DIE_SHARD="1")
         r = subprocess.run(base, cwd=HERE, env=env_die,
                            capture_output=True, timeout=600)
         assert r.returncode != 0, \
             "compaction worker death did not fail the pass loudly"
-        env_ok = _serve_env(tmp, cache="xla_c2")
+        env_ok = _serve_env()
         r2 = subprocess.run(base, cwd=HERE, env=env_ok,
                             capture_output=True, timeout=600)
         assert r2.returncode == 0, r2.stderr[-2000:]

@@ -46,10 +46,9 @@ def _free_port() -> int:
 
 def _spawn_server(port: int, tmp: str):
     env = dict(
-        os.environ, JAX_PLATFORMS="cpu", GYT_PLATFORM="cpu",
+        os.environ, JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count="
                   f"{N_SHARDS}",
-        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "xla_cache"),
         GYT_N_HOSTS="16", GYT_SVC_CAPACITY="256",
         GYT_TASK_CAPACITY="256", GYT_CONN_BATCH="256",
         GYT_RESP_BATCH="512", GYT_LISTENER_BATCH="64", GYT_FOLD_K="2",

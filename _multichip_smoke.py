@@ -43,13 +43,9 @@ def _free_port() -> int:
 
 def _spawn_server(port: int, tmp: str):
     env = dict(
-        os.environ, JAX_PLATFORMS="cpu", GYT_PLATFORM="cpu",
+        os.environ, JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count="
                   f"{N_SHARDS}",
-        # fresh per-run compile cache: RELOADING a cached shard_map
-        # executable is broken on the 0.4.x jaxlib line (see
-        # tests/conftest.py) — an always-cold scoped dir never reloads
-        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "xla_cache"),
         # small mesh geometry: smoke compiles must stay in CI budget
         GYT_N_HOSTS="16", GYT_SVC_CAPACITY="256",
         GYT_TASK_CAPACITY="256", GYT_CONN_BATCH="256",

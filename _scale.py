@@ -571,9 +571,7 @@ def _phase_mproc() -> dict:
             GYT_SCALE_MPROC_CHILD="1", GYT_SCALE_MPROC_LEGS=n,
             GYT_SCALE_MPROC_SLOT=str(slot_i),
             GYT_SCALE_MPROC_CRASH=(
-                "1" if int(n) >= 4 and not crash_done else "0"),
-            JAX_COMPILATION_CACHE_DIR=tempfile.mkdtemp(
-                prefix="gyt_mproc_xla_"))
+                "1" if int(n) >= 4 and not crash_done else "0"))
         if int(n) >= 4 and not crash_done:
             crash_done = True
         t0 = time.time()
@@ -1099,14 +1097,10 @@ def _phase_million() -> dict:
 def _run_phase_subproc(phase: str) -> dict:
     env = dict(
         os.environ, GYT_SCALE_PHASE=phase,
-        JAX_PLATFORMS="cpu", GYT_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                    + " --xla_force_host_platform_device_count="
-                   f"{N_SHARDS}").strip(),
-        # always-cold scoped compile cache: reloading cached shard_map
-        # executables is broken on 0.4.x (tests/conftest.py)
-        JAX_COMPILATION_CACHE_DIR=tempfile.mkdtemp(
-            prefix="gyt_scale_xla_"))
+                   f"{N_SHARDS}").strip())
     t0 = time.time()
     try:
         r = subprocess.run([sys.executable, __file__], env=env,

@@ -150,13 +150,6 @@ class Compactor:
                 fixed = [jax.device_put(a, r.sharding)
                          if hasattr(r, "sharding") else a
                          for a, r in zip(fixed, refs)]
-            else:
-                # commit to the device BEFORE the donating folds see
-                # the state: a numpy-leaf pytree through a cache-
-                # reloaded donating executable aborts on the 0.4.x
-                # jaxlib line (layout resolution — same bug family
-                # conftest documents for shard_map reloads)
-                fixed = [jax.device_put(a) for a in fixed]
             return jax.tree_util.tree_unflatten(treedef, fixed)
 
         rt.state = unflatten(data["state"], rt.state)

@@ -64,13 +64,23 @@ class _NullStats:
         return contextlib.nullcontext()
 
 
+def _pin_cpu_backend() -> None:
+    """The serving process owns the chip; a replay worker that opened
+    it would fail or hang. Unpickling the worker's ``cfg`` argument has
+    already imported jax, which read ``JAX_PLATFORMS`` then — so the
+    backend is pinned through jax's config (no backend exists yet),
+    and the variable is assigned for whatever this worker starts."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _part_group_worker(cfg, opts, jobs, upto_tick, q) -> None:
     """One worker process: replay each assigned WAL shard through a
     per-shard Compactor (sequentially — parallelism is ACROSS
-    workers). Runs in a fresh interpreter; force the CPU backend
-    before jax loads so a TPU-serving host never has its devices
-    claimed by replay workers."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    workers). Runs in a fresh (spawned) interpreter, on the CPU
+    backend whatever the parent runs on."""
+    _pin_cpu_backend()
     import resource
     import traceback
 

@@ -390,6 +390,7 @@ class FabricGateway:
         self._peer_conns: dict = {}       # (h,p) -> [reader,writer,lock]
         self._rr = 0
         self._server = None
+        self._open_conns: set = set()  # accepted conns (stop() closes)
         self._tasks: list = []
         # (tick, key) -> ["ok", resp, body|None] | ["neg", msg, expiry]
         self._cache: OrderedDict = OrderedDict()
@@ -441,6 +442,13 @@ class FabricGateway:
         self._hub_relays.clear()
         if self._server is not None:
             self._server.close()
+            # force-close accepted conns BEFORE wait_closed: since
+            # Python 3.12.1 it waits for every live conn, and a
+            # stopping gateway must not wait on peers and subscribers
+            # that never hang up (two peered gateways would wait on
+            # each other for ever) — the net/server.py discipline
+            for w in list(self._open_conns):
+                w.close()
             await self._server.wait_closed()
             self._server = None
         for u in self.upstreams:
@@ -1173,6 +1181,7 @@ class FabricGateway:
 
     # ---------------------------------------------------------- the fronts
     async def _handle(self, reader, writer) -> None:
+        self._open_conns.add(writer)
         try:
             try:
                 first = await asyncio.wait_for(reader.readexactly(4),
@@ -1193,6 +1202,7 @@ class FabricGateway:
         except Exception:           # pragma: no cover — keep serving
             log.exception("gateway conn failed")
         finally:
+            self._open_conns.discard(writer)
             self.subs.unsubscribe_conn(writer)
             writer.close()
             try:

@@ -622,9 +622,10 @@ class IngestSupervisor:
         env["PYTHONPATH"] = pkg_parent + (
             os.pathsep + env["PYTHONPATH"]
             if env.get("PYTHONPATH") else "")
-        # the worker never touches jax — make sure a TPU-pinning env
-        # can't make N workers grab the accelerator runtime
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # one process owns the chip, and it is the supervisor: whatever
+        # JAX_PLATFORMS it was started with, a worker (which needs no
+        # jax) can never open the accelerator runtime
+        env["JAX_PLATFORMS"] = "cpu"
         h.proc = subprocess.Popen(
             [sys.executable, "-m", "gyeeta_tpu.net.ingestproc",
              "--ctrl-fd", str(child_fd), "--cfg", json.dumps(cfg)],

@@ -566,6 +566,13 @@ class GytServer:
                     self.watchdog.beat()      # liveness heartbeat
             except Exception:                     # pragma: no cover
                 log.exception("tick failed")
+                # counted, with what the devices held when it failed
+                # (an allocation that did not fit shows here): a tick
+                # that fails every 5 s must not only scroll past
+                from gyeeta_tpu.obs import xlamon
+                self.rt.stats.bump("tick_errors")
+                for k, v in xlamon.device_gauges().items():
+                    self.rt.stats.gauge(k, v)
 
     # ------------------------------------------------- admission control
     def throttle_level(self) -> int:

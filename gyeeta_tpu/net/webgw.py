@@ -50,6 +50,7 @@ class WebGateway:
         self.upstream = (upstream_host, upstream_port)
         self.host, self.port = host, port
         self._server = None
+        self._open_conns: set = set()  # accepted conns (stop() closes)
         self._qc: Optional[QueryClient] = None
         self._lock = asyncio.Lock()
         # GIL-relief JSON encode tier (GYT_QUERY_PROCS, net/qexec.py):
@@ -68,6 +69,10 @@ class WebGateway:
     async def stop(self) -> None:
         if self._server:
             self._server.close()
+            # since Python 3.12.1 wait_closed waits for every live
+            # conn: close keep-alive clients instead of waiting on them
+            for w in list(self._open_conns):
+                w.close()
             await self._server.wait_closed()
             self._server = None
         if self._qc is not None:
@@ -101,6 +106,7 @@ class WebGateway:
 
     # ------------------------------------------------------------ http
     async def _handle(self, reader, writer) -> None:
+        self._open_conns.add(writer)
         try:
             while True:
                 try:
@@ -149,6 +155,7 @@ class WebGateway:
         except (ConnectionError, OSError):
             pass
         finally:
+            self._open_conns.discard(writer)
             writer.close()
             try:
                 await writer.wait_closed()

@@ -544,9 +544,7 @@ def mesh_clusters(es: EdgeSet, node_capacity: int, n_iters: int = 16):
     return ntbl, labels, sizes
 
 
-# Process-wide compiled-builder memo (see sharded.memo_sharded: also a
-# 0.4.x persistent-cache-reload correctness fix — the dep-graph a2a
-# programs were exactly the ones that came back with broken layouts).
+# Process-wide compiled-builder memo (see sharded.memo_sharded).
 from gyeeta_tpu.parallel.sharded import memoize_builder as _memoize  # noqa: E402
 
 dep_step_fn = _memoize(dep_step_fn)

@@ -17,22 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 HOST_AXIS = "hosts"
 SLICE_AXIS = "slices"    # DCN axis of a multi-slice mesh (outer)
 
-if not hasattr(jax, "shard_map"):
-    # Older jax (<0.6) only ships shard_map under jax.experimental and
-    # spells the replication check ``check_rep`` (renamed ``check_vma``
-    # later). Every sharded tier entry point imports this module to
-    # build its Mesh, so installing the translated alias here keeps
-    # the call sites on the one current spelling.
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _exp_shard_map(f, **kw)
-
-    jax.shard_map = _shard_map_compat
-
-
 def make_mesh(n_devices: int | None = None) -> Mesh:
     """1-D mesh over the first ``n_devices`` local devices (default: all)."""
     devs = jax.devices()

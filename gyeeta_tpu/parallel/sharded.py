@@ -36,16 +36,9 @@ def mesh_key(mesh) -> tuple:
 
 def memo_sharded(key: tuple, make):
     """Process-wide compiled-function memo for the mesh tier (the
-    sharded twin of ``runtime._memo_jit``). Beyond the compile-time
-    win, this is a CORRECTNESS fix on the 0.4.x jaxlib line: a second
-    ShardedRuntime with identical geometry used to re-trace the same
-    shard_map program, HIT the persistent XLA cache entry written
-    minutes earlier by the first instance, and the reloaded executable
-    came back with broken layouts — the long-standing "a2a rollup"
-    garbage-value failure (negative collective sums, NaN health
-    counters) that only reproduced when two mesh runtimes shared a
-    process. Sharing the in-memory executable means the program is
-    never re-traced, so the broken reload path is never taken."""
+    sharded twin of ``runtime._memo_jit``): a second ShardedRuntime
+    with identical geometry shares the first one's traced and compiled
+    programs instead of re-tracing the whole fold family."""
     fn = _MESH_MEMO.get(key)
     if fn is None:
         fn = _MESH_MEMO[key] = make()
@@ -322,9 +315,7 @@ def age_apis_sharded(cfg: aggstate.EngineCfg, mesh, max_age_ticks: int):
 def memoize_builder(builder):
     """Route a compiled-program builder ``f(cfg?, mesh, extras...)``
     through the process-wide memo (every arg must be hashable; Mesh
-    args key by geometry). Used below and by ``depgraph``/``rollup`` —
-    see :func:`memo_sharded` for why this is also a 0.4.x correctness
-    fix, not just a compile-time saving."""
+    args key by geometry). Used below and by ``depgraph``/``rollup``."""
     from jax.sharding import Mesh
 
     def wrapper(*args, **kwargs):

@@ -902,6 +902,38 @@ def columns_for(cfg: EngineCfg, st: AggState, subsys: str, names=None,
     return _COLUMNS_OF[subsys](cfg, st, names=names)
 
 
+def serverstatus_columns(rt, tick: int, nhosts: int, nsvc: float):
+    """serverstatus subsystem (ref madhavastatus): one-row self status
+    — shared by both runtimes and the snapshot tier, which differ only
+    in where ``tick`` / ``nhosts`` / ``nsvc`` are read from. The event
+    counters are the exact host-side ints (the () f32 device scalars
+    lose increments past ~2^24 events); ``platform`` / ``devicekind`` /
+    ``ndevices`` are the backend jax took, as jax reports it."""
+    from gyeeta_tpu import version as V
+    from gyeeta_tpu.obs.xlamon import device_info
+
+    c = rt.stats.counters
+    dev = device_info()
+    obj = lambda v: np.array([v], object)             # noqa: E731
+    num = lambda v: np.array([float(v)], np.float64)  # noqa: E731
+    cols = {
+        "uptime": num(rt._clock() - rt._t_started),
+        "tick": num(tick),
+        "nhosts": num(nhosts),
+        "nsvc": num(nsvc),
+        "connevents": num(c.get("conn_events", 0)),
+        "respevents": num(c.get("resp_events", 0)),
+        "queries": num(c.get("queries", 0)),
+        "alertsfired": num(rt.alerts.stats.get("nfired", 0)),
+        "wirever": num(V.CURR_WIRE_VERSION),
+        "version": obj(V.__version__),
+        "platform": obj(dev["platform"]),
+        "devicekind": obj(dev["devicekind"]),
+        "ndevices": num(dev["ndevices"]),
+    }
+    return cols, np.ones(1, bool)
+
+
 # process-local subsystems answered by the runtime itself (no engine
 # columns): self-metrics readback + Prometheus exposition. Shared by
 # Runtime and ShardedRuntime so the two surfaces cannot drift.

@@ -481,6 +481,9 @@ SERVERSTATUS_FIELDS = (
     num("alertsfired", "alertsfired", "Alerts notified"),
     num("wirever", "wirever", "Wire protocol version"),
     string("version", "version", "Server version"),
+    string("platform", "platform", "JAX backend platform in use"),
+    string("devicekind", "devicekind", "JAX device kind in use"),
+    num("ndevices", "ndevices", "Devices the JAX backend reports"),
 )
 
 # ------------------------------------------------------------ trace defs

@@ -275,28 +275,14 @@ class EngineSnapshot:
         return cols, np.ones(len(seen), bool)
 
     def _serverstatus_columns(self):
-        from gyeeta_tpu import version as V
         rt = self.rt
-        c = rt.stats.counters
-        obj = lambda v: np.array([v], object)             # noqa: E731
-        num = lambda v: np.array([float(v)], np.float64)  # noqa: E731
         if hasattr(rt, "_rollup"):                   # ShardedRuntime
             nsvc = float(np.asarray(rt._rollup(self.state).n_svc_live))
         else:
             nsvc = float(np.asarray(self.state.tbl.n_live))
-        cols = {
-            "uptime": num(rt._clock() - rt._t_started),
-            "tick": num(self.tick),
-            "nhosts": num(int((self._host_last_ticks() >= 0).sum())),
-            "nsvc": num(nsvc),
-            "connevents": num(c.get("conn_events", 0)),
-            "respevents": num(c.get("resp_events", 0)),
-            "queries": num(c.get("queries", 0)),
-            "alertsfired": num(rt.alerts.stats.get("nfired", 0)),
-            "wirever": num(V.CURR_WIRE_VERSION),
-            "version": obj(V.__version__),
-        }
-        return cols, np.ones(1, bool)
+        return api.serverstatus_columns(
+            rt, self.tick, int((self._host_last_ticks() >= 0).sum()),
+            nsvc)
 
     def _svc_task_ids(self):
         cols, live = self.columns("taskstate")

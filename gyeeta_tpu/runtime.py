@@ -26,6 +26,7 @@ from gyeeta_tpu.engine import aggstate, compact, step
 from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.history import open_store
 from gyeeta_tpu.obs import health as obs_health
+from gyeeta_tpu.obs import xlamon
 from gyeeta_tpu.obs.spans import FoldProfiler, SpanTracer
 from gyeeta_tpu.parallel import depgraph as dg
 from gyeeta_tpu.ingest import decode, native, wire
@@ -67,9 +68,10 @@ def _memo_jit(key: tuple, make):
 def snap_pingpong_enabled(env=None) -> bool:
     """Snapshot ping-pong prototype (ROADMAP query item (a)): donate
     the retired (N-2) snapshot's buffers back as the next tree-copy's
-    destination. Measured ~12x cheaper publish at the 32k geometry on
-    the 0.4.37 CPU backend (bench.py ``snap_pingpong`` row — the plain
-    copy pays full-state alloc+free every publish). Default OFF
+    destination. ~12x cheaper publish at the 32k geometry in an earlier
+    CPU-backend run (bench.py ``snap_pingpong`` row; not measured on a
+    chip — the plain copy pays full-state alloc+free every publish).
+    Default OFF
     because the win has a sharp edge: on CPU the merged-column renders
     are ZERO-COPY numpy views of snapshot buffers, so an off-tick
     consumer (history writer queue, alert delivery) more than two
@@ -343,8 +345,8 @@ class Runtime:
         # GYT_SNAP_PINGPONG=1: donate the RETIRED snapshot's buffers as
         # the next copy's destination (ROADMAP query item (a) — halves
         # HBM churn per publish where the backend implements donation;
-        # see snapshot_copy for the refcount guard and the 0.4.x/CPU
-        # caveats, measured by bench.py's snap_pingpong phase)
+        # see snapshot_copy for the refcount guard and the CPU-view
+        # caveat, exercised by bench.py's snap_pingpong phase)
         self._snap_pingpong = snap_pingpong_enabled()
         self._snap_copy_pp = mj("snap_copy_pp", make_pingpong_copy) \
             if self._snap_pingpong else None
@@ -417,7 +419,7 @@ class Runtime:
         self._slab_bufs = [
             {"conn": decode.alloc_conn_cols(K * self.cfg.conn_batch),
              "resp": decode.alloc_resp_cols(K * self.cfg.resp_batch),
-             "hw_conn": 0, "hw_resp": 0}
+             "hw_conn": 0, "hw_resp": 0, "consumer": None}
             for _ in range(2)]
         self._slab_active = 0
         # fold_all jit cache: one compiled variant per section-presence
@@ -760,6 +762,14 @@ class Runtime:
             buf = self._slab_bufs[self._slab_active]
             self._slab_active ^= 1          # flip: next decode goes to
             self.stats.bump("stage_slab_flips")  # the idle buffer
+            # device_put reads host memory asynchronously (and may
+            # alias it outright on the CPU backend), so a staging
+            # buffer is rewritten only once the fold that consumed its
+            # last contents has finished — an output of that fold is
+            # ready. The fold in between keeps the device busy
+            # meanwhile; a device two dispatches behind the host used
+            # to fold half-rewritten lanes, silently.
+            jax.block_until_ready(buf["consumer"])
             crecs, nc = decode.take_raw_chunks(
                 self._conn_raw, K * self.cfg.conn_batch)
             rrecs, nr = decode.take_raw_chunks(
@@ -819,6 +829,8 @@ class Runtime:
                 self.state, self.dep, np.int32(self._tick_no), *secs)
         self._profiler.on_fold()      # GYT_JAX_PROFILE bracket (opt-in)
         self._pressures.append(pressure)
+        if connresp == "slab":
+            buf["consumer"] = pressure
         if "connresp" in sections:
             self._td_dirty = True
         self.stats.bump("fold_dispatches")
@@ -952,6 +964,9 @@ class Runtime:
         # scrape-level signal, not just a growing fallback counter
         gauges["native_decode_available"] = \
             1.0 if native.available() else 0.0
+        # what each device holds now and at its high-water mark (none
+        # on the CPU backend, which reports no memory_stats)
+        gauges.update(xlamon.device_gauges())
         # WAL health rides the same one-readback report path: fsync lag
         # (the RPO bound), pending bytes, segment footprint
         if self.journal is not None:
@@ -1212,30 +1227,10 @@ class Runtime:
         return cols, np.ones(len(seen), bool)
 
     def _serverstatus_columns(self):
-        """serverstatus subsystem (ref madhavastatus): one-row self
-        status from the live counters."""
-        from gyeeta_tpu import version as V
-
-        c = self.stats.counters
-        obj = lambda v: np.array([v], object)  # noqa: E731
-        num = lambda v: np.array([float(v)], np.float64)  # noqa: E731
-        cols = {
-            "uptime": num(self._clock() - self._t_started),
-            "tick": num(self._tick_no),
-            "nhosts": num(int((np.asarray(self.state.host_last_tick)
-                               >= 0).sum())),
-            "nsvc": num(int(np.asarray(self.state.tbl.n_live))),
-            # exact host-side int counters (the () f32 device scalars
-            # lose increments past ~2^24 events); the sharded runtime
-            # bumps the same counters in its feed path
-            "connevents": num(c.get("conn_events", 0)),
-            "respevents": num(c.get("resp_events", 0)),
-            "queries": num(c.get("queries", 0)),
-            "alertsfired": num(self.alerts.stats.get("nfired", 0)),
-            "wirever": num(V.CURR_WIRE_VERSION),
-            "version": obj(V.__version__),
-        }
-        return cols, np.ones(1, bool)
+        return api.serverstatus_columns(
+            self, self._tick_no,
+            int((np.asarray(self.state.host_last_tick) >= 0).sum()),
+            float(np.asarray(self.state.tbl.n_live)))
 
     def _alert_columns(self, subsys: str):
         """Column source for realtime alertdef evaluation — the same

@@ -167,7 +167,19 @@ class Daemon:
             n = _ck.sweep_stale_tmp(opts.checkpoint_dir)
             if n:
                 log.info("swept %d stale .tmp.npz staging file(s)", n)
+        # one monitor per process, created before the first compile
+        # (state init compiles too); its counters join the runtime's
+        from gyeeta_tpu.obs.xlamon import XlaMonitor, device_info
+        xlamon = XlaMonitor()
         self.rt = _make_runtime(args, cfg, opts)
+        xlamon.attach(self.rt.stats)
+        # the backend jax took, stated once (after _make_runtime: it
+        # may still set the CPU device-count flag, which a backend
+        # initialized earlier would not see)
+        dev = device_info()
+        log.info("device: platform=%s kind=%s count=%d "
+                 "(JAX_PLATFORMS=%r)", dev["platform"], dev["devicekind"],
+                 dev["ndevices"], os.environ.get("JAX_PLATFORMS"))
         if args.restore:
             extra = self.rt.restore(args.restore)
             log.info("restored checkpoint %s (tick %s)", args.restore,
@@ -271,7 +283,9 @@ class Daemon:
             os.makedirs(self.rt.opts.checkpoint_dir, exist_ok=True)
             crash_path = f"{self.rt.opts.checkpoint_dir}/gyt_crash.log"
         else:
-            crash_path = "/tmp/gyt_crash.log"
+            import tempfile
+            crash_path = os.path.join(tempfile.gettempdir(),
+                                      "gyt_crash.log")
         crashguard.enable_crash_dumps(crash_path)
         watchdog = None
         if self.args.tick_interval:
@@ -449,15 +463,18 @@ def _make_runtime(args, cfg, opts):
     """The ``--shards N`` fleet mode: a :class:`ShardedRuntime` over an
     N-device mesh (the production shape — per-shard fused folds, one
     collective roll-up per tick, per-shard WAL subdirs), else the flat
-    single-device Runtime. On a CPU host the mesh devices are forced
-    via ``xla_force_host_platform_device_count`` — set BEFORE the first
+    single-device Runtime. Where ``JAX_PLATFORMS`` names the CPU
+    backend the mesh devices are forced via
+    ``xla_force_host_platform_device_count`` — set BEFORE the first
     jax backend init, which is why this helper owns runtime
-    construction."""
+    construction. Any other backend is left exactly as it is: its
+    devices are the chips it has."""
     shards = int(getattr(args, "shards", 0) or 0)
     if shards <= 1:
         return Runtime(cfg, opts)
     flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
+    if (os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+            and "xla_force_host_platform_device_count" not in flags):
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={shards}"
         ).strip()
@@ -468,9 +485,10 @@ def _make_runtime(args, cfg, opts):
     ndev = len(jax.devices())
     if ndev < shards:
         raise SystemExit(
-            f"--shards {shards} needs {shards} devices, backend has "
-            f"{ndev} (a CPU host must not initialize jax before the "
-            f"device-count flag is set — check for early jax use)")
+            f"--shards {shards} needs {shards} devices, backend "
+            f"{jax.devices()[0].platform!r} has {ndev} (virtual CPU "
+            f"devices are forced only under JAX_PLATFORMS=cpu, and "
+            f"only if jax was not initialized earlier)")
     log.info("sharded runtime: %d-shard mesh (%d devices available), "
              "per-shard WAL %s", shards, ndev,
              "on" if opts.journal_dir else "off")

@@ -46,13 +46,6 @@ def update(sk: CMS, key_hi, key_lo, values, valid=None) -> CMS:
     rows = [b + r * width for r, b in enumerate(buckets)]
     flat_idx = jnp.concatenate(rows)
     flat_vals = jnp.tile(vals, depth)
-    # GYT_PALLAS=1: the hash→bucket→add inner loop as a hand kernel
-    # (sketch/pallas_scatter.py prototype); vals are pre-masked, so
-    # both paths apply identical updates
-    from gyeeta_tpu.sketch import pallas_scatter as _ps
-    if _ps.enabled():
-        return CMS(counts=_ps.scatter_add(sk.counts, flat_idx,
-                                          flat_vals))
     counts = sk.counts.reshape(-1).at[flat_idx].add(flat_vals)
     return CMS(counts=counts.reshape(depth, width))
 

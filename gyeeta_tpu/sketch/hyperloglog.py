@@ -46,33 +46,21 @@ def _idx_rank(key_hi, key_lo, p: int):
 
 
 def update(sk: HLL, key_hi, key_lo, valid=None) -> HLL:
-    """Global (no entity axis) register update via scatter-max.
-
-    GYT_PALLAS=1 routes the register write through the hand-kernel
-    prototype (``sketch/pallas_scatter.py``) — rank is pre-masked to 0
-    on invalid lanes, so both paths see identical no-op updates."""
+    """Global (no entity axis) register update via scatter-max."""
     p = int(np.log2(sk.regs.shape[-1]))
     idx, rank = _idx_rank(key_hi, key_lo, p)
     if valid is not None:
         rank = jnp.where(valid, rank, 0)
-    from gyeeta_tpu.sketch import pallas_scatter as _ps
-    if _ps.enabled():
-        return HLL(regs=_ps.scatter_max(sk.regs, idx, rank))
     return HLL(regs=sk.regs.at[idx].max(rank))
 
 
 def update_entities(sk: HLL, entity_row, key_hi, key_lo, valid=None) -> HLL:
     """Per-entity update: scatter-max at (entity_row, register)."""
     p = int(np.log2(sk.regs.shape[-1]))
-    m = sk.regs.shape[-1]
     idx, rank = _idx_rank(key_hi, key_lo, p)
     if valid is not None:
         rank = jnp.where(valid, rank, 0)
         entity_row = jnp.where(valid, entity_row, 0)
-    from gyeeta_tpu.sketch import pallas_scatter as _ps
-    if _ps.enabled():
-        flat_idx = entity_row.astype(jnp.int32) * m + idx
-        return HLL(regs=_ps.scatter_max(sk.regs, flat_idx, rank))
     return HLL(regs=sk.regs.at[entity_row, idx].max(rank))
 
 

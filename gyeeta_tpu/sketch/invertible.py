@@ -26,10 +26,7 @@ Bucket contents (struct-of-arrays, all ``(depth, width)``):
   re-hash (a decoded key must hash INTO its own bucket).
 
 Update is pure scatter-max / masked scatter-set — it rides the fused
-``fold_all`` dispatch with zero extra dispatches, and the ``prio``
-scatter-max routes through the Pallas hand-kernel prototype when
-``GYT_PALLAS=1`` (``sketch/pallas_scatter.py``), exactly like the
-CMS/HLL updates. Bucket mass totals are deliberately NOT tracked: the
+``fold_all`` dispatch with zero extra dispatches. Bucket mass totals are deliberately NOT tracked: the
 CMS next door already accounts every lane's mass, so a per-bucket
 vsum would duplicate the most expensive scatter in the fold for a
 signal the error bounds never read. The candidate-replacement write resolves a
@@ -146,7 +143,6 @@ def update(sk: InvSketch, key_hi, key_lo, prio, valid,
     if hot is not None:
         # full-batch accounting — counted BEFORE candidate compaction
         n_hot = n_hot + jnp.sum(valid & hot).astype(jnp.float32)
-    from gyeeta_tpu.sketch import pallas_scatter as _ps
     if 0 < budget < n:
         score = jnp.where(valid, pr, -1.0)
         _, sel = jax.lax.top_k(score, budget)
@@ -155,11 +151,8 @@ def update(sk: InvSketch, key_hi, key_lo, prio, valid,
         valid = valid[sel] & (score[sel] >= 0)
     bks = buckets(key_hi, key_lo, d, w)
     flat_idx = jnp.concatenate([b + r * w for r, b in enumerate(bks)])
-    if _ps.enabled():
-        prio_new = _ps.scatter_max(sk.prio, flat_idx, jnp.tile(pr, d))
-    else:
-        prio_new = sk.prio.reshape(-1).at[flat_idx].max(
-            jnp.tile(pr, d)).reshape(d, w)
+    prio_new = sk.prio.reshape(-1).at[flat_idx].max(
+        jnp.tile(pr, d)).reshape(d, w)
 
     fp_l = fingerprint(key_hi, key_lo)
     e_hi, e_lo = encode_key(key_hi, key_lo, fp_l)

@@ -31,7 +31,9 @@ import numpy as np
 # Reserved sentinel marking empty/invalid slots. A real key of all-ones is
 # astronomically unlikely for hashed flow keys (and merely loses one slot if
 # it occurs); a real all-zero key is NOT special, unlike the previous design.
-SENTINEL = jnp.uint32(0xFFFFFFFF)
+# A numpy scalar, like table.EMPTY/TOMB: a jnp one would be a device array
+# built at import — importing the package would open the accelerator.
+SENTINEL = np.uint32(0xFFFFFFFF)
 
 
 class TopK(NamedTuple):

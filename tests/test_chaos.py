@@ -224,8 +224,16 @@ def test_query_conn_error_budget(rt):
 # ----------------------------------------------- client-side deadlines
 def test_connect_deadlines_clear_error():
     async def scenario():
+        # the handlers hold their conns until released, then close
+        # them: since Python 3.12.1 Server.wait_closed waits for every
+        # live conn, so a handler that just slept would hang the stop
+        release = asyncio.Event()
+
         async def black_hole(reader, writer):
-            await asyncio.sleep(30)
+            try:
+                await release.wait()
+            finally:
+                writer.close()
 
         srv = await asyncio.start_server(black_hole, "127.0.0.1", 0)
         host, port = srv.sockets[0].getsockname()[:2]
@@ -235,6 +243,7 @@ def test_connect_deadlines_clear_error():
         qc = QueryClient(connect_timeout=0.2)
         with pytest.raises(ConnectionError, match="timed out"):
             await qc.connect(host, port)
+        release.set()
         srv.close()
         await srv.wait_closed()
         return a, qc
@@ -246,13 +255,19 @@ def test_connect_deadlines_clear_error():
 
 def test_query_deadline_clear_error():
     async def scenario():
+        release = asyncio.Event()
+
         async def wedged(reader, writer):
-            # answer registration, then swallow every query forever
-            await wire.read_frame(reader)
-            writer.write(wire.encode_register_resp(
-                wire.REG_OK, 0xFFFFFFFF, version.CURR_WIRE_VERSION))
-            await writer.drain()
-            await asyncio.sleep(30)
+            # answer registration, then swallow every query until the
+            # test is over (see black_hole above for the close)
+            try:
+                await wire.read_frame(reader)
+                writer.write(wire.encode_register_resp(
+                    wire.REG_OK, 0xFFFFFFFF, version.CURR_WIRE_VERSION))
+                await writer.drain()
+                await release.wait()
+            finally:
+                writer.close()
 
         srv = await asyncio.start_server(wedged, "127.0.0.1", 0)
         host, port = srv.sockets[0].getsockname()[:2]
@@ -260,6 +275,7 @@ def test_query_deadline_clear_error():
         await qc.connect(host, port)
         with pytest.raises(TimeoutError, match="timed out"):
             await qc.query({"subsys": "hoststate"}, timeout=0.2)
+        release.set()
         srv.close()
         await srv.wait_closed()
         return qc
@@ -571,32 +587,8 @@ def test_torn_newest_checkpoint_walks_back(rt, tmp_path):
 
 
 # ------------------------------------------------------------ e2e (slow)
-@pytest.fixture
-def no_xla_disk_cache():
-    """The 0.4.x jaxlib persistent compilation cache corrupts the heap
-    under this scenario's compile-while-dispatching interleaving (three
-    runtimes compiling folds while the asyncio server dispatches —
-    crash reproduced with the cache dir set, on 1 AND 8 devices, cold
-    and warm, faults on or off; 0/6 crashes with the cache dir unset).
-    Same jaxlib-line fragility family as the shard_map reload crash
-    documented in conftest.py — point the cache dir at nothing for
-    this one test (the enable flag alone does NOT stop writes on this
-    jax version)."""
-    import jax
-    from jax._src import compilation_cache as jcc
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", "")
-    # the cache singleton binds its directory at the FIRST compile in
-    # the process (import-time jnp constants count) and ignores config
-    # changes after that — drop it so the "" dir takes effect
-    jcc.reset_cache()
-    yield
-    jax.config.update("jax_compilation_cache_dir", old or "")
-    jcc.reset_cache()
-
-
 @pytest.mark.slow
-def test_chaos_e2e_server_kill_converges(tmp_path, no_xla_disk_cache):
+def test_chaos_e2e_server_kill_converges(tmp_path):
     """The whole robustness story: sim agents stream through the seeded
     chaos proxy (corruption + disconnects + re-splitting), the server
     dies mid-run and a replacement restores the latest usable

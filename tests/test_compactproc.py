@@ -314,3 +314,32 @@ def test_cli_compact_parallel_and_list(parted, tmp_path):
     listing = json.loads(buf.getvalue())
     assert len(listing["shards"]) == TICKS // WINDOW_TICKS
     assert all(len(e["parts"]) == NSHARDS for e in listing["shards"])
+
+
+# ------------------------------------------------------- one chip owner
+def _report_backend(q) -> None:
+    """Spawn target: by the time this runs, unpickling it has imported
+    this module — and with it jax — under the parent's environment."""
+    from gyeeta_tpu.history import compactproc
+    compactproc._pin_cpu_backend()
+    import jax
+    q.put(jax.default_backend())
+
+
+def test_replay_worker_never_opens_the_parents_accelerator(monkeypatch):
+    """A serving process on a chip host exports ``JAX_PLATFORMS`` for
+    the TPU; its spawned replay workers inherit that. The worker's pin
+    must still land it on the CPU backend (here ``tpu`` cannot even
+    initialize, so a worker that honoured it would raise)."""
+    import multiprocessing as mp
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    ctx = mp.get_context("spawn")
+    q = ctx.Queue()
+    p = ctx.Process(target=_report_backend, args=(q,), daemon=True)
+    p.start()
+    try:
+        assert q.get(timeout=120) == "cpu"
+    finally:
+        p.join(timeout=30)
+    assert not p.is_alive()

@@ -167,3 +167,63 @@ def test_sharded_fused_vs_legacy(monkeypatch):
         return d
 
     assert run(True) == run(False)
+
+
+def test_staging_buffer_not_rewritten_under_a_fold_in_flight():
+    """The double-buffered conn/resp staging slabs are reused every
+    second dispatch, and ``device_put`` reads host memory asynchronously
+    (it may alias it outright on the CPU backend). With a device two
+    dispatches behind the host, the decode of slab N used to rewrite the
+    buffer slab N-2's fold had not read yet: response samples were
+    folded under other services' ids — counted as unknown, or worse,
+    silently attributed. Here every fold is preceded by device work that
+    keeps the whole pool busy, so the host always runs ahead; every
+    sample must still land on its own service."""
+    import jax
+    import jax.numpy as jnp
+
+    from gyeeta_tpu.runtime import Runtime
+
+    # default lane widths: staging arrays big enough to be transferred
+    # asynchronously; a small slab keeps the fold itself cheap
+    cfg = EngineCfg(svc_capacity=1024, n_hosts=64, task_capacity=256)
+    rt = Runtime(cfg)
+    sim = ParthaSim(n_hosts=16, n_svcs=16, seed=5)
+    rt.feed(sim.listener_frames())
+    rt.flush()
+    big = jnp.ones((3000, 3000), jnp.float32)
+
+    @jax.jit
+    def busy(x):
+        m = jax.lax.fori_loop(0, 8, lambda i, a: (a @ big) * 1e-4, big)
+        return x + m[0, 0] * 0.0
+
+    real = rt._get_fold_all
+
+    def behind(names):
+        fold = real(names)
+        return lambda st, dep, tick, *secs: fold(
+            st._replace(n_conn=busy(st.n_conn)), dep, tick, *secs)
+
+    rt._get_fold_all = behind
+    lanes_r = cfg.fold_k * cfg.resp_batch
+    lanes_c = cfg.fold_k * cfg.conn_batch
+    sent = []
+    try:
+        for _ in range(8):
+            resp = sim.resp_records(lanes_r)
+            sent.append(resp)
+            rt.feed(wire.encode_frames_chunked(wire.NOTIFY_RESP_SAMPLE,
+                                               resp)
+                    + sim.conn_frames(lanes_c))
+        rt.flush()
+        assert float(np.asarray(rt.state.n_resp_unknown)) == 0.0
+        resp = np.concatenate(sent)
+        ids, want = np.unique(resp["glob_id"], return_counts=True)
+        key = (np.asarray(rt.state.tbl.key_hi).astype(np.uint64)
+               << np.uint64(32)) | np.asarray(rt.state.tbl.key_lo)
+        row_of = {int(k): r for r, k in enumerate(key)}
+        got = np.asarray(rt.state.resp_win.cur).sum(axis=1)
+        assert [got[row_of[int(i)]] for i in ids] == want.tolist()
+    finally:
+        rt.close()

@@ -36,22 +36,6 @@ CFG = EngineCfg(n_hosts=4, svc_capacity=64, task_capacity=128,
                 fold_k=2)
 
 
-@pytest.fixture(autouse=True, scope="module")
-def no_xla_disk_cache():
-    """This module creates multiple Runtimes with identical programs —
-    on the 0.4.x jaxlib line, RELOADING a just-written persistent-cache
-    entry segfaults (the documented test_recovery/chaos-e2e fragility;
-    see tests/conftest.py + test_chaos.py). Compile fresh instead."""
-    import jax
-    from jax._src import compilation_cache as jcc
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", "")
-    jcc.reset_cache()
-    yield
-    jax.config.update("jax_compilation_cache_dir", old or "")
-    jcc.reset_cache()
-
-
 # ------------------------------------------------------- WAL file format
 def test_journal_roundtrip_position_and_attribution(tmp_path):
     st = Stats()

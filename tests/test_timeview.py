@@ -166,13 +166,9 @@ def test_compactor_restart_resume(tmp_path):
     from the newest raw shard and continues from the shard's recorded
     WAL position — parity still holds at the final tick.
 
-    Slow tier: restoring a snapshot and then running the donating
-    fold/tick executables trips the KNOWN jaxlib-0.4.x cached-
-    executable-reload abort when those executables come back from a
-    warm persistent XLA cache (the same pre-existing bug class
-    conftest documents for shard_map reloads and test_recovery) —
-    ci.sh clears the test cache before full runs, so the slow tier
-    always executes this all-miss."""
+    Slow tier for its wall clock only (two compactors, four driven
+    ticks); the resumed numpy-leaf state goes through cache-reloaded
+    donating programs, cold and warm alike."""
     rt = Runtime(CFG, _opts(tmp_path))
     sim = ParthaSim(n_hosts=8, n_svcs=4, seed=11)
     rt.feed(sim.name_frames())
