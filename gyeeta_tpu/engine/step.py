@@ -58,15 +58,20 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     dual-observed flow's bytes are never counted twice. The dep graph
     dedups its halves via scatter-max.
     """
+    # each component folds inside a ``jax.named_scope`` (metadata only:
+    # the compiled program does the same work) so that a device trace
+    # can put an op down to its component
+    scope = jax.named_scope
     valid = cb.valid
     svc_side = valid & cb.is_accept
-    if "upsert" in _ABLATE:
-        tbl, rows = st.tbl, table.lookup(st.tbl, cb.svc_hi, cb.svc_lo,
-                                         svc_side)
-        any_new = jnp.any(svc_side & (rows < 0))
-    else:
-        tbl, rows, any_new = table.upsert_fast2(
-            st.tbl, cb.svc_hi, cb.svc_lo, svc_side)
+    with scope("conn.upsert"):
+        if "upsert" in _ABLATE:
+            tbl, rows = st.tbl, table.lookup(st.tbl, cb.svc_hi,
+                                             cb.svc_lo, svc_side)
+            any_new = jnp.any(svc_side & (rows < 0))
+        else:
+            tbl, rows, any_new = table.upsert_fast2(
+                st.tbl, cb.svc_hi, cb.svc_lo, svc_side)
     ok = svc_side & (rows >= 0)
     rowz = jnp.where(ok, rows, 0)
     S = cfg.svc_capacity
@@ -80,10 +85,11 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     ctr_win = st.ctr_win
     lanes = jnp.where(ok, rowz, S)  # S = dropped (mode=drop)
     if "ctr" not in _ABLATE:
-        upd = jnp.stack(
-            [cb.bytes_sent, cb.bytes_rcvd,
-             cb.is_close.astype(jnp.float32), cb.duration_us], axis=1)
-        cur = st.ctr_win.cur.at[lanes].add(upd, mode="drop")
+        with scope("conn.ctr"):
+            upd = jnp.stack(
+                [cb.bytes_sent, cb.bytes_rcvd,
+                 cb.is_close.astype(jnp.float32), cb.duration_us], axis=1)
+            cur = st.ctr_win.cur.at[lanes].add(upd, mode="drop")
         ctr_win = st.ctr_win._replace(cur=cur)
 
     # the service→host homing column only changes when a NEW row is
@@ -91,93 +97,100 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     # rehoming re-announces through the listener sweep, which upserts)
     # — so the scatter-set rides the upsert's own miss signal and the
     # all-hit steady state pays nothing for it
-    svc_host = jax.lax.cond(
-        any_new,
-        lambda col: col.at[lanes].set(cb.host_id, mode="drop"),
-        lambda col: col, st.svc_host)
-    svc_hll = st.svc_hll if "svchll" in _ABLATE else hll.update_entities(
-        st.svc_hll, rowz, cb.cli_hi, cb.cli_lo, valid=ok)
-    glob_hll = st.glob_hll if "globhll" in _ABLATE else hll.update(
-        st.glob_hll, cb.flow_hi, cb.flow_lo, valid=valid)
+    with scope("conn.upsert"):
+        svc_host = jax.lax.cond(
+            any_new,
+            lambda col: col.at[lanes].set(cb.host_id, mode="drop"),
+            lambda col: col, st.svc_host)
+    with scope("conn.svc_hll"):
+        svc_hll = st.svc_hll if "svchll" in _ABLATE \
+            else hll.update_entities(st.svc_hll, rowz, cb.cli_hi,
+                                     cb.cli_lo, valid=ok)
+    with scope("conn.glob_hll"):
+        glob_hll = st.glob_hll if "globhll" in _ABLATE else hll.update(
+            st.glob_hll, cb.flow_hi, cb.flow_lo, valid=valid)
     # byte accounting takes the ACCEPT side only (valid=svc_side below
     # already masks client-observed lanes): a dual-observed flow would
     # otherwise count twice into the additive CMS/top-K. Server-side
     # listener accounting is also where the reference attaches traffic
     # stats.
     tot_bytes = cb.bytes_sent + cb.bytes_rcvd
-    cms = st.cms if "cms" in _ABLATE else countmin.update(
-        st.cms, cb.flow_hi, cb.flow_lo, tot_bytes, valid=svc_side)
+    with scope("conn.cms"):
+        cms = st.cms if "cms" in _ABLATE else countmin.update(
+            st.cms, cb.flow_hi, cb.flow_lo, tot_bytes, valid=svc_side)
     # sketch-assisted candidate compaction (CMS+heap, the shape of
     # the FPGA sketch-acceleration papers): the CMS — queried AFTER
     # this batch folded into it — upper-bounds every flow's
     # cumulative mass, so only the topk_budget best lanes enter the
     # grouping sort. One hash row is enough for a safe-side
     # ranking signal (sketch/countmin.py:upper_bound).
-    est = None
-    if "cms" not in _ABLATE and 0 < cfg.topk_budget:
-        est = countmin.upper_bound(cms, cb.flow_hi, cb.flow_lo)
-    # priority-aware hot admission (PSketch): on top of the budget's
-    # relative ranking, a lane enters the exact top-K merge only when
-    # its estimate clears an absolute floor of the total folded mass —
-    # colder lanes keep their mass in the CMS and their excluded mass
-    # lands in ``evicted`` (the bound stays honest because a floored
-    # lane scores −1, same as padding, and unselected valid mass is
-    # always accounted).
-    hot = None
-    if est is not None and cfg.hh_hot_frac > 0:
-        thresh = jnp.float32(cfg.hh_hot_frac) * countmin.total(cms)
-        hot = est >= thresh
+    est = hot = sel = None
     n = cb.flow_hi.shape[0]
-    sel = None
-    if est is not None and 0 < cfg.topk_budget < n:
-        # ONE shared candidate selection feeds BOTH heavy-hitter
-        # structures (the exact merge's grouping sort and the
-        # invertible bucket-ownership writes): score = estimate on
-        # admitted lanes, −1 on padding/cold lanes. Mass excluded by
-        # the selection is charged to ``evicted`` here, so the
-        # undercount bound stays exactly as honest as the in-update
-        # compaction it replaces.
-        score = jnp.where(svc_side, est.astype(jnp.float32), -1.0)
-        if hot is not None:
-            score = jnp.where(hot, score, -1.0)
-        _, sel = jax.lax.top_k(score, cfg.topk_budget)
-        sel_ok = score[sel] >= 0.0
-        c_hi, c_lo = cb.flow_hi[sel], cb.flow_lo[sel]
-        c_vals = jnp.where(sel_ok, tot_bytes[sel].astype(jnp.float32),
-                           0.0)
-        c_prio = jnp.where(sel_ok, est[sel].astype(jnp.float32), 0.0)
-        extra_evicted = (jnp.sum(jnp.where(svc_side, tot_bytes, 0.0))
-                         - jnp.sum(c_vals))
-    if "topk" in _ABLATE:
-        flow_topk = st.flow_topk
-    elif sel is not None:
-        ftk = st.flow_topk._replace(
-            evicted=st.flow_topk.evicted + extra_evicted)
-        flow_topk = topk.update(ftk, c_hi, c_lo, c_vals, valid=sel_ok)
-    else:
-        flow_topk = topk.update(
-            st.flow_topk, cb.flow_hi, cb.flow_lo, tot_bytes,
-            valid=svc_side, est=est, budget=cfg.topk_budget)
-    if "hh" in _ABLATE or cfg.hh_width <= 0:
-        inv = st.inv
-    else:
-        # invertible candidate buckets (sketch/invertible.py): the
-        # selected (admitted) lanes compete for bucket ownership with
-        # their estimate as priority — per-tick decoding recovers
-        # heavy keys straight from this state, no candidate list.
-        # Falls back to every accept-side lane with its own mass as
-        # priority when the CMS is ablated.
-        if sel is not None:
-            inv = invertible.update(st.inv, c_hi, c_lo, c_prio,
+    with scope("conn.topk_select"):
+        if "cms" not in _ABLATE and 0 < cfg.topk_budget:
+            est = countmin.upper_bound(cms, cb.flow_hi, cb.flow_lo)
+        # priority-aware hot admission (PSketch): on top of the
+        # budget's relative ranking, a lane enters the exact top-K
+        # merge only when its estimate clears an absolute floor of the
+        # total folded mass — colder lanes keep their mass in the CMS
+        # and their excluded mass lands in ``evicted`` (the bound stays
+        # honest because a floored lane scores −1, same as padding, and
+        # unselected valid mass is always accounted).
+        if est is not None and cfg.hh_hot_frac > 0:
+            thresh = jnp.float32(cfg.hh_hot_frac) * countmin.total(cms)
+            hot = est >= thresh
+        if est is not None and 0 < cfg.topk_budget < n:
+            # ONE shared candidate selection feeds BOTH heavy-hitter
+            # structures (the exact merge's grouping sort and the
+            # invertible bucket-ownership writes): score = estimate on
+            # admitted lanes, −1 on padding/cold lanes. Mass excluded
+            # by the selection is charged to ``evicted`` here, so the
+            # undercount bound stays exactly as honest as the in-update
+            # compaction it replaces.
+            score = jnp.where(svc_side, est.astype(jnp.float32), -1.0)
+            if hot is not None:
+                score = jnp.where(hot, score, -1.0)
+            _, sel = jax.lax.top_k(score, cfg.topk_budget)
+            sel_ok = score[sel] >= 0.0
+            c_hi, c_lo = cb.flow_hi[sel], cb.flow_lo[sel]
+            c_vals = jnp.where(sel_ok,
+                               tot_bytes[sel].astype(jnp.float32), 0.0)
+            c_prio = jnp.where(sel_ok, est[sel].astype(jnp.float32), 0.0)
+            extra_evicted = (jnp.sum(jnp.where(svc_side, tot_bytes, 0.0))
+                             - jnp.sum(c_vals))
+    with scope("conn.topk"):
+        if "topk" in _ABLATE:
+            flow_topk = st.flow_topk
+        elif sel is not None:
+            ftk = st.flow_topk._replace(
+                evicted=st.flow_topk.evicted + extra_evicted)
+            flow_topk = topk.update(ftk, c_hi, c_lo, c_vals,
                                     valid=sel_ok)
         else:
-            inv_prio = est if est is not None else tot_bytes
-            inv = invertible.update(st.inv, cb.flow_hi, cb.flow_lo,
-                                    inv_prio, valid=svc_side,
-                                    budget=cfg.topk_budget)
-        if hot is not None:
-            inv = inv._replace(n_hot=inv.n_hot + jnp.sum(
-                svc_side & hot).astype(jnp.float32))
+            flow_topk = topk.update(
+                st.flow_topk, cb.flow_hi, cb.flow_lo, tot_bytes,
+                valid=svc_side, est=est, budget=cfg.topk_budget)
+    with scope("conn.inv"):
+        if "hh" in _ABLATE or cfg.hh_width <= 0:
+            inv = st.inv
+        else:
+            # invertible candidate buckets (sketch/invertible.py): the
+            # selected (admitted) lanes compete for bucket ownership
+            # with their estimate as priority — per-tick decoding
+            # recovers heavy keys straight from this state, no
+            # candidate list. Falls back to every accept-side lane with
+            # its own mass as priority when the CMS is ablated.
+            if sel is not None:
+                inv = invertible.update(st.inv, c_hi, c_lo, c_prio,
+                                        valid=sel_ok)
+            else:
+                inv_prio = est if est is not None else tot_bytes
+                inv = invertible.update(st.inv, cb.flow_hi, cb.flow_lo,
+                                        inv_prio, valid=svc_side,
+                                        budget=cfg.topk_budget)
+            if hot is not None:
+                inv = inv._replace(n_hot=inv.n_hot + jnp.sum(
+                    svc_side & hot).astype(jnp.float32))
     return st._replace(
         tbl=tbl, ctr_win=ctr_win, svc_host=svc_host, svc_hll=svc_hll,
         glob_hll=glob_hll, cms=cms, flow_topk=flow_topk, inv=inv,
@@ -252,15 +265,19 @@ def ingest_resp_flat(cfg: EngineCfg, st: AggState, flat) -> AggState:
     reference likewise only folds response stats into *known* listeners
     (``gy_socket_stat.cc`` resp events resolve against listener_tbl_).
     """
+    scope = jax.named_scope
     valid = flat.valid
-    rows = table.lookup(st.tbl, flat.svc_hi, flat.svc_lo, valid)
+    with scope("resp.lookup"):
+        rows = table.lookup(st.tbl, flat.svc_hi, flat.svc_lo, valid)
     ok = valid & (rows >= 0)
     n_unknown = jnp.sum(valid & (rows < 0)).astype(jnp.float32)
     rowz = jnp.where(ok, rows, 0)
     resp_win = st.resp_win
     if "loghist" not in _ABLATE:
-        cur = loghist.update_entities(
-            st.resp_win.cur, cfg.resp_spec, rowz, flat.resp_us, valid=ok)
+        with scope("resp.loghist"):
+            cur = loghist.update_entities(
+                st.resp_win.cur, cfg.resp_spec, rowz, flat.resp_us,
+                valid=ok)
         resp_win = st.resp_win._replace(cur=cur)
     stage, stage_n = st.td_stage, st.td_stage_n
     n_over = jnp.int32(0)
@@ -273,9 +290,10 @@ def ingest_resp_flat(cfg: EngineCfg, st: AggState, flat) -> AggState:
         # Static stride keeps shapes fixed; lane order is arrival order,
         # uncorrelated with service identity.
         k = max(1, cfg.td_sample_stride)
-        stage, stage_n, n_over = tdigest.stage_samples(
-            stage, stage_n, jnp.where(ok, rows, -1)[::k],
-            flat.resp_us[::k])
+        with scope("resp.td_stage"):
+            stage, stage_n, n_over = tdigest.stage_samples(
+                stage, stage_n, jnp.where(ok, rows, -1)[::k],
+                flat.resp_us[::k])
     return st._replace(
         resp_win=resp_win, td_stage=stage, td_stage_n=stage_n,
         n_resp=st.n_resp + jnp.sum(valid).astype(jnp.float32),
@@ -805,22 +823,33 @@ def fold_all(cfg: EngineCfg, st: AggState, dep, tick, *, listener=None,
     """
     from gyeeta_tpu.parallel import depgraph as dg
 
+    # one named scope per section fold (``sect.<kind>``); the conn/resp
+    # slab's components carry their own (``conn.*``, ``resp.*``)
+    scope = jax.named_scope
     if listener is not None:
-        st = ingest_listener(cfg, st, listener)
+        with scope("sect.listener"):
+            st = ingest_listener(cfg, st, listener)
     if host is not None:
-        st = ingest_host(cfg, st, host)
+        with scope("sect.host"):
+            st = ingest_host(cfg, st, host)
     if task is not None:
-        st = ingest_task(cfg, st, task)
+        with scope("sect.task"):
+            st = ingest_task(cfg, st, task)
     if cpumem is not None:
-        st = ingest_cpumem(cfg, st, cpumem)
+        with scope("sect.cpumem"):
+            st = ingest_cpumem(cfg, st, cpumem)
     if trace is not None:
-        st = ingest_trace(cfg, st, trace)
+        with scope("sect.trace"):
+            st = ingest_trace(cfg, st, trace)
     if ping is not None:
-        st = ping_tasks(cfg, st, ping)
+        with scope("sect.ping"):
+            st = ping_tasks(cfg, st, ping)
     if delta is not None:
-        st, dep = ingest_delta(cfg, st, dep, delta, tick)
+        with scope("sect.delta"):
+            st, dep = ingest_delta(cfg, st, dep, delta, tick)
     if connresp is not None:
         cbs, rbs = connresp
         st = fold_many(cfg, st, cbs, rbs)
-        dep = dg.dep_fold_many(dep, cbs, tick)
+        with scope("dep.fold"):
+            dep = dg.dep_fold_many(dep, cbs, tick)
     return st, dep, stage_pressure(st)

@@ -228,6 +228,11 @@ def available() -> bool:
     return _load() is not None
 
 
+def decode_path() -> str:
+    """The decode path a span reports: ``native`` or ``python``."""
+    return "native" if available() else "python"
+
+
 # ------------------------------------------------------ columnar kernels
 def decode_conn_into(recs: np.ndarray, cols: dict, off: int = 0) -> bool:
     """Decode TCP_CONN records into flat column arrays at lane ``off``

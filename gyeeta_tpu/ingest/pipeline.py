@@ -96,25 +96,16 @@ class FeedPipeline:
                                          False):
             j.append(buf, hid=hid, conn_id=conn_id,
                      tick=getattr(self._rt, "_tick_no", 0))
-        # fold-side visibility (the deframe span above only covers the
-        # worker): the serving thread's decode+dispatch wall per buffer
-        # rides its own span + timing hist, so the decode/fold overlap
-        # win is observable in `obs top` and /metrics (stage
-        # `pipeline_fold_dispatch`; the runtime's own `fold_dispatch`
-        # hist times just the device dispatch inside this window)
+        # fold-side visibility (the deframe timing above only covers
+        # the worker): the serving thread's decode+dispatch wall per
+        # buffer is the span + timing stage ``pipeline_fold_dispatch``,
+        # so the decode/fold overlap win is observable in `obs top` and
+        # /metrics (the runtime's own `fold_dispatch` times just the
+        # device dispatch inside this window)
         nrec = sum(len(a) for a in recs.values())
-        t1 = time.perf_counter()
-        spans = getattr(self._rt, "spans", None)
-        if spans is not None:
-            with spans.span("fold_dispatch", nrec=nrec,
-                            path="native" if native.available()
-                            else "python"):
-                n = self._rt.ingest_records(recs)
-        else:
-            n = self._rt.ingest_records(recs)
-        self._rt.stats.observe_ms("pipeline_fold_dispatch",
-                                  (time.perf_counter() - t1) * 1e3)
-        return n
+        with self._rt.spans.span("pipeline_fold_dispatch", nrec=nrec,
+                                 path=native.decode_path()):
+            return self._rt.ingest_records(recs)
 
     def feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
         self._fifo.append((self._ex.submit(self._deframe, buf),

@@ -55,7 +55,10 @@ import asyncio
 import collections
 import concurrent.futures
 import os
+import time
 from typing import Optional
+
+from gyeeta_tpu.obs.spans import SpanTracer
 
 
 class Overloaded(Exception):
@@ -153,12 +156,30 @@ class JsonRenderPool:
             self._pool = None
 
 
+class ReqClock:
+    """One request's stamps (``time.perf_counter()``) across the loop
+    and a worker thread: ``req`` is the span ring's request id,
+    ``t_in`` when the loop had decoded the request, ``t_done`` when its
+    answer was computed (the worker's last line; the loop's, for a
+    request that ran inline)."""
+
+    __slots__ = ("req", "t_in", "t_done")
+
+    def __init__(self, req: int):
+        self.req = req
+        self.t_in = self.t_done = time.perf_counter()
+
+
 class QueryExecutor:
     def __init__(self, rt, workers: Optional[int] = None,
                  queue_max: Optional[int] = None,
                  shed_policy: Optional[str] = None):
         env = os.environ
         self.rt = rt
+        # a runtime stand-in without a span ring (tests) gets its own
+        self.spans = getattr(rt, "spans", None)
+        if self.spans is None:
+            self.spans = SpanTracer(stats=rt.stats)
         self.workers = int(workers if workers is not None
                            else env.get("GYT_QUERY_WORKERS", "4"))
         self.queue_max = int(queue_max if queue_max is not None
@@ -174,7 +195,7 @@ class QueryExecutor:
             max_workers=max(1, self.workers),
             thread_name_prefix="gyt-query")
         self._running = 0             # queries holding a worker thread
-        # waiting room, newest at the right; (req, future) pairs.
+        # waiting room, newest at the right; (req, future, clock).
         # All scheduling state is event-loop-confined — no locks.
         self._pending: collections.deque = collections.deque()
 
@@ -183,16 +204,21 @@ class QueryExecutor:
         return self._running + len(self._pending)
 
     # -------------------------------------------------------------- run
-    async def run(self, req: dict) -> dict:
+    async def run(self, req: dict, clock: Optional[ReqClock] = None
+                  ) -> dict:
         """Admit one query: execute immediately while the pool has
         headroom, else wait in the policy-ordered queue. Raises
         :class:`Overloaded` (counted, policy-labeled) when admission
         sheds it — which under ``lifo`` is the OLDEST waiter, so THIS
-        call usually proceeds and a stale one errors out instead."""
+        call usually proceeds and a stale one errors out instead.
+        ``clock`` carries the request's id and stamps (the GYT edge
+        takes it where the frame is decoded; other edges start here)."""
         stats = self.rt.stats
         loop = asyncio.get_running_loop()
+        if clock is None:
+            clock = ReqClock(self.spans.next_req())
         if self._running < self.workers and not self._pending:
-            return await self._execute(loop, req)
+            return await self._execute(loop, req, clock)
         if self.shed_policy == "fifo" \
                 and self._inflight >= self.queue_max:
             # classic bounded-FIFO tail drop: the NEW arrival sheds
@@ -202,13 +228,13 @@ class QueryExecutor:
                 f"query queue full ({self._inflight} in flight, "
                 f"max {self.queue_max})")
         fut = loop.create_future()
-        self._pending.append((req, fut))
+        self._pending.append((req, fut, clock))
         if self.shed_policy == "lifo":
             # depth-aware freshness shed: drop the OLDEST waiters past
             # the bound — the dashboard that sent them has already
             # refreshed; the newest request is the one still on screen
             while self._inflight > self.queue_max and len(self._pending) > 1:
-                old_req, old_fut = self._pending.popleft()
+                _old_req, old_fut, _old_clock = self._pending.popleft()
                 if not old_fut.done():
                     stats.bump("queries_shed|policy=lifo")
                     stats.bump("queries_shed")
@@ -219,12 +245,12 @@ class QueryExecutor:
         self._gauge()
         return await fut
 
-    async def _execute(self, loop, req: dict) -> dict:
+    async def _execute(self, loop, req: dict, clock: ReqClock) -> dict:
         self._running += 1
         self._gauge()
         try:
             return await loop.run_in_executor(self._pool, self._call,
-                                              req)
+                                              req, clock)
         finally:
             self._running -= 1
             self._dispatch_next(loop)
@@ -234,14 +260,15 @@ class QueryExecutor:
         """A worker freed: hand it the policy's next waiter (lifo =
         newest first; fifo = oldest first)."""
         while self._pending and self._running < self.workers:
-            req, fut = (self._pending.pop() if self.shed_policy == "lifo"
-                        else self._pending.popleft())
+            req, fut, clock = (
+                self._pending.pop() if self.shed_policy == "lifo"
+                else self._pending.popleft())
             if fut.done():                # already shed
                 continue
 
-            async def _chain(req=req, fut=fut):
+            async def _chain(req=req, fut=fut, clock=clock):
                 try:
-                    out = await self._execute(loop, req)
+                    out = await self._execute(loop, req, clock)
                 except BaseException as e:     # noqa: BLE001
                     if not fut.done():
                         fut.set_exception(e)
@@ -255,11 +282,18 @@ class QueryExecutor:
     def _gauge(self) -> None:
         self.rt.stats.gauge("query_queue_depth", float(self._inflight))
 
-    def _call(self, req: dict) -> dict:
-        return self.rt.query({**req, "consistency": "snapshot"})
+    def _call(self, req: dict, clock: ReqClock) -> dict:
+        """On a worker thread. ``query_queue`` is the interval the
+        request waited for it: admission queue + executor hand-off."""
+        self.spans.interval("query_queue", clock.t_in, req=clock.req)
+        try:
+            with self.spans.request(clock.req):
+                return self.rt.query({**req, "consistency": "snapshot"})
+        finally:
+            clock.t_done = time.perf_counter()
 
     def close(self) -> None:
-        for _req, fut in self._pending:
+        for _req, fut, _clock in self._pending:
             if not fut.done():
                 fut.cancel()
         self._pending.clear()

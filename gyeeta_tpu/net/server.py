@@ -27,12 +27,14 @@ import asyncio
 import json
 import logging
 import pathlib
+import time
 from typing import Optional
 
 import numpy as np
 
 from gyeeta_tpu import version
 from gyeeta_tpu.ingest import refproto, refquery, wire
+from gyeeta_tpu.net.qexec import Overloaded, ReqClock
 from gyeeta_tpu.runtime import Runtime
 
 log = logging.getLogger("gyeeta_tpu.net")
@@ -438,17 +440,23 @@ class GytServer:
             return True
         return any(k in req for k in ("at", "window", "tstart", "tend"))
 
-    async def run_query(self, req: dict) -> dict:
+    async def run_query(self, req: dict, clock=None) -> dict:
         """One query request → response dict, shared by the GYT query
         loop and the NM edge (the REST gateway rides the GYT loop).
         Snapshot-eligible queries run OFF-loop on the executor with
         admission control; everything else keeps the original inline
         strong path (feed barrier + live read). Raises
-        :class:`~gyeeta_tpu.net.qexec.Overloaded` on shed."""
+        :class:`~gyeeta_tpu.net.qexec.Overloaded` on shed. ``clock``
+        (``net/qexec.py:ReqClock``) ties the request's spans together."""
         if self._inline_query(req):
             self._feed_barrier()
-            return self.rt.query(req)
-        return await self.qexec.run(req)
+            clock = clock or ReqClock(self.rt.spans.next_req())
+            try:
+                with self.rt.spans.request(clock.req):
+                    return self.rt.query(req)
+            finally:
+                clock.t_done = time.perf_counter()
+        return await self.qexec.run(req, clock)
 
     # ------------------------------------------------------------- serving
     async def start(self) -> tuple[str, int]:
@@ -551,17 +559,19 @@ class GytServer:
             try:
                 self._feed_barrier()
                 self.rt.run_tick()
-                if self._ingest is not None:
-                    # workers stamp WAL chunks with the window tick
-                    # (replay merge order + compactor window evidence)
-                    self._ingest.broadcast_tick(self.rt._tick_no)
-                if self._relay is not None:
-                    # remote relays stamp THEIR WALs with the same tick
-                    self._relay.broadcast_tick(self.rt._tick_no)
-                self._resolve_pending_domains()
-                await self.push_trace_control()
-                await self.push_throttle()
-                await self.push_subscriptions()
+                # what still holds the loop once the tick has returned
+                with self.rt.spans.span("tick_push", annotate=True):
+                    if self._ingest is not None:
+                        # workers stamp WAL chunks with the window tick
+                        # (replay merge order + compactor window evidence)
+                        self._ingest.broadcast_tick(self.rt._tick_no)
+                    if self._relay is not None:
+                        # remote relays stamp THEIR WALs with the same tick
+                        self._relay.broadcast_tick(self.rt._tick_no)
+                    self._resolve_pending_domains()
+                    await self.push_trace_control()
+                    await self.push_throttle()
+                    await self.push_subscriptions()
                 if self.watchdog is not None:
                     self.watchdog.beat()      # liveness heartbeat
             except Exception:                     # pragma: no cover
@@ -1140,11 +1150,12 @@ class GytServer:
                 await writer.drain()
                 continue
             outstanding += 1
+            spans = self.rt.spans
+            clock = ReqClock(spans.next_req())
             try:
                 self.rt.stats.bump("net_queries")
-                out = await self.run_query(req)
+                out = await self.run_query(req, clock)
             except Exception as e:
-                from gyeeta_tpu.net.qexec import Overloaded
                 outstanding -= 1
                 # admission-control shed answers QS_BUSY (counted in
                 # gyt_queries_shed_total) — the client backs off; a
@@ -1160,12 +1171,26 @@ class GytServer:
                 # per chunk: bounded transport memory (the 16MB-frame /
                 # multi-GB discipline of the reference webserver)
                 sent = 0
+                frames = wire.iter_query_frames(seqid, out, wire.QS_OK)
                 try:
-                    for frame in wire.iter_query_frames(seqid, out,
-                                                        wire.QS_OK):
-                        writer.write(frame)
+                    while True:
+                        # the loop-side part of the reply: JSON encode
+                        # (the generator's first step) + write, one
+                        # span per frame and one for the closing step;
+                        # the drain in between yields the loop
+                        with spans.span("query_encode", req=clock.req,
+                                        annotate=True):
+                            frame = next(frames, None)
+                            if frame is not None:
+                                writer.write(frame)
+                        if frame is None:
+                            break
                         await writer.drain()
                         sent += 1
+                    # answer computed → last frame drained: the wait
+                    # for the loop + encode + write
+                    spans.interval("query_reply", clock.t_done,
+                                   req=clock.req)
                 except Exception as e:
                     if sent == 0 and not isinstance(e, ConnectionError):
                         # e.g. unserializable result: the query still

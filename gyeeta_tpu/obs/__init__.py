@@ -10,9 +10,12 @@ Three pillars over the process-wide ``Stats`` registry
   probe-failure/eviction counters, dep-graph fill, digest-stage
   pressure, read back as ONE batched transfer per report cadence
   (``engine/step.py:engine_health_vec``).
-- ``obs/spans.py``  — ring-buffer span tracer over the feed pipeline
-  (deframe → decode+fold per batch, with size and native-vs-fallback
-  path) + the opt-in ``GYT_JAX_PROFILE`` device-trace bracket.
+- ``obs/spans.py``  — the one stage timer of the serving loop: a span
+  is a ring row (with its parent and request id), a timing histogram
+  of the same name and, on the leaves, a ``jax.profiler``
+  annotation, so the feed, tick and query phases lie on the
+  profiler's clock beside the device's events (OPERATIONS.md
+  "Pipeline span tracing").
 
 ``python -m gyeeta_tpu obs top`` renders the live surface; see the
 Monitoring section of OPERATIONS.md for scrape config and alerting
@@ -21,7 +24,7 @@ starting points.
 
 from __future__ import annotations
 
-from gyeeta_tpu.obs.spans import FoldProfiler, SpanTracer  # noqa: F401
+from gyeeta_tpu.obs.spans import SpanTracer  # noqa: F401
 
 
 def format_top(selfstats: dict, prev_counters: dict | None = None,
@@ -195,9 +198,23 @@ def format_top(selfstats: dict, prev_counters: dict | None = None,
     if spans:
         lines.append("")
         lines.append("recent spans (newest first):")
-        lines.append(f"  {'stage':<16} {'wallms':>9} {'nrec':>9} path")
-        for s in spans[:16]:
-            lines.append(f"  {s['name']:<16} {s['wallms']:>9} "
+        lines.append(f"  {'stage':<22} {'wallms':>9} {'nrec':>9} path")
+        # a tree, newest first: each span's children indented under it
+        shown = spans[:16]
+        ids = {s.get("id") for s in shown}
+        kids: dict = {}
+        for s in shown:
+            p = s.get("parent", 0)
+            kids.setdefault(p if p in ids else 0, []).append(s)
+
+        def walk(s, depth):
+            name = "  " * depth + s["name"]
+            lines.append(f"  {name:<22} {s['wallms']:>9} "
                          f"{s['nrec']:>9} {s.get('path', '')}")
+            for c in kids.get(s.get("id"), ()):
+                walk(c, depth + 1)
+
+        for s in kids.get(0, []):
+            walk(s, 0)
 
     return "\n".join(ln[:width] for ln in lines) + "\n"

@@ -39,7 +39,7 @@ from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.ingest import decode, native, wire
 from gyeeta_tpu.obs import health as obs_health
 from gyeeta_tpu.obs import xlamon
-from gyeeta_tpu.obs.spans import FoldProfiler, SpanTracer
+from gyeeta_tpu.obs.spans import SpanTracer
 from gyeeta_tpu.parallel import depgraph as dg
 from gyeeta_tpu.parallel import pairing, rollup, sharded
 from gyeeta_tpu.parallel.mesh import shard_of_host  # noqa: F401 — re-export
@@ -68,9 +68,12 @@ class ShardedRuntime:
         self.layout = ShardLayout(self.mesh)
         self.opts = opts or RuntimeOpts()
         self.stats = Stats()
-        # pipeline span ring + opt-in device-trace bracket (obs tier)
-        self.spans = SpanTracer()
-        self._profiler = FoldProfiler()
+        # the one stage timer: span ring + timing histograms + profiler
+        # annotations on the leaves (obs/spans.py; same span names at
+        # the same places as the single-node Runtime)
+        self.spans = SpanTracer(stats=self.stats,
+                                annotation=jax.profiler.TraceAnnotation)
+        self._tick_p0 = None          # run_tick entry, until its publish
         from gyeeta_tpu.utils.colcache import ColumnCache
         self._cols = ColumnCache()    # version-keyed snapshot memo
         self.names = InternTable()
@@ -336,12 +339,15 @@ class ShardedRuntime:
 
     def feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
         """Byte stream → routed stacked batches → sharded folds."""
+        with self.spans.span("feed", nrec=len(buf)):
+            return self._feed(buf, hid, conn_id)
+
+    def _feed(self, buf: bytes, hid: int, conn_id: int) -> int:
         data = (self._pending + buf) if self._pending else buf
         try:
-            with self.stats.timeit("deframe"), \
-                    self.spans.span("deframe", nrec=len(data),
-                                    path="native" if native.available()
-                                    else "python"):
+            with self.spans.span("deframe", nrec=len(data),
+                                 path=native.decode_path(),
+                                 annotate=True):
                 recs, consumed, unknown = native.drain2(data)
         except wire.FrameError:
             self.stats.bump("frames_bad")
@@ -570,38 +576,40 @@ class ShardedRuntime:
         self._n_resp_raw -= nr
         for s in range(self.n):
             self._shard_events[s] += len(crecs[s]) + len(rrecs[s])
-        with self.stats.timeit("fold_dispatch"), \
-                self.spans.span("decode_fold",
-                                nrec=nc + nr,
-                                path="native" if native.available()
-                                else "python"):
-            b = lambda r, sz: decode.conn_batch_fast(  # noqa: E731
-                r, sz, stats=self.stats)
-            cbs = self.layout.put(
-                sharded.stack_prerouted((b, lanes_c), crecs))
-            b = lambda r, sz: decode.resp_batch_fast(  # noqa: E731
-                r, sz, stats=self.stats)
-            rbs = self.layout.put(
-                sharded.stack_prerouted((b, lanes_r), rrecs))
+        span = self.spans.span
+        with span("fold_dispatch", nrec=nc + nr,
+                  path=native.decode_path()):
+            with span("slab_decode", nrec=nc + nr,
+                      path=native.decode_path(), annotate=True):
+                b = lambda r, sz: decode.conn_batch_fast(  # noqa: E731
+                    r, sz, stats=self.stats)
+                cbs = sharded.stack_prerouted((b, lanes_c), crecs)
+                b = lambda r, sz: decode.resp_batch_fast(  # noqa: E731
+                    r, sz, stats=self.stats)
+                rbs = sharded.stack_prerouted((b, lanes_r), rrecs)
+            with span("fold_h2d", nrec=nc + nr, annotate=True):
+                cbs, rbs = self.layout.put(cbs), self.layout.put(rbs)
             # previous dispatch's pressure scalar is ready by now:
             # flush the fullest per-shard stages before folding if
             # headroom is low
-            if (self._pressure is not None
-                    and int(self._pressure) > self.cfg.td_stage_cap // 2):
-                self.state = self._td_flush(self.state)
-                self.stats.bump("td_partial_flushes")
-            if self._fused:
-                # ONE fused dispatch: fold + dep (a2a pairing) +
-                # pressure output — no observation dispatch
-                fn = self._fold_dep_slab if lanes_c > self.cfg.conn_batch \
-                    else self._fold_dep_chunk
-                self.state, self.dep, self._pressure = fn(
-                    self.state, self.dep, cbs, rbs,
-                    np.int32(self._tick_no))
-                self.stats.bump("fold_dispatches")
-            else:
-                self.state = self._fold(self.state, cbs, rbs)
-        self._profiler.on_fold()      # GYT_JAX_PROFILE bracket (opt-in)
+            if self._pressure is not None:
+                with span("td_flush", annotate=True):
+                    if int(self._pressure) > self.cfg.td_stage_cap // 2:
+                        self.state = self._td_flush(self.state)
+                        self.stats.bump("td_partial_flushes")
+            with span("fold_enqueue", nrec=nc + nr, annotate=True):
+                if self._fused:
+                    # ONE fused dispatch: fold + dep (a2a pairing) +
+                    # pressure output — no observation dispatch
+                    fn = self._fold_dep_slab \
+                        if lanes_c > self.cfg.conn_batch \
+                        else self._fold_dep_chunk
+                    self.state, self.dep, self._pressure = fn(
+                        self.state, self.dep, cbs, rbs,
+                        np.int32(self._tick_no))
+                    self.stats.bump("fold_dispatches")
+                else:
+                    self.state = self._fold(self.state, cbs, rbs)
         self._td_dirty = True
         if not self._fused:
             self._pressure = self._td_pressure(self.state)
@@ -877,7 +885,7 @@ class ShardedRuntime:
         from gyeeta_tpu.sketch import invertible
 
         self.flush()
-        with self.stats.timeit("topk_recover"):
+        with self.spans.span("topk_recover"):
             ru = self._cols.get("__rollup",
                                 lambda: self._rollup(self.state))
             rec = {
@@ -1007,7 +1015,7 @@ class ShardedRuntime:
         unchanged)."""
         from gyeeta_tpu.query.snapshot import EngineSnapshot
         from gyeeta_tpu.runtime import snapshot_copy
-        with self.stats.timeit("snapshot_publish"):
+        with self.spans.span("snapshot_publish", annotate=True):
             state, dep = snapshot_copy(self, (self.state, self.dep))
         self._snap_version += 1
         snap = EngineSnapshot(
@@ -1019,6 +1027,10 @@ class ShardedRuntime:
         # only retained when the flag is on)
         self._snap_old = self.snapshot if self._snap_pingpong else None
         self.snapshot = snap
+        if self._tick_p0 is not None:     # see Runtime.publish_snapshot
+            self.spans.interval("tick_visible", self._tick_p0,
+                                nrec=self._tick_no)
+            self._tick_p0 = None
         self.stats.bump("snapshots_published")
         self.stats.gauge("snapshot_tick", float(self._tick_no))
         self.stats.gauge("snapshot_age_seconds", 0.0)
@@ -1094,19 +1106,27 @@ class ShardedRuntime:
         return gauges
 
     def run_tick(self) -> dict:
-        with self.stats.timeit("tick"), self.spans.span(
-                "tick", nrec=self._tick_no):
-            return self._run_tick()
+        self._tick_p0 = time.perf_counter()
+        try:
+            with self.spans.span("tick", nrec=self._tick_no):
+                return self._run_tick()
+        finally:
+            self._tick_p0 = None      # a tick that failed before its swap
 
     def _run_tick(self) -> dict:
         """Sharded 5s pass: classify → alerts on merged columns → window
-        tick → ageing."""
+        tick → ageing. Every step runs inside a leaf span, as in
+        ``Runtime._run_tick`` (plus ``rollup``; no history sweep)."""
         report = {}
-        self.flush()
+        span = self.spans.span
+        with span("tick.flush", annotate=True):
+            self.flush()
         if self._td_dirty:    # tick-cadence digest compression (bounded)
-            self.td_drain(max_iters=self.opts.td_drain_iters_per_tick)
-        self.state = self._classify(self.state)
-        self._cols.bump()
+            with span("tick.td_drain", annotate=True):
+                self.td_drain(max_iters=self.opts.td_drain_iters_per_tick)
+        with span("tick.classify", annotate=True):
+            self.state = self._classify(self.state)
+            self._cols.bump()
         # publish the post-classify view and route alert evaluation
         # through it — tick-time work pre-warms the snapshot's merged
         # columns for the dashboards (see Runtime._run_tick)
@@ -1118,7 +1138,7 @@ class ShardedRuntime:
         # flowstate/serverstatus/topk queries and alertdefs this window
         # reuse the tick's collective instead of re-dispatching.
         t_ru = self._clock()
-        with self.stats.timeit("rollup"):
+        with span("rollup", annotate=True):
             fv = self._fleet_roll(snap.state, snap.dep)
             health_vec = np.asarray(fv.health)
         self.stats.gauge("rollup_seconds",
@@ -1131,16 +1151,18 @@ class ShardedRuntime:
         ev = self.opts.hh_recover_every_ticks
         if ev and self.cfg.hh_width > 0 \
                 and (self._tick_no + 1) % ev == 0:
-            report["topk_recovered"] = self._cols.get(
-                "__hh_recover", self.heavy_recover)["recovered_keys"]
+            with span("tick.hh_recover", annotate=True):
+                report["topk_recovered"] = self._cols.get(
+                    "__hh_recover", self.heavy_recover)["recovered_keys"]
         # alert eval short-circuits BEFORE any column render when no
         # realtime def is enabled (counted; pending group-wait batches
         # still flush on schedule)
-        if self.alerts.wants_realtime():
-            fired = self.alerts.check(None, columns_fn=snap.columns)
-        else:
-            self.stats.bump("alert_eval_skipped")
-            fired = self.alerts.flush_groups()
+        with span("tick.alerts", annotate=True):
+            if self.alerts.wants_realtime():
+                fired = self.alerts.check(None, columns_fn=snap.columns)
+            else:
+                self.stats.bump("alert_eval_skipped")
+                fired = self.alerts.flush_groups()
         report["alerts_fired"] = len(fired)
         for a in fired:
             self.notifylog.add_alert(a)
@@ -1150,31 +1172,38 @@ class ShardedRuntime:
         # device health from the SAME collective (no extra readback);
         # the drop-pressure signal (VERDICT r4 #10) feeds off the vector
         from gyeeta_tpu.utils import droppressure
-        health = self.engine_health(vec=health_vec)
-        self._shard_rate_gauges()
-        self._last_drops = droppressure.check(
-            obs_health.drops_for_pressure(health),
-            {"svc": self.cfg.svc_capacity,
-             "task": self.cfg.task_capacity,
-             "api": self.cfg.api_capacity,
-             "dep": self.opts.dep_pair_capacity},
-            getattr(self, "_last_drops", {}),
-            self.notifylog, self.stats)
-        self.state = self._tick(self.state)
-        if self._tick_no % self.opts.task_age_every_ticks == 0:
-            self.state = self._age_tasks(self.state)
-            self.state = self._age_apis(self.state)
-        self.dep = self._dep_age(self.dep, np.int32(self._tick_no))
-        with self._reg_lock:      # ageing structurally mutates the
-            self.cgroups.age()    # registries snapshot aux renders
-            self.mounts.age()     # iterate on worker threads
-            self.netifs.age()
-            self.natclusters.age()
-            self.traceconns.age()
-        # journal fsync cadence backstop + checkpoint-with-WAL-position
-        # (same durability contract as the single-node Runtime: the
-        # checkpoint records the fsynced journal position and
-        # supersedes older segments)
+        with span("tick.health", annotate=True):
+            health = self.engine_health(vec=health_vec)
+            self._shard_rate_gauges()
+            self._last_drops = droppressure.check(
+                obs_health.drops_for_pressure(health),
+                {"svc": self.cfg.svc_capacity,
+                 "task": self.cfg.task_capacity,
+                 "api": self.cfg.api_capacity,
+                 "dep": self.opts.dep_pair_capacity},
+                getattr(self, "_last_drops", {}),
+                self.notifylog, self.stats)
+        with span("tick.roll", annotate=True):
+            self.state = self._tick(self.state)
+            if self._tick_no % self.opts.task_age_every_ticks == 0:
+                self.state = self._age_tasks(self.state)
+                self.state = self._age_apis(self.state)
+            self.dep = self._dep_age(self.dep, np.int32(self._tick_no))
+            with self._reg_lock:      # ageing structurally mutates the
+                self.cgroups.age()    # registries snapshot aux renders
+                self.mounts.age()     # iterate on worker threads
+                self.netifs.age()
+                self.natclusters.age()
+                self.traceconns.age()
+        with span("tick.close", annotate=True):
+            self._tick_close(report)
+        return report
+
+    def _tick_close(self, report: dict) -> None:
+        """The journal's fsync backstop and the checkpoint-with-WAL-
+        position (same durability contract as the single-node Runtime:
+        the checkpoint records the fsynced journal position and
+        supersedes older segments)."""
         if self.journal is not None:
             self.journal.poll()
         if (self.opts.checkpoint_dir
@@ -1192,7 +1221,6 @@ class ShardedRuntime:
             self.stats.bump("checkpoints")
         # the window tick / ageing above changed every view
         self._cols.bump()
-        return report
 
     # -------------------------------------------------------------- query
     def crud(self, req: dict) -> dict:
@@ -1231,7 +1259,7 @@ class ShardedRuntime:
             return out
         self.stats.bump("queries")
         self.flush()          # live queries see all staged records
-        with self.stats.timeit("query"):
+        with self.spans.span("query", annotate=True):
             return api.execute(self.cfg, None, QueryOptions.from_json(req),
                                names=self.names,
                                columns_fn=self._merged_columns)
@@ -1251,13 +1279,12 @@ class ShardedRuntime:
         if out is not None:
             return out
         self.stats.bump("queries")
-        with self.stats.timeit("query"):
+        with self.spans.span("query", annotate=True):
             return snap.query(req)
 
     def close(self) -> None:
         """Release background workers (alert delivery, DNS resolver).
         Idempotent — mirrors Runtime.close()."""
-        self._profiler.close()
         self.alerts.close()
         self.dns.close()
         if self.journal is not None:

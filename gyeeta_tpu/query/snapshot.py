@@ -135,9 +135,13 @@ class EngineSnapshot:
             return out
 
     def _render(self, req: dict) -> dict:
-        out = api.execute(self.rt.cfg, None,
-                          api.QueryOptions.from_json(req),
-                          names=self.rt.names, columns_fn=self.columns)
+        """A result-cache miss: column compute, device→host readback
+        and row assembly (the first reader of a fresh snapshot also
+        waits here for the device to finish the publish copy)."""
+        with self.rt.spans.span("query_render", annotate=True):
+            out = api.execute(self.rt.cfg, None,
+                              api.QueryOptions.from_json(req),
+                              names=self.rt.names, columns_fn=self.columns)
         out["snaptick"] = self.tick
         return out
 

@@ -150,13 +150,16 @@ def test_metrics_parity_single_vs_sharded():
     shard = srt.query({"subsys": "metrics"})["text"]
     srt.close()
 
-    names_1 = {n for n in _parse_exposition(single)
-               if n.startswith("gyt_engine_")
-               or n.startswith("gyt_stage_")}
-    names_n = {n for n in _parse_exposition(shard)
-               if n.startswith("gyt_engine_")
-               or n.startswith("gyt_stage_")}
-    assert names_1 == names_n
+    def surface(text):
+        # engine health gauges + the stage histograms; the single-node
+        # runtime's double-buffered staging slab (gyt_engine_stage_slab_*)
+        # has no twin on the mesh
+        return {n for n in _parse_exposition(text)
+                if (n.startswith("gyt_engine_")
+                    and not n.startswith("gyt_engine_stage_slab_"))
+                or n.startswith("gyt_stage_duration_")}
+
+    assert surface(single) == surface(shard)
     # and the engine gauges carry real readbacks on both
     for text in (single, shard):
         s = _parse_exposition(text)
@@ -228,8 +231,9 @@ def test_runtime_spans_ride_selfstats():
     rt = _fed_runtime()
     ss = rt.query({"subsys": "selfstats"})
     names = {s["name"] for s in ss["spans"]}
-    assert {"deframe", "decode_fold", "tick"} <= names
-    folds = [s for s in ss["spans"] if s["name"] == "decode_fold"]
+    assert {"deframe", "fold_dispatch", "tick"} <= names
+    assert "decode_fold" not in names
+    folds = [s for s in ss["spans"] if s["name"] == "fold_dispatch"]
     assert folds and folds[0]["nrec"] > 0
     assert folds[0]["path"] in ("native", "python")
     # the top renderer consumes the same payload
@@ -260,35 +264,6 @@ def test_format_top_relay_ledger_section():
     ss["counters"]["relay_published_records|relay=rb"] = 110
     m = re.search(r"ledger_open\s+(\S+)", format_top(ss))
     assert m and float(m.group(1)) == 10.0
-
-
-def test_fold_profiler_unset_inert():
-    """Unset GYT_JAX_PROFILE = profiler disarmed, on_fold is a no-op."""
-    from gyeeta_tpu.obs.spans import FoldProfiler
-
-    off = FoldProfiler(env={})
-    off.on_fold()
-    assert not off.armed and off._seen == 0
-
-
-@pytest.mark.slow   # starts a real jax trace bracket (~80s on 1 vCPU);
-                    # the inert-path knob gating stays in the fast tier
-def test_fold_profiler_knob_gated(tmp_path):
-    """GYT_JAX_PROFILE brackets exactly N folds."""
-    from gyeeta_tpu.obs.spans import FoldProfiler
-
-    prof = FoldProfiler(env={"GYT_JAX_PROFILE": str(tmp_path),
-                             "GYT_JAX_PROFILE_FOLDS": "2"})
-    assert prof.armed
-    prof.on_fold()
-    assert prof._active and prof._seen == 1
-    prof.on_fold()
-    assert not prof._active and prof._seen == 2   # stopped at N
-    prof.on_fold()                                # inert afterwards
-    assert prof._seen == 2
-    prof.close()
-    # the trace bracket actually wrote a profile artifact
-    assert any(tmp_path.rglob("*"))
 
 
 # ------------------------------------------- timing quantile regression

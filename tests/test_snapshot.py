@@ -214,10 +214,10 @@ async def _busy_edge_scenario():
     # make snapshot queries slow enough to overlap: wrap the pool call
     inner = srv.qexec._call
 
-    def slow_call(req):
+    def slow_call(*args):
         import time
         time.sleep(0.3)
-        return inner(req)
+        return inner(*args)
 
     srv.qexec._call = slow_call
 
