@@ -217,6 +217,9 @@ class AggState(NamedTuple):
     n_resp: jnp.ndarray
     n_td_overflow: jnp.ndarray        # samples that missed the digest path
     n_resp_unknown: jnp.ndarray       # resp samples for unannounced svcs
+    n_probe: jnp.ndarray              # (2,) int32 — conn/resp probe lanes
+    #                                   sent to stage 2, lookups that
+    #                                   overflowed it (table.lookup_counted)
 
 
 def init(cfg: EngineCfg) -> AggState:
@@ -277,4 +280,5 @@ def init(cfg: EngineCfg) -> AggState:
         n_resp=jnp.zeros((), jnp.float32),
         n_td_overflow=jnp.zeros((), jnp.float32),
         n_resp_unknown=jnp.zeros((), jnp.float32),
+        n_probe=jnp.zeros((2,), jnp.int32),
     )

@@ -66,11 +66,12 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     svc_side = valid & cb.is_accept
     with scope("conn.upsert"):
         if "upsert" in _ABLATE:
-            tbl, rows = st.tbl, table.lookup(st.tbl, cb.svc_hi,
-                                             cb.svc_lo, svc_side)
+            tbl = st.tbl
+            rows, probe = table.lookup_counted(st.tbl, cb.svc_hi,
+                                               cb.svc_lo, svc_side)
             any_new = jnp.any(svc_side & (rows < 0))
         else:
-            tbl, rows, any_new = table.upsert_fast2(
+            tbl, rows, any_new, probe = table.upsert_fast2(
                 st.tbl, cb.svc_hi, cb.svc_lo, svc_side)
     ok = svc_side & (rows >= 0)
     rowz = jnp.where(ok, rows, 0)
@@ -195,6 +196,7 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
         tbl=tbl, ctr_win=ctr_win, svc_host=svc_host, svc_hll=svc_hll,
         glob_hll=glob_hll, cms=cms, flow_topk=flow_topk, inv=inv,
         n_conn=st.n_conn + jnp.sum(valid).astype(jnp.float32),
+        n_probe=st.n_probe + probe,
     )
 
 
@@ -268,7 +270,8 @@ def ingest_resp_flat(cfg: EngineCfg, st: AggState, flat) -> AggState:
     scope = jax.named_scope
     valid = flat.valid
     with scope("resp.lookup"):
-        rows = table.lookup(st.tbl, flat.svc_hi, flat.svc_lo, valid)
+        rows, probe = table.lookup_counted(st.tbl, flat.svc_hi,
+                                           flat.svc_lo, valid)
     ok = valid & (rows >= 0)
     n_unknown = jnp.sum(valid & (rows < 0)).astype(jnp.float32)
     rowz = jnp.where(ok, rows, 0)
@@ -299,6 +302,7 @@ def ingest_resp_flat(cfg: EngineCfg, st: AggState, flat) -> AggState:
         n_resp=st.n_resp + jnp.sum(valid).astype(jnp.float32),
         n_resp_unknown=st.n_resp_unknown + n_unknown,
         n_td_overflow=st.n_td_overflow + n_over.astype(jnp.float32),
+        n_probe=st.n_probe + probe,
     )
 
 
@@ -487,7 +491,7 @@ def ingest_delta(cfg: EngineCfg, st: AggState, dep, db, tick):
 
     S = cfg.svc_capacity
     # ---- ONE upsert over the unique svc keys of the whole dispatch
-    tbl, urows, any_new = table.upsert_fast2(
+    tbl, urows, any_new, _ = table.upsert_fast2(
         st.tbl, db.svc_hi, db.svc_lo, db.svc_valid)
     ok_u = db.svc_valid & (urows >= 0)
     lanes_u = jnp.where(ok_u, urows, S)
@@ -680,6 +684,9 @@ HEALTH_KEYS = (
     # ever dropped — the per-key error bar every flow row reports),
     # invertible-bucket fill, and hot-admission lane count
     "topk_evicted", "hh_occupied", "hh_hot_lanes",
+    # the staged table probe (engine/table.py:lookup_counted) of the
+    # slab fold: lanes that needed stage 2, lookups that overflowed it
+    "probe_residue", "probe_fallbacks",
 )
 
 
@@ -712,7 +719,12 @@ def engine_health_vec(cfg: EngineCfg, st: AggState, dep) -> jnp.ndarray:
         s(dep.n_paired), s(dep.n_expired), s(dep.n_dropped),
         s(st.flow_topk.evicted), s(st.inv.prio > 0), s(st.inv.n_hot),
     )
-    return jnp.stack(vals)
+    # int32 on the device (a float32 counter stops counting past 2^24);
+    # read as uint32 so the gauges wrap at 2^32, not at 2^31
+    probe = jnp.sum((st.n_probe + dep.n_probe).reshape(-1, 2), axis=0)
+    return jnp.concatenate(
+        [jnp.stack(vals), jax.lax.bitcast_convert_type(
+            probe, jnp.uint32).astype(jnp.float32)])
 
 
 def heavy_recover(cfg: EngineCfg, st: AggState) -> dict:

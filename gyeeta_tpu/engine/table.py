@@ -40,17 +40,33 @@ EMPTY = np.uint32(0xFFFFFFFF)
 TOMB = np.uint32(0xFFFFFFFE)
 
 PROBES = 16  # unrolled double-hash probe rounds
+P1 = 4                    # stage-1 slots, probed for every lane
+RESIDUE_DIV = PROBES      # stage 2 holds B // RESIDUE_DIV lanes
+STAGED_MIN_LANES = 1024   # smaller batches stay single-stage: on a v5e the
+#                           staged probe already wins at 256 lanes (30 µs
+#                           against 64), but a probe that small is under
+#                           0.25 ms a dispatch and each staged lookup is
+#                           one more two-branch cond to compile
 # Load guidance: a key whose probe positions are ALL occupied can never
 # insert — it drops on every retry and permanently defeats the
 # ``upsert_fast`` all-hit fast path (one such key forces the 16-round
 # insert machinery on every dispatch). The permanent-failure odds are
 # ~load^PROBES per key: at 8 probes, 0.5^8 ≈ 0.4% of keys at 50% load
 # (observed in the bench: a stuck key cost ~2.5ms/µbatch forever);
-# at 16 probes it is 0.0015% at 50% and 0.3% at 70%. The lookup cost
-# is one (B, PROBES) gather — doubling probes costs ~1.5% of the fold,
-# the cheapest insurance available. Size slabs for ≤70% steady-state
-# occupancy; drops are counted in ``n_drop`` and re-sent keys retry
-# next sweep.
+# at 16 probes it is 0.0015% at 50% and 0.3% at 70%. Size slabs for
+# ≤70% steady-state occupancy; drops are counted in ``n_drop`` and
+# re-sent keys retry next sweep.
+#
+# What the 16 probes cost is gathered words: 5.7–8.5 ns a word on a v5e,
+# whatever the batch, and a 16-slot probe of both key halves is 32 words
+# a lane — 15.9 ms per 65,536 lanes, 74 % of the slab fold (PERF.md §5–6,
+# PR 25–26). Almost none of them find anything: replaying the insert path
+# (4,096-key batches), the share of keys that sit past probe slot
+# 0 / 1 / 3 / 7 is 24.9 / 8.2 / 1.24 / 0.02 % at 50 % load, 0.07 % past
+# slot 3 at 25 % load, 4.7 % at the 70 % sizing load. So ``lookup`` is
+# staged (:func:`lookup_counted`): ``P1`` slots for every lane, the other
+# slots for the residue only, 9.5 words a lane; B // 16 lanes hold the
+# 70 % load's 4.7 %. ``upsert``'s insert rounds keep the full width.
 
 
 class Table(NamedTuple):
@@ -164,25 +180,24 @@ def upsert_fast(tbl: Table, khi, klo, valid=None):
     working set is resident — the moral equivalent of the reference's
     RCU read-mostly fast path vs its insert slow path
     (``gy_rcu_inc.h:1664``)."""
-    khi = khi.astype(jnp.uint32)
-    klo = klo.astype(jnp.uint32)
-    if valid is None:
-        valid = jnp.ones((khi.shape[0],), bool)
-    tbl, rows, _ = upsert_fast2(tbl, khi, klo, valid)
+    tbl, rows, _, _ = upsert_fast2(tbl, khi, klo, valid)
     return tbl, rows
 
 
 def upsert_fast2(tbl: Table, khi, klo, valid=None):
-    """:func:`upsert_fast` that also returns the ``any_miss`` () bool —
-    True when this batch carried at least one key that was not already
-    resolvable (i.e. the insert machinery ran). Callers use it to
-    cond-skip work that only matters for NEW rows (e.g. the dep-graph
-    edge identity columns, which existing rows already hold)."""
+    """:func:`upsert_fast` → ``(table, rows, any_miss, probe)``.
+
+    ``any_miss`` () bool — True when this batch carried at least one key
+    that was not already resolvable (i.e. the insert machinery ran).
+    Callers use it to cond-skip work that only matters for NEW rows
+    (e.g. the dep-graph edge identity columns, which existing rows
+    already hold). ``probe`` — the (2,) int32 counts of the all-hit
+    probe (:func:`lookup_counted`)."""
     khi = khi.astype(jnp.uint32)
     klo = klo.astype(jnp.uint32)
     if valid is None:
         valid = jnp.ones((khi.shape[0],), bool)
-    rows0 = lookup(tbl, khi, klo, valid)
+    rows0, probe = lookup_counted(tbl, khi, klo, valid)
     any_miss = jnp.any(valid & (rows0 < 0)
                        & ~_is_empty(khi, klo) & ~_is_tomb(khi, klo))
     tbl, rows = jax.lax.cond(
@@ -190,31 +205,89 @@ def upsert_fast2(tbl: Table, khi, klo, valid=None):
         lambda t: upsert(t, khi, klo, valid),
         lambda t: (t, rows0),
         tbl)
-    return tbl, rows, any_miss
+    return tbl, rows, any_miss, probe
 
 
-def lookup(tbl: Table, khi, klo, valid=None):
-    """Find rows for a batch of keys without inserting. -1 = absent.
+def _first_match(tbl: Table, khi, klo, slots):
+    """→ (B,) int32: per lane the FIRST of its ``slots`` (B, P) that
+    holds its key, else -1. The two key-half gathers share one index
+    array; the pick is a masked max, not a third gather (a
+    ``slots[lane, argmax]`` pick cost 0.7 ms per 65,536 lanes on a v5e
+    beside 4.0 ms of key gathers)."""
+    m = (tbl.key_hi[slots] == khi[:, None]) \
+        & (tbl.key_lo[slots] == klo[:, None])
+    first = m & (jnp.cumsum(m, axis=1) == 1)
+    return jnp.max(jnp.where(first, slots, -1), axis=1)
 
-    The two (B, PROBES) key-half gathers share one index array, so XLA
-    fuses them into a single gather loop — a measured attempt to halve
-    them via a derived-fingerprint probe (one fp gather + per-lane
-    verify) was NOT faster on CPU and cost an extra ~2.5 ms per 65k
-    lanes in verify/cond overhead. Don't re-split this."""
+
+def _lookup_full(tbl: Table, khi, klo, valid):
+    """The single-stage probe: all ``PROBES`` slots of every lane."""
+    rows = _first_match(
+        tbl, khi, klo, _probe_slots(khi, klo, tbl.key_hi.shape[0]))
+    return jnp.where(valid, rows, -1)
+
+
+def _compact(mask, size: int):
+    """Lane ids of the first ``size`` set lanes of ``mask`` (B,),
+    ascending, padded with B. One sort: 0.2 ms per 65,536 lanes on a
+    v5e, where ``jnp.nonzero(size=)`` (a B-update scatter-add) took
+    0.6 ms."""
+    B = mask.shape[0]
+    lane = jnp.arange(B, dtype=jnp.int32)
+    return jax.lax.sort(jnp.where(mask, lane, B))[:size]
+
+
+def lookup_counted(tbl: Table, khi, klo, valid=None):
+    """:func:`lookup` → ``(rows, probe)``; ``probe`` is (2,) int32: the
+    lanes stage 1 left open, and 1 when they overflowed the residue
+    (0 / 0 for a single-stage batch).
+
+    A two-stage probe. A key sits at the first of its ``PROBES`` slots
+    that was free when it was inserted, so stage 1 looks at the first
+    ``P1`` slots of every lane and stage 2 at the other slots of the
+    lanes still open, compacted into ``B // RESIDUE_DIV`` lanes. More
+    open lanes than that (a burst of unknown keys, a slab far above its
+    sizing load) take the other slots at full width instead. Either way
+    the rows are those of :func:`_lookup_full`: the first match among
+    the first ``P1`` slots, else the first among the rest.
+    """
     capacity = tbl.key_hi.shape[0]
     khi = khi.astype(jnp.uint32)
     klo = klo.astype(jnp.uint32)
     B = khi.shape[0]
     if valid is None:
         valid = jnp.ones((B,), bool)
+    if B < STAGED_MIN_LANES:
+        return (_lookup_full(tbl, khi, klo, valid),
+                jnp.zeros((2,), jnp.int32))
+    R = B // RESIDUE_DIV
     slots = _probe_slots(khi, klo, capacity)
-    cur_hi = tbl.key_hi[slots]
-    cur_lo = tbl.key_lo[slots]
-    m = (cur_hi == khi[:, None]) & (cur_lo == klo[:, None])
-    pos = jnp.argmax(m, axis=1)
-    found = jnp.any(m, axis=1) & valid
-    rows = slots[jnp.arange(B), pos]
-    return jnp.where(found, rows, -1)
+    rows1 = jnp.where(valid, _first_match(tbl, khi, klo, slots[:, :P1]), -1)
+    open_ = valid & (rows1 < 0)
+    n_open = jnp.sum(open_).astype(jnp.int32)
+
+    def residue(_):
+        idx = _compact(open_, R)
+        lane = jnp.minimum(idx, B - 1)      # padding probes a lane twice
+        rhi, rlo = khi[lane], klo[lane]
+        rows2 = _first_match(
+            tbl, rhi, rlo, _probe_slots(rhi, rlo, capacity)[:, P1:])
+        return rows1.at[idx].set(rows2, mode="drop")
+
+    def full(_):
+        rows2 = _first_match(tbl, khi, klo, slots[:, P1:])
+        return jnp.where(open_, rows2, rows1)
+
+    # the branches close over the key columns read-only and yield (B,)
+    # rows: nothing slab-sized is carried through the cond
+    over = n_open > R
+    rows = jax.lax.cond(over, full, residue, None)
+    return rows, jnp.stack([n_open, over.astype(jnp.int32)])
+
+
+def lookup(tbl: Table, khi, klo, valid=None):
+    """Find rows for a batch of keys without inserting. -1 = absent."""
+    return lookup_counted(tbl, khi, klo, valid)[0]
 
 
 def delete(tbl: Table, khi, klo, valid=None):

@@ -80,6 +80,9 @@ def gauges_from_vec(vec, caps: dict) -> dict:
         "engine_hh_occupancy_ratio": round(
             h["hh_occupied"] / max(caps["hh"], 1), 4),
         "engine_hh_hot_lanes": h["hh_hot_lanes"],
+        # the slab fold's staged table probes (engine/table.py)
+        "engine_probe_residue_lanes": h["probe_residue"],
+        "engine_probe_fallbacks": h["probe_fallbacks"],
     }
 
 

@@ -82,6 +82,8 @@ class DepGraph(NamedTuple):
     n_paired: jnp.ndarray    # () f32 — halves joined into an edge
     n_expired: jnp.ndarray   # () f32 — halves evicted unpaired (TTL)
     n_dropped: jnp.ndarray   # () f32 — dispatch/table overflow drops
+    n_probe: jnp.ndarray     # (2,) i32 — edge-probe lanes sent to stage 2,
+    #                          lookups that overflowed it
 
     @property
     def e_nconn(self):
@@ -115,6 +117,7 @@ def init(pair_capacity: int = 4096, edge_capacity: int = 2048) -> DepGraph:
         n_paired=jnp.zeros((), jnp.float32),
         n_expired=jnp.zeros((), jnp.float32),
         n_dropped=jnp.zeros((), jnp.float32),
+        n_probe=jnp.zeros((2,), jnp.int32),
     )
 
 
@@ -138,8 +141,8 @@ def fold_edges(dep: DepGraph, cli_hi, cli_lo, cli_svc, ser_hi, ser_lo,
     path). Edge-folding agents ship PRE-AGGREGATED edges, so a lane may
     represent many flows (``engine/step.py:ingest_delta``)."""
     khi, klo = edge_key(cli_hi, cli_lo, ser_hi, ser_lo)
-    tbl, rows, any_new = table.upsert_fast2(dep.edge_tbl, khi, klo,
-                                            valid=valid)
+    tbl, rows, any_new, probe = table.upsert_fast2(dep.edge_tbl, khi, klo,
+                                                   valid=valid)
     ok = valid & (rows >= 0)
     E = dep.e_nconn.shape[0]
     lanes = jnp.where(ok, rows, E)
@@ -177,6 +180,7 @@ def fold_edges(dep: DepGraph, cli_hi, cli_lo, cli_svc, ser_hi, ser_lo,
         e_last_tick=set_(dep.e_last_tick, jnp.int32(tick)),
         n_dropped=dep.n_dropped
         + jnp.sum(valid & (rows < 0)).astype(jnp.float32),
+        n_probe=dep.n_probe + probe,
     )
 
 

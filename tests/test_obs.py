@@ -185,6 +185,33 @@ def test_engine_health_single_batched_readback():
     rt.close()
 
 
+def test_probe_gauges_in_health_and_selfstats():
+    """The staged table probe's two device counters ride the one health
+    readback into ``engine_health()`` and ``selfstats``. The first slabs
+    carry keys no table holds yet (every edge is new) and overflow into
+    the full-width path; the same traffic again is all-hit: a small
+    residue and no further fallback."""
+    cfg = CFG._replace(conn_batch=1024, resp_batch=2048)
+    rt = Runtime(cfg)
+    sim = ParthaSim(n_hosts=8, n_svcs=2, seed=3)
+    frames = sim.conn_frames(4096) + sim.resp_frames(8192)
+    rt.feed(sim.listener_frames() + frames)
+    rt.run_tick()
+    g0 = rt.engine_health()
+    assert g0["engine_probe_fallbacks"] >= 1
+    rt.feed(frames)
+    rt.run_tick()
+    g = rt.engine_health()
+    assert g["engine_probe_fallbacks"] == g0["engine_probe_fallbacks"]
+    lanes = g["engine_probe_residue_lanes"] - g0["engine_probe_residue_lanes"]
+    assert 0 <= lanes < 0.1 * (4096 + 8192)
+    counters = rt.query({"subsys": "selfstats"})["counters"]
+    for k in ("engine_probe_residue_lanes", "engine_probe_fallbacks"):
+        assert counters[k] == g[k]
+    assert str(rt.state.n_probe.dtype) == "int32"
+    rt.close()
+
+
 def test_probe_failures_surface_in_health():
     """Overflowing a tiny svc slab shows up as probe failures +
     occupancy ~1.0 (the PSketch silent-saturation lesson)."""

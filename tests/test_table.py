@@ -140,3 +140,85 @@ def test_churn_storm(rng, jitted):
                                           jnp.asarray(sl)))
             assert (got >= 0).all()
     assert int(tbl.n_live) == len(live)
+
+
+# ----------------------------------------------------------- staged probe
+def _loaded(rng, cap, load, batch=1024):
+    """A slab filled to ``load`` through the insert path, with a few of
+    its keys tombstoned → (tbl, live keys, tombstoned keys)."""
+    n = int(cap * load)
+    khi, klo = keys_of(rng, n)
+    tbl = table.init(cap)
+    up = jax.jit(table.upsert)
+    for i in range(0, n, batch):
+        tbl, _ = up(tbl, jnp.asarray(khi[i:i + batch]),
+                    jnp.asarray(klo[i:i + batch]))
+    dead = slice(0, 16)
+    tbl, _ = table.delete(tbl, jnp.asarray(khi[dead]), jnp.asarray(klo[dead]))
+    return tbl, (khi[16:], klo[16:]), (khi[dead], klo[dead])
+
+
+def _probe_lanes(rng, live, dead, B, n_absent):
+    """B lanes: live keys drawn WITH repeats, then absent keys, the
+    tombstoned keys, both sentinel keys, and invalid lanes."""
+    pick = rng.integers(0, len(live[0]), B)
+    khi, klo = live[0][pick].copy(), live[1][pick].copy()
+    valid = np.ones(B, bool)
+    a = slice(0, n_absent)
+    khi[a], klo[a] = keys_of(rng, n_absent, lo=2**31, hi=2**32 - 8)
+    d = slice(n_absent, n_absent + 16)
+    khi[d], klo[d] = dead
+    khi[-4:-2], klo[-4:-2] = table.EMPTY, table.EMPTY
+    khi[-2:], klo[-2:] = table.TOMB, table.TOMB
+    valid[rng.integers(0, B, B // 8)] = False
+    return jnp.asarray(khi), jnp.asarray(klo), jnp.asarray(valid)
+
+
+@pytest.mark.parametrize("B", [256, 4096])
+@pytest.mark.parametrize("jit", [True, False])
+@pytest.mark.parametrize("load", [0.25, 0.5, 0.7, 0.85])
+def test_staged_lookup_matches_full_probe(rng, load, jit, B):
+    """``lookup`` (4 slots for every lane, 12 for the residue, or the
+    overflow's full width) returns exactly the 16-slot probe's rows."""
+    assert 256 < table.STAGED_MIN_LANES <= 4096
+    tbl, live, dead = _loaded(rng, 4096, load)
+    khi, klo, valid = _probe_lanes(rng, live, dead, B, n_absent=B // 64)
+    staged, full = table.lookup, table._lookup_full
+    if jit:
+        staged, full = jax.jit(staged), jax.jit(full)
+    want = np.asarray(full(tbl, khi, klo, valid))
+    assert np.array_equal(np.asarray(staged(tbl, khi, klo, valid)), want)
+    v = np.asarray(valid)
+    assert (want[~v] == -1).all() and (want[v] >= 0).sum() > B // 2
+    # absent and tombstoned keys miss
+    assert (want[:B // 64 + 16] == -1).all()
+
+
+def test_staged_lookup_overflow_counted(rng):
+    """More absent keys than the residue holds: the overflow branch
+    answers (same rows) and is counted."""
+    B = 4096
+    tbl, live, dead = _loaded(rng, 4096, 0.5)
+    khi, klo, valid = _probe_lanes(rng, live, dead, B,
+                                   n_absent=B // table.RESIDUE_DIV + 64)
+    rows, probe = jax.jit(table.lookup_counted)(tbl, khi, klo, valid)
+    assert np.array_equal(np.asarray(rows), np.asarray(
+        table._lookup_full(tbl, khi, klo, valid)))
+    probe = np.asarray(probe)
+    assert probe[1] == 1 and probe[0] > B // table.RESIDUE_DIV
+
+
+def test_staged_lookup_counters_at_half_load(rng):
+    """All-hit lanes at the service slab's 50 % load: about 1.2 % of
+    them need stage 2 and none overflows; a small batch counts nothing."""
+    B = 8192
+    tbl, live, _ = _loaded(rng, 16384, 0.5, batch=4096)
+    pick = rng.integers(0, len(live[0]), B)
+    khi, klo = jnp.asarray(live[0][pick]), jnp.asarray(live[1][pick])
+    rows, probe = jax.jit(table.lookup_counted)(tbl, khi, klo)
+    assert (np.asarray(rows) >= 0).all()
+    probe = np.asarray(probe)
+    assert probe[1] == 0
+    assert 0.005 * B <= probe[0] <= 0.025 * B
+    _, small = table.lookup_counted(tbl, khi[:256], klo[:256])
+    assert np.asarray(small).tolist() == [0, 0]
