@@ -24,7 +24,7 @@ from typing import NamedTuple
 
 import numpy as np
 
-from gyeeta_tpu.ingest import native, wire
+from gyeeta_tpu.ingest import native, pack, wire
 from gyeeta_tpu.utils import hashing as H
 
 _log = logging.getLogger("gyeeta_tpu.ingest")
@@ -601,20 +601,33 @@ def conn_batch(recs: np.ndarray, size: int = wire.MAX_CONNS_PER_BATCH
     )
 
 
+# column dtypes of the conn/resp slab, as the device fold consumes them
+_CONN_DTYPES = ConnBatch(*[np.uint32] * 10, *[np.float32] * 3, np.int32,
+                         *[np.bool_] * 3)
+_RESP_DTYPES = RespBatch(np.uint32, np.uint32, np.float32, np.int32,
+                         np.bool_)
+
+
+def alloc_slab_cols(nconn: int, nresp: int) -> tuple[np.ndarray, dict, dict]:
+    """One zeroed word block (``ingest/pack.py``) holding every
+    ConnBatch column of ``nconn`` lanes, then every RespBatch column of
+    ``nresp`` lanes, and the two ``{field: view}`` dicts the columnar
+    decoders write into — a slab decoded through them is already packed
+    for its host→device transfer, in the order a ``(ConnBatch,
+    RespBatch)`` pair flattens."""
+    block, views = pack.alloc(
+        tuple((np.dtype(dt), (nconn,)) for dt in _CONN_DTYPES)
+        + tuple((np.dtype(dt), (nresp,)) for dt in _RESP_DTYPES))
+    nc = len(ConnBatch._fields)
+    return (block, dict(zip(ConnBatch._fields, views[:nc])),
+            dict(zip(RespBatch._fields, views[nc:])))
+
+
 def alloc_conn_cols(size: int) -> dict:
-    """Zeroed flat ConnBatch columns (everything but ``valid``) in the
-    exact dtypes the device fold consumes — the preallocated buffers
-    the native wire→columnar decoders write into."""
-    u32 = lambda: np.zeros(size, np.uint32)     # noqa: E731
-    f32 = lambda: np.zeros(size, np.float32)    # noqa: E731
-    return dict(
-        svc_hi=u32(), svc_lo=u32(), flow_hi=u32(), flow_lo=u32(),
-        cli_hi=u32(), cli_lo=u32(), cli_task_hi=u32(),
-        cli_task_lo=u32(), cli_rel_hi=u32(), cli_rel_lo=u32(),
-        bytes_sent=f32(), bytes_rcvd=f32(), duration_us=f32(),
-        host_id=np.zeros(size, np.int32),
-        is_close=np.zeros(size, bool),
-        is_accept=np.zeros(size, bool))
+    """Zeroed flat ConnBatch columns in the exact dtypes the device
+    fold consumes (views of one block) — the preallocated buffers the
+    native wire→columnar decoders write into."""
+    return alloc_slab_cols(size, 0)[1]
 
 
 def _concat_chunks(chunks: list, dtype) -> np.ndarray:
@@ -624,12 +637,9 @@ def _concat_chunks(chunks: list, dtype) -> np.ndarray:
 
 
 def alloc_resp_cols(size: int) -> dict:
-    """Zeroed flat RespBatch columns (everything but ``valid``) — the
-    resp half of the preallocated staging-slab buffers."""
-    return dict(svc_hi=np.zeros(size, np.uint32),
-                svc_lo=np.zeros(size, np.uint32),
-                resp_us=np.zeros(size, np.float32),
-                host_id=np.zeros(size, np.int32))
+    """Zeroed flat RespBatch columns (views of one block) — the resp
+    half of the preallocated staging-slab buffers."""
+    return alloc_slab_cols(0, size)[2]
 
 
 def _reuse_cols(cols: dict, n: int, clear_to: int) -> None:
@@ -671,10 +681,9 @@ def conn_batch_parts(chunks: list, size: int, stats=None, out=None,
                     break
                 off += len(c)
         if ok:
-            valid = np.zeros(size, bool)
-            valid[:n] = True
+            cols["valid"][:n] = True
             _count_path(stats, True, n)
-            return ConnBatch(valid=valid, **cols)
+            return ConnBatch(**cols)
     _count_path(stats, False, n)
     return conn_batch(_concat_chunks(chunks, wire.TCP_CONN_DT), size)
 
@@ -692,24 +701,20 @@ def resp_batch_parts(chunks: list, size: int, stats=None, out=None,
         cols = out if out is not None else alloc_resp_cols(size)
         if out is not None:
             _reuse_cols(cols, n, clear_to)
-        svc_hi, svc_lo = cols["svc_hi"], cols["svc_lo"]
-        resp_us, host_id = cols["resp_us"], cols["host_id"]
         off = 0
         ok = True
         for c in chunks:
             if len(c):
-                if not native.decode_resp_into(c, svc_hi, svc_lo,
-                                               resp_us, host_id, off):
+                if not native.decode_resp_into(
+                        c, cols["svc_hi"], cols["svc_lo"],
+                        cols["resp_us"], cols["host_id"], off):
                     ok = False       # library vanished mid-batch
                     break
                 off += len(c)
         if ok:
-            valid = np.zeros(size, bool)
-            valid[:n] = True
+            cols["valid"][:n] = True
             _count_path(stats, True, n)
-            return RespBatch(svc_hi=svc_hi, svc_lo=svc_lo,
-                             resp_us=resp_us, host_id=host_id,
-                             valid=valid)
+            return RespBatch(**cols)
     _count_path(stats, False, n)
     return resp_batch(_concat_chunks(chunks, wire.RESP_SAMPLE_DT), size)
 

@@ -236,8 +236,8 @@ def decode_path() -> str:
 # ------------------------------------------------------ columnar kernels
 def decode_conn_into(recs: np.ndarray, cols: dict, off: int = 0) -> bool:
     """Decode TCP_CONN records into flat column arrays at lane ``off``
-    (cols: the 16 non-valid ConnBatch columns, each contiguous and of
-    length >= off+len(recs)). Returns False when the native library is
+    (cols: the ConnBatch columns by field name, each contiguous and of
+    length >= off+len(recs); ``valid`` is the caller's to set). Returns False when the native library is
     unavailable — callers fall back to decode.conn_batch."""
     lib = _load()
     if lib is None:
@@ -336,9 +336,8 @@ def decode_conn(recs, size: int):
                          f" split upstream")
     cols = D.alloc_conn_cols(size)
     decode_conn_into(recs, cols, 0)
-    valid = np.zeros(size, bool)
-    valid[:len(recs)] = True
-    return D.ConnBatch(valid=valid, **cols)
+    cols["valid"][:len(recs)] = True
+    return D.ConnBatch(**cols)
 
 
 def drain(buf: bytes) -> tuple[dict, int]:

@@ -292,6 +292,36 @@ class QueryExecutor:
         finally:
             clock.t_done = time.perf_counter()
 
+    def prewarm(self, prev) -> int:
+        """A tick just replaced snapshot ``prev``: render the requests
+        it answered from its cache (asked again within its life — a
+        dashboard's refresh) into the NEW snapshot's cache, on the
+        workers, before their next ask. Without it the first ask of
+        each request after every tick is a render, and every client
+        refreshing the same view waits behind it. The renders are the
+        ones those asks would have paid; a request nobody repeats costs
+        one render more, once. They start with the tick's end, beside
+        the loop's catch-up: the first answer from the new tick comes
+        later for it (PERF.md §6, PR 30). → jobs handed to the pool."""
+        snap = getattr(self.rt, "snapshot", None)
+        if prev is None or snap is None or snap is prev:
+            return 0
+        reqs = prev.repeated()
+        for req in reqs:
+            self._pool.submit(self._warm, snap, req)
+        return len(reqs)
+
+    def _warm(self, snap, req: dict) -> None:
+        """On a worker thread: one render-ahead, a request of the
+        server's own (its ``query_render`` rides this span's id)."""
+        try:
+            with self.spans.request(self.spans.next_req()), \
+                    self.spans.span("query_prewarm"):
+                snap.warm(req)
+        except Exception:                   # noqa: BLE001 — nobody waits
+            # the live ask of the same request will raise to its client
+            self.rt.stats.bump("query_cache_prewarm_errors")
+
     def close(self) -> None:
         for _req, fut, _clock in self._pending:
             if not fut.done():

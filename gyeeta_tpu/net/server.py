@@ -558,7 +558,11 @@ class GytServer:
             await asyncio.sleep(self.tick_interval)
             try:
                 self._feed_barrier()
+                prev = getattr(self.rt, "snapshot", None)
                 self.rt.run_tick()
+                # the fresh snapshot renders ahead what the replaced
+                # one kept answering (off-loop, on the query workers)
+                self.qexec.prewarm(prev)
                 # what still holds the loop once the tick has returned
                 with self.rt.spans.span("tick_push", annotate=True):
                     if self._ingest is not None:

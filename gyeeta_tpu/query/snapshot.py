@@ -87,6 +87,7 @@ class EngineSnapshot:
         self._cols = ColumnCache()
         self._results: collections.OrderedDict = collections.OrderedDict()
         self._results_max = int(result_cache_max)
+        self._again: dict = {}      # key → request, noted on a cache hit
         self._lock = threading.Lock()
         # single-flight: per-request and per-subsystem compute locks so
         # a dashboard stampede onto a FRESH snapshot collapses N
@@ -115,24 +116,63 @@ class EngineSnapshot:
         if self._results_max <= 0:
             stats.bump("query_cache_misses")
             return self._render(req)
-        with self._lock:
-            hit = self._results.get(key)
+        hit = self._cached(key, req)
         if hit is not None:
             stats.bump("query_cache_hits")
             return hit
         with self._flight_lock(("r", key)):
-            with self._lock:              # the holder may have stored
-                hit = self._results.get(key)
+            hit = self._cached(key, req)   # the holder may have stored
             if hit is not None:
                 stats.bump("query_cache_hits")
                 return hit
             stats.bump("query_cache_misses")
-            out = self._render(req)
+            return self._fill(key, req)
+
+    def _cached(self, key, req: dict):
+        """The cached answer, or None. A hit also notes the request as
+        REPEATED: asked again within one snapshot's life, so worth
+        rendering ahead on the next (:meth:`repeated`)."""
+        with self._lock:
+            hit = self._results.get(key)
+            if hit is not None:
+                self._again.setdefault(key, req)
+        return hit
+
+    def _fill(self, key, req: dict) -> dict:
+        out = self._render(req)
+        with self._lock:
+            self._results[key] = out
+            while len(self._results) > self._results_max:
+                self._results.popitem(last=False)
+        return out
+
+    # a dashboard fleet repeats a handful of requests; the bound keeps
+    # one tick's render-ahead work small whatever else hit the cache
+    PREWARM_MAX = 16
+
+    def repeated(self) -> list:
+        """The requests this snapshot answered from its cache at least
+        once, soonest-repeated first, at most ``PREWARM_MAX``. A request
+        asked once (a one-off, however slow) is never among them."""
+        with self._lock:
+            return list(self._again.values())[:self.PREWARM_MAX]
+
+    def warm(self, req: dict) -> None:
+        """Render ``req`` into the result cache ahead of its first ask
+        (the serving edge hands a fresh snapshot its predecessor's
+        :meth:`repeated` requests, ``net/qexec.py:prewarm``): a
+        dashboard's first refresh after a tick is then a hit instead of
+        a render. Single-flights with live asks of the same request;
+        counted apart from misses (``query_cache_prewarms``) and never
+        noted as a repeat, so a request nobody asks any more is rendered
+        ahead once and then dropped."""
+        key = request_key(req)
+        with self._flight_lock(("r", key)):
             with self._lock:
-                self._results[key] = out
-                while len(self._results) > self._results_max:
-                    self._results.popitem(last=False)
-            return out
+                if key in self._results:
+                    return
+            self.rt.stats.bump("query_cache_prewarms")
+            self._fill(key, req)
 
     def _render(self, req: dict) -> dict:
         """A result-cache miss: column compute, device→host readback

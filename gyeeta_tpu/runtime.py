@@ -29,7 +29,7 @@ from gyeeta_tpu.obs import health as obs_health
 from gyeeta_tpu.obs import xlamon
 from gyeeta_tpu.obs.spans import SpanTracer
 from gyeeta_tpu.parallel import depgraph as dg
-from gyeeta_tpu.ingest import decode, native, wire
+from gyeeta_tpu.ingest import decode, native, pack, wire
 from gyeeta_tpu.query import api
 from gyeeta_tpu.semantic import derive
 from gyeeta_tpu.utils import checkpoint as ckpt
@@ -425,13 +425,17 @@ class Runtime:
         # double-buffered conn/resp decode slabs: the idle buffer is
         # decoded into while the in-flight fold still owns (device
         # copies of) the other — host decode of batch N+1 overlaps
-        # device fold of batch N (async dispatch + buffer flip)
+        # device fold of batch N (async dispatch + buffer flip). Each
+        # buffer's columns are views of ONE word block, and the block
+        # is what crosses to the device
         K = self.cfg.fold_k
-        self._slab_bufs = [
-            {"conn": decode.alloc_conn_cols(K * self.cfg.conn_batch),
-             "resp": decode.alloc_resp_cols(K * self.cfg.resp_batch),
-             "hw_conn": 0, "hw_resp": 0, "consumer": None}
-            for _ in range(2)]
+        self._slab_bufs = []
+        for _ in range(2):
+            block, conn, resp = decode.alloc_slab_cols(
+                K * self.cfg.conn_batch, K * self.cfg.resp_batch)
+            self._slab_bufs.append(
+                {"block": block, "conn": conn, "resp": resp,
+                 "hw_conn": 0, "hw_resp": 0, "consumer": None})
         self._slab_active = 0
         # fold_all jit cache: one compiled variant per section-presence
         # combination (hot path = connresp-only; a 5s sweep batch adds
@@ -739,13 +743,21 @@ class Runtime:
             cfg = self.cfg
 
             def make():
-                def fn(st, dep, tick, *secs, _names=names):
+                # the sections arrive as one packed word block
+                # (ingest/pack.py); ``layout`` = (treedef, leaf dtypes
+                # and shapes) is static, and the unpack is the fold's
+                # first few ops
+                def fn(st, dep, tick, block, layout, _names=names):
+                    treedef, leaves = layout
+                    secs = jax.tree.unflatten(
+                        treedef, pack.unpack(block, leaves))
                     return step.fold_all(cfg, st, dep, tick,
                                          **dict(zip(_names, secs)))
                 # names the compiled module: a device trace tells the
                 # slab fold from the section-only folds
                 fn.__name__ = fold_all_name(names)
-                return jax.jit(fn, donate_argnums=(0, 1))
+                return jax.jit(fn, donate_argnums=(0, 1),
+                               static_argnums=(4,))
 
             jitted = _memo_jit(("fold_all", cfg, names), make)
             self._fold_all_jits[names] = jitted
@@ -831,14 +843,23 @@ class Runtime:
         names = tuple(k for k in step.FOLD_ALL_ORDER if k in sections)
         with self.spans.span("fold_dispatch", nrec=nrec,
                              path=native.decode_path()):
-            # the staged (idle-buffer) columns transfer while the
+            # the staged (idle-buffer) block transfers while the
             # previous fold may still be in flight; the jit call below
-            # never blocks on it (async dispatch)
+            # never blocks on it (async dispatch). ONE array whatever
+            # sections ride: a slab decoded in place IS its block, the
+            # rest is one small host copy into a fresh one
             with self.spans.span("fold_h2d", nrec=nrec, annotate=True):
-                secs = jax.device_put(tuple(sections[k] for k in names))
+                leaves, treedef = jax.tree.flatten(
+                    tuple(sections[k] for k in names))
+                block = pack.pack(
+                    leaves, into=buf["block"] if buf else None)
+                self.stats.bump("h2d_arrays")
+                self.stats.bump("h2d_bytes", block.nbytes)
+                block = jax.device_put(block)
             with self.spans.span("fold_enqueue", nrec=nrec, annotate=True):
                 self.state, self.dep, pressure = self._get_fold_all(names)(
-                    self.state, self.dep, np.int32(self._tick_no), *secs)
+                    self.state, self.dep, np.int32(self._tick_no), block,
+                    (treedef, pack.layout_of(leaves)))
         self._pressures.append(pressure)
         if buf is not None:
             buf["consumer"] = pressure

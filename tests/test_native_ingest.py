@@ -352,3 +352,65 @@ def test_native_conn_decode_parity():
         np.testing.assert_array_equal(
             np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
             err_msg=field)
+
+
+@pytest.mark.parametrize("alloc", ["conn", "resp", "slab"])
+def test_staging_columns_are_views_of_one_block(alloc):
+    """The columns the allocators hand out are what the decoders write
+    into AND what crosses to the device: C-contiguous, writable, in the
+    fold's dtypes, one after the other inside one word block
+    (ingest/pack.py), ``valid`` among them."""
+    from gyeeta_tpu.ingest import decode, pack
+
+    nc, nr = {"conn": (96, 0), "resp": (0, 200), "slab": (96, 200)}[alloc]
+    if alloc == "slab":
+        block, conn, resp = decode.alloc_slab_cols(nc, nr)
+    else:
+        conn = decode.alloc_conn_cols(nc) if nc else {}
+        resp = decode.alloc_resp_cols(nr) if nr else {}
+        block = next(iter({**conn, **resp}.values())).base
+    assert block.dtype == np.uint32 and block.ndim == 1
+    assert block.flags.owndata and not block.any()
+    assert tuple(conn) == (decode.ConnBatch._fields if conn else ())
+    assert tuple(resp) == (decode.RespBatch._fields if resp else ())
+    layout = tuple((np.dtype(dt), (n,)) for n, dts in (
+        (nc, decode._CONN_DTYPES), (nr, decode._RESP_DTYPES)) for dt in dts)
+    offs, nwords = pack.offsets(layout)
+    assert block.size == nwords and np.all(np.diff(offs) > 0)
+    place = dict(zip(
+        [("conn", f) for f in decode.ConnBatch._fields]
+        + [("resp", f) for f in decode.RespBatch._fields],
+        zip(offs, layout)))
+    for which, cols in (("conn", conn), ("resp", resp)):
+        for name, a in cols.items():
+            off, (dt, shape) = place[which, name]
+            assert a.dtype == dt and a.shape == shape, name
+            assert a.flags.c_contiguous and a.flags.writeable, name
+            assert a.base is block, name
+            # each from a 64-byte line of its own
+            assert a.ctypes.data == block.ctypes.data + 4 * off, name
+            assert off % pack.LINE == 0, name
+
+
+@needs_native
+def test_native_and_numpy_decoders_agree_through_the_block():
+    """Decoding into the block's views (the native path, a reused
+    buffer whose earlier fill was larger) equals the NumPy decoders'
+    fresh columns, column by column and as the packed block."""
+    from gyeeta_tpu.ingest import decode, pack
+
+    sim = ParthaSim(n_hosts=8, n_svcs=4, seed=31)
+    block, ccols, rcols = decode.alloc_slab_cols(1024, 2048)
+    decode.conn_batch_parts([sim.conn_records(900)], 1024, out=ccols)
+    decode.resp_batch_parts([sim.resp_records(2048)], 2048, out=rcols)
+    conn, resp = sim.conn_records(333), sim.resp_records(777)
+    a = decode.conn_batch_parts([conn[:100], conn[100:]], 1024,
+                                out=ccols, clear_to=900)
+    ar = decode.resp_batch_parts([resp], 2048, out=rcols, clear_to=2048)
+    b, br = decode.conn_batch(conn, 1024), decode.resp_batch(resp, 2048)
+    for got, want in ((a, b), (ar, br)):
+        for f in got._fields:
+            assert getattr(got, f).base is block, f
+            assert getattr(got, f).tobytes() == \
+                getattr(want, f).tobytes(), f
+    assert np.array_equal(block, pack.pack(list(b) + list(br)))

@@ -112,3 +112,38 @@ def test_policy_validated_and_no_hang_on_close():
         ex.close()
 
     asyncio.run(run())
+
+
+def test_prewarm_hands_over_and_counts_a_failed_render():
+    """``prewarm`` renders the replaced snapshot's repeated requests on
+    the fresh one; a render that raises is counted, not lost in the
+    pool (the live ask of that request raises to its client)."""
+
+    class _Snap:
+        def __init__(self, reqs=()):
+            self.reqs, self.warmed = list(reqs), []
+
+        def repeated(self):
+            return self.reqs
+
+        def warm(self, req):
+            if req.get("bad"):
+                raise ValueError("no such column")
+            self.warmed.append(req)
+
+    rt = _FakeRT()
+    ex = QueryExecutor(rt, workers=1)
+    try:
+        prev = _Snap([{"i": 1}, {"i": 2, "bad": True}, {"i": 3}])
+        rt.snapshot = prev
+        assert ex.prewarm(prev) == 0           # no publish in between
+        assert ex.prewarm(None) == 0
+        rt.snapshot = new = _Snap()
+        assert ex.prewarm(prev) == 3
+        ex._pool.submit(lambda: None).result()  # one worker: FIFO
+        assert new.warmed == [{"i": 1}, {"i": 3}] and not prev.warmed
+        assert rt.stats.counters.get("query_cache_prewarm_errors") == 1
+        rows = {r["stage"]: r["count"] for r in rt.stats.timing_rows()}
+        assert rows.get("query_prewarm") == 3
+    finally:
+        ex.close()

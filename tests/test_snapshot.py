@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 
 import pytest
 
@@ -330,5 +331,143 @@ def test_metrics_scrape_touches_no_live_state():
         assert _dispatches(rt) == d0
         assert "gyt_snapshot_age_seconds" in out["text"]
         assert "gyt_snapshots_published_total" in out["text"]
+    finally:
+        rt.close()
+
+
+# ------------------------------------------- render-ahead at a publish
+HOSTQ = {"subsys": "hoststate", "sortcol": "hostid", "sortdesc": False}
+ONCE = {"subsys": "svcstate", "maxrecs": 3}
+
+
+def _ask(rt, req):
+    return rt.query({**req, "consistency": "snapshot"})
+
+
+@pytest.mark.parametrize("case", ["repeat", "one_off", "asked_first",
+                                  "dropped", "cache_off", "bounded",
+                                  "in_order"])
+def test_prewarm_renders_ahead_what_the_last_snapshot_repeated(
+        case, monkeypatch):
+    """A request a snapshot answered from its cache is rendered into
+    the next snapshot's cache by ``QueryExecutor.prewarm`` — same
+    answer as a live ask, a hit for the first live ask; a request asked
+    once is not; one nobody asks any more is rendered ahead once."""
+    from gyeeta_tpu.net.qexec import QueryExecutor
+    if case == "cache_off":
+        monkeypatch.setenv("GYT_QUERY_CACHE_MAX", "0")
+    rt = Runtime(CFG)
+    qx = QueryExecutor(rt, workers=2)
+    c = rt.stats.counters
+
+    def tick():
+        prev = rt.snapshot
+        rt.feed(_feed_buf(sim, 64))
+        rt.run_tick()
+        n = qx.prewarm(prev)
+        t0 = time.monotonic()
+        while rt.snapshot.result_cache_len() < n:   # the workers' part
+            assert time.monotonic() - t0 < 60.0
+            time.sleep(0.005)
+        return n
+
+    try:
+        sim = ParthaSim(n_hosts=8, n_svcs=3, seed=21)
+        _warm(rt, sim)
+        assert qx.prewarm(rt.snapshot) == 0          # nothing replaced
+        _ask(rt, QUERY), _ask(rt, QUERY)             # a repeat
+        _ask(rt, ONCE)                               # a one-off
+        if case == "bounded":
+            for k in range(40):
+                q = {**ONCE, "maxrecs": 4 + k}
+                _ask(rt, q), _ask(rt, q)
+        if case == "in_order":
+            for _ in range(3):
+                _ask(rt, HOSTQ)             # repeated later than QUERY
+            assert rt.snapshot.repeated() == [QUERY, HOSTQ]
+            assert tick() == 2
+            return
+        assert rt.snapshot.repeated() == ([] if case == "cache_off" else
+                                          [QUERY] + [{**ONCE, "maxrecs":
+                                                      4 + k} for k in
+                                                     range(15)]
+                                          if case == "bounded" else [QUERY])
+        if case == "asked_first":
+            # a live ask got there before the workers: one render
+            prev = rt.snapshot
+            rt.feed(_feed_buf(sim, 64))
+            rt.run_tick()
+            m0 = c.get("query_cache_misses", 0)
+            live = _ask(rt, QUERY)
+            rt.snapshot.warm(QUERY)
+            assert c.get("query_cache_misses", 0) == m0 + 1
+            assert c.get("query_cache_prewarms", 0) == 0
+            assert _ask(rt, QUERY) is live
+            return
+        n = tick()
+        assert n == {"cache_off": 0, "bounded": 16}.get(case, 1)
+        assert c.get("query_cache_prewarms", 0) == n
+        if case == "cache_off":
+            return
+        m0, h0 = c.get("query_cache_misses", 0), c.get(
+            "query_cache_hits", 0)
+        if case == "one_off":
+            _ask(rt, ONCE)
+            assert c.get("query_cache_misses", 0) == m0 + 1
+            return
+        if case == "dropped":
+            # nobody asked it on this snapshot: not carried further
+            assert rt.snapshot.repeated() == []
+            assert tick() == 0
+            return
+        out = _ask(rt, QUERY)
+        assert (c.get("query_cache_misses", 0), c.get(
+            "query_cache_hits", 0)) == (m0, h0 + 1)
+        assert out["snaptick"] == rt.snapshot.tick
+        # what a live render of the same snapshot gives, bit for bit
+        fresh = rt.snapshot._render(dict(QUERY))
+        assert json.dumps(out, default=str, sort_keys=True) == \
+            json.dumps(fresh, default=str, sort_keys=True)
+        # one ask keeps it riding: rendered ahead on the next one too
+        assert rt.snapshot.repeated() == [QUERY]
+    finally:
+        qx.close()
+        rt.close()
+
+
+def test_tick_loop_prewarms_the_fresh_snapshot():
+    """The serving edge's tick hands the fresh snapshot its
+    predecessor's repeated requests: a client's first ask after the
+    tick is a hit."""
+    from gyeeta_tpu.net import GytServer
+    from gyeeta_tpu.sim.nodeweb import NodeWebSim
+
+    async def scenario(rt):
+        srv = GytServer(rt, tick_interval=0.3)
+        host, port = await srv.start()
+        nw = NodeWebSim()
+        await nw.connect(host, port)
+        try:
+            a = await nw.query_web("hoststate", maxrecs=50)
+            await nw.query_web("hoststate", maxrecs=50)
+            c = rt.stats.counters
+            t0 = time.monotonic()
+            while not c.get("query_cache_prewarms", 0):
+                assert time.monotonic() - t0 < 30.0
+                await asyncio.sleep(0.02)
+            await asyncio.sleep(0.1)
+            m0 = c.get("query_cache_misses", 0)
+            b = await nw.query_web("hoststate", maxrecs=50)
+            return a, b, c.get("query_cache_misses", 0) - m0
+        finally:
+            await nw.close()
+            await srv.stop()
+
+    rt = Runtime(CFG)
+    try:
+        _warm(rt, ParthaSim(n_hosts=8, n_svcs=3, seed=22))
+        a, b, missed = asyncio.run(scenario(rt))
+        assert b["snaptick"] > a["snaptick"]
+        assert missed == 0
     finally:
         rt.close()

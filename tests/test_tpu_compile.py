@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 from gyeeta_tpu.engine import aggstate, step, table
 from gyeeta_tpu.engine.aggstate import EngineCfg
-from gyeeta_tpu.ingest import decode, wire
+from gyeeta_tpu.ingest import decode, pack, wire
 from gyeeta_tpu.parallel import depgraph as dg
 from gyeeta_tpu.parallel import rollup, sharded
 from gyeeta_tpu.semantic import derive
@@ -229,12 +229,17 @@ def test_fold_component_compiles_for_v5e(one_chip, tpu_branches, build):
 # --------------------------------------------- the served path, whole
 def _p_fold_slab(one):
     # the production fused dispatch, as Runtime._get_fold_all builds it
-    # for the conn/resp K-slab
-    return (lambda s, d, c, r: step.fold_all(FLEET, s, d, 0,
-                                             connresp=(c, r)),
-            (_on(_state(FLEET), one), _on(_dep(), one),
-             _on(_conn(CONN_LANES, FLEET.fold_k), one),
-             _on(_resp(RESP_LANES, FLEET.fold_k), one)), (0, 1))
+    # for the conn/resp K-slab: the 22 columns arrive as one packed
+    # word block and the fold's first ops take them apart
+    leaves, treedef = jax.tree.flatten(
+        (_conn(CONN_LANES, FLEET.fold_k), _resp(RESP_LANES, FLEET.fold_k)))
+    layout = pack.layout_of(leaves)
+    block = jax.ShapeDtypeStruct((pack.offsets(layout)[1],), np.uint32,
+                                 sharding=one)
+    return (lambda s, d, b: step.fold_all(
+        FLEET, s, d, 0, connresp=jax.tree.unflatten(
+            treedef, pack.unpack(b, layout))),
+        (_on(_state(FLEET), one), _on(_dep(), one), block), (0, 1))
 
 
 def _p_fold_sweep(one):
