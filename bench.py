@@ -54,10 +54,10 @@ PHASE_TIMEOUT = {"fold_toy": 1500, "fold_ns": 2700,
                  "feed_toy": 900, "feed_ns": 1500,
                  "feed_toy_wal": 900, "topk_recover": 900,
                  "compact": 1200, "compact_par": 2400,
-                 "timeview_aggr": 900, "snap_pingpong": 900}
+                 "timeview_aggr": 900}
 PHASE_ORDER = ("fold_toy", "fold_ns", "feed_ns", "feed_toy",
                "feed_toy_wal", "topk_recover", "compact",
-               "compact_par", "timeview_aggr", "snap_pingpong")
+               "compact_par", "timeview_aggr")
 
 
 def _geometry(which: str):
@@ -85,7 +85,7 @@ def _bench_fold(cfg, sim, dev, label: str, dep_pairs: int,
                 dep_edges: int) -> dict:
     """Steady-state ingest-fold throughput: the PRODUCTION dispatch
     (engine fold + dependency-graph fold in one jit, both donated —
-    exactly ``Runtime._fold_many_dep``) with the production flush
+    ``step.fold_all``'s connresp-only variant) with the production flush
     policy (lagged pressure check → partial flush). The dep fold used
     to be billed only to the feed path, making feed_vs_fold compare
     different machines. Returns {rate, ms_per_dispatch, n_flushes}."""
@@ -264,15 +264,10 @@ def _bench_feed(cfg, sim, label: str, dep_pairs: int,
     jax.block_until_ready(rt.state)
     feed_rate = feed_calls * ev_per_buf / (time.perf_counter() - t0)
     # device dispatches per feed batch over the measured loop: the
-    # fused fold_all calls + digest partial flushes (contract ≤ 2; the
-    # legacy path issued 2+ per batch before counting per-subsystem
-    # folds)
+    # fold_all calls + digest partial flushes (contract ≤ 2)
     c1 = rt.stats.counters
     delta = lambda k: c1.get(k, 0) - c0.get(k, 0)   # noqa: E731
-    if getattr(rt, "_fused", False):
-        disp = delta("fold_dispatches") + delta("td_partial_flushes")
-    else:   # legacy: every slab fold issues a pressure dispatch too
-        disp = 2 * delta("slab_dispatches") + delta("td_partial_flushes")
+    disp = delta("fold_dispatches") + delta("td_partial_flushes")
     dispatches_per_batch = round(disp / max(feed_calls, 1), 4)
     # overlap win, measured directly: the same feed loop with a
     # block_until_ready barrier after every batch — the host can never
@@ -668,70 +663,6 @@ def _bench_timeview_aggr() -> dict:
     return out
 
 
-def _bench_snap_pingpong() -> dict:
-    """Snapshot ping-pong prototype (ROADMAP query item (a), ISSUE-10
-    satellite): publish cost with the retired (N-2) snapshot's buffers
-    donated as the copy's destination vs the plain non-donating copy.
-    An earlier CPU-backend run (not a chip measurement): donation was
-    honored and the ping-pong publish ~12x cheaper at the 32k geometry (the
-    plain copy's cost is dominated by allocating+freeing the full
-    state every publish; the donated path writes into the retired
-    buffers). ``donations``/``fallbacks`` count how often the refcount
-    guard allowed it. Default stays OFF (GYT_SNAP_PINGPONG=1 enables):
-    on CPU the merged-column renders are ZERO-COPY numpy views of
-    snapshot buffers, and an off-tick consumer (the history writer's
-    queue) that falls more than two ticks behind could still hold
-    views of the N-2 snapshot when it donates — see OPERATIONS.md
-    "Fleet-scale deployment" for the enablement conditions."""
-    import gc
-
-    from gyeeta_tpu.engine.aggstate import EngineCfg
-    from gyeeta_tpu.runtime import Runtime
-    from gyeeta_tpu.sim.partha import ParthaSim
-    from gyeeta_tpu.utils.config import RuntimeOpts
-
-    cfg = EngineCfg(svc_capacity=32768, n_hosts=8192,
-                    task_capacity=8192)
-    sim = ParthaSim(n_hosts=256, n_svcs=64, n_clients=2048)
-    out: dict = {}
-    for mode in ("off", "on"):
-        os.environ["GYT_SNAP_PINGPONG"] = "1" if mode == "on" else "0"
-        rt = Runtime(cfg, RuntimeOpts(dep_pair_capacity=16384,
-                                      dep_edge_capacity=16384))
-        rt.feed(sim.conn_frames(2048) + sim.resp_frames(2048))
-        rt.flush()
-        for _ in range(3):              # compile + settle generations
-            rt.publish_snapshot()
-        gc.collect()
-        iters = 12
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            rt.publish_snapshot()
-        ms = (time.perf_counter() - t0) / iters * 1e3
-        c = rt.stats.counters
-        out[f"publish_ms_{mode}"] = round(ms, 3)
-        if mode == "on":
-            out["donations"] = c.get("snapshot_pingpong_donations", 0)
-            out["fallbacks"] = c.get("snapshot_pingpong_fallbacks", 0)
-            out["errors"] = c.get("snapshot_pingpong_errors", 0)
-        rt.close()
-        del rt
-        gc.collect()
-    os.environ.pop("GYT_SNAP_PINGPONG", None)
-    out["ratio_on_vs_off"] = round(
-        out["publish_ms_on"] / max(out["publish_ms_off"], 1e-9), 4)
-    out["note"] = (
-        "donation honored on this backend; default OFF because CPU "
-        "merged-column renders are zero-copy views — enable when "
-        "off-tick consumers drain within 2 ticks (OPERATIONS.md)")
-    print(f"bench[snap_pingpong]: publish {out['publish_ms_off']} ms "
-          f"(copy) vs {out['publish_ms_on']} ms (ping-pong, "
-          f"{out.get('donations', 0)} donations / "
-          f"{out.get('fallbacks', 0)} fallbacks)",
-          file=sys.stderr, flush=True)
-    return out
-
-
 def _proc_usage() -> dict:
     """Per-phase resource row (ISSUE-12 satellite): peak RSS plus
     CPU-seconds split between THIS process (the fold side) and its
@@ -806,8 +737,6 @@ def _run_phase(phase: str) -> dict:
         return _bench_compact_par(cfg, dp, de)
     if phase == "timeview_aggr":
         return _bench_timeview_aggr()
-    if phase == "snap_pingpong":
-        return _bench_snap_pingpong()
     raise SystemExit(f"unknown phase {phase!r}")
 
 
@@ -825,8 +754,7 @@ _PHASE_METRIC = {"fold_toy": "rate", "fold_ns": "rate",
                  "topk_recover": "recover_ms_per_tick",
                  "compact": "replay_ev_per_sec",
                  "compact_par": "scaling_1_to_4",
-                 "timeview_aggr": "speedup",
-                 "snap_pingpong": "ratio_on_vs_off"}
+                 "timeview_aggr": "speedup"}
 
 
 def _phase_subproc(phase: str, platform: str | None):
@@ -1005,12 +933,6 @@ def _orchestrate(platform: str | None) -> None:
         # aggregate capacity ratio, records/worker-CPU-second
         # methodology (gate ≥ 2.5x)
         result["compact_par"] = dict(cpp)
-    pp = phases.get("snap_pingpong", {})
-    if "ratio_on_vs_off" in pp:
-        # snapshot ping-pong prototype row (ISSUE-10 satellite): copy
-        # cost ± donated-destination publish, with the CPU-donation
-        # caveat recorded in the row itself
-        result["snap_pingpong"] = dict(pp)
     tv = phases.get("timeview_aggr", {})
     if "speedup" in tv:
         # windowed-aggregation vectorization row (ISSUE 9 satellite):

@@ -206,18 +206,14 @@ if ! JAX_PLATFORMS=cpu python _rcompact_smoke.py; then
     exit 1
 fi
 
-# Fused fold-path smoke: the fused megakernel is the DEFAULT fold path
-# (a regression to the legacy per-subsystem dispatch sequence would
-# silently cost 2-6x fold throughput).
+# Fold-path smoke: a default Runtime folds a fed stream in fold_all
+# dispatches.
 echo "ci: fused fold-path smoke" >&2
 if ! JAX_PLATFORMS=cpu python - <<'PYEOF'
-from gyeeta_tpu.runtime import Runtime, fused_fold_enabled
+from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.sim.partha import ParthaSim
 
-assert fused_fold_enabled(env={}), "fused fold must be the default"
-assert not fused_fold_enabled(env={"GYT_FUSED_FOLD": "0"})
 rt = Runtime()
-assert rt._fused, "fused fold path not active by default"
 sim = ParthaSim(n_hosts=4, n_svcs=4, seed=3)
 rt.feed(sim.listener_frames())
 rt.feed(sim.conn_frames(4096))
@@ -225,7 +221,7 @@ rt.feed(sim.resp_frames(4096))
 rt.flush()
 assert rt.stats.counters.get("fold_dispatches", 0) > 0
 rt.close()
-print("ci: fused fold default OK")
+print("ci: fused fold OK")
 PYEOF
 then
     echo "ci: FATAL — fused fold-path smoke failed" >&2

@@ -22,8 +22,6 @@ jit). `fold_step` is the fused flagship step used by bench + __graft_entry__.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -34,15 +32,6 @@ from gyeeta_tpu.engine.aggstate import (
 )
 from gyeeta_tpu.sketch import countmin, hyperloglog as hll, invertible, \
     loghist, tdigest, topk, windows
-
-
-# Bench-only ablation switch: GYT_BENCH_ABLATE="topk,tdigest" compiles the
-# fold WITHOUT those components so per-component device cost can be
-# attributed on real hardware. Read ONCE at module import — set it in the
-# environment before the process starts (the _ablate.py driver spawns
-# subprocesses for exactly this reason). Never set in production.
-_ABLATE = frozenset(
-    os.environ.get("GYT_BENCH_ABLATE", "").split(",")) - {""}
 
 
 def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
@@ -65,14 +54,8 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     valid = cb.valid
     svc_side = valid & cb.is_accept
     with scope("conn.upsert"):
-        if "upsert" in _ABLATE:
-            tbl = st.tbl
-            rows, probe = table.lookup_counted(st.tbl, cb.svc_hi,
-                                               cb.svc_lo, svc_side)
-            any_new = jnp.any(svc_side & (rows < 0))
-        else:
-            tbl, rows, any_new, probe = table.upsert_fast2(
-                st.tbl, cb.svc_hi, cb.svc_lo, svc_side)
+        tbl, rows, any_new, probe = table.upsert_fast2(
+            st.tbl, cb.svc_hi, cb.svc_lo, svc_side)
     ok = svc_side & (rows >= 0)
     rowz = jnp.where(ok, rows, 0)
     S = cfg.svc_capacity
@@ -83,15 +66,13 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     # 6.3 ms → 1.9 ms per 32k-lane dispatch on one core); per-slot
     # accumulation order per column is still lane order, so the result
     # is bit-identical to the per-column form.
-    ctr_win = st.ctr_win
     lanes = jnp.where(ok, rowz, S)  # S = dropped (mode=drop)
-    if "ctr" not in _ABLATE:
-        with scope("conn.ctr"):
-            upd = jnp.stack(
-                [cb.bytes_sent, cb.bytes_rcvd,
-                 cb.is_close.astype(jnp.float32), cb.duration_us], axis=1)
-            cur = st.ctr_win.cur.at[lanes].add(upd, mode="drop")
-        ctr_win = st.ctr_win._replace(cur=cur)
+    with scope("conn.ctr"):
+        upd = jnp.stack(
+            [cb.bytes_sent, cb.bytes_rcvd,
+             cb.is_close.astype(jnp.float32), cb.duration_us], axis=1)
+        cur = st.ctr_win.cur.at[lanes].add(upd, mode="drop")
+    ctr_win = st.ctr_win._replace(cur=cur)
 
     # the service→host homing column only changes when a NEW row is
     # claimed (existing rows re-write the value they already hold;
@@ -104,12 +85,11 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
             lambda col: col.at[lanes].set(cb.host_id, mode="drop"),
             lambda col: col, st.svc_host)
     with scope("conn.svc_hll"):
-        svc_hll = st.svc_hll if "svchll" in _ABLATE \
-            else hll.update_entities(st.svc_hll, rowz, cb.cli_hi,
-                                     cb.cli_lo, valid=ok)
+        svc_hll = hll.update_entities(st.svc_hll, rowz, cb.cli_hi,
+                                      cb.cli_lo, valid=ok)
     with scope("conn.glob_hll"):
-        glob_hll = st.glob_hll if "globhll" in _ABLATE else hll.update(
-            st.glob_hll, cb.flow_hi, cb.flow_lo, valid=valid)
+        glob_hll = hll.update(st.glob_hll, cb.flow_hi, cb.flow_lo,
+                              valid=valid)
     # byte accounting takes the ACCEPT side only (valid=svc_side below
     # already masks client-observed lanes): a dual-observed flow would
     # otherwise count twice into the additive CMS/top-K. Server-side
@@ -117,8 +97,8 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     # stats.
     tot_bytes = cb.bytes_sent + cb.bytes_rcvd
     with scope("conn.cms"):
-        cms = st.cms if "cms" in _ABLATE else countmin.update(
-            st.cms, cb.flow_hi, cb.flow_lo, tot_bytes, valid=svc_side)
+        cms = countmin.update(st.cms, cb.flow_hi, cb.flow_lo, tot_bytes,
+                              valid=svc_side)
     # sketch-assisted candidate compaction (CMS+heap, the shape of
     # the FPGA sketch-acceleration papers): the CMS — queried AFTER
     # this batch folded into it — upper-bounds every flow's
@@ -128,7 +108,7 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
     est = hot = sel = None
     n = cb.flow_hi.shape[0]
     with scope("conn.topk_select"):
-        if "cms" not in _ABLATE and 0 < cfg.topk_budget:
+        if 0 < cfg.topk_budget:
             est = countmin.upper_bound(cms, cb.flow_hi, cb.flow_lo)
         # priority-aware hot admission (PSketch): on top of the
         # budget's relative ranking, a lane enters the exact top-K
@@ -160,9 +140,7 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
             extra_evicted = (jnp.sum(jnp.where(svc_side, tot_bytes, 0.0))
                              - jnp.sum(c_vals))
     with scope("conn.topk"):
-        if "topk" in _ABLATE:
-            flow_topk = st.flow_topk
-        elif sel is not None:
+        if sel is not None:
             ftk = st.flow_topk._replace(
                 evicted=st.flow_topk.evicted + extra_evicted)
             flow_topk = topk.update(ftk, c_hi, c_lo, c_vals,
@@ -172,15 +150,15 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
                 st.flow_topk, cb.flow_hi, cb.flow_lo, tot_bytes,
                 valid=svc_side, est=est, budget=cfg.topk_budget)
     with scope("conn.inv"):
-        if "hh" in _ABLATE or cfg.hh_width <= 0:
+        if cfg.hh_width <= 0:
             inv = st.inv
         else:
             # invertible candidate buckets (sketch/invertible.py): the
             # selected (admitted) lanes compete for bucket ownership
             # with their estimate as priority — per-tick decoding
             # recovers heavy keys straight from this state, no
-            # candidate list. Falls back to every accept-side lane with
-            # its own mass as priority when the CMS is ablated.
+            # candidate list. Without a top-K budget (no estimate)
+            # every accept-side lane competes with its own mass.
             if sel is not None:
                 inv = invertible.update(st.inv, c_hi, c_lo, c_prio,
                                         valid=sel_ok)
@@ -222,8 +200,6 @@ def ingest_resp(cfg: EngineCfg, st: AggState, rb) -> AggState:
 def td_flush(cfg: EngineCfg, st: AggState) -> AggState:
     """Compress the staged digest samples into the per-svc digests (one
     vmapped pass) and clear the stage."""
-    if "tdigest" in _ABLATE:
-        return st
     svc_td, stage, stage_n = tdigest.flush_staged(
         st.svc_td, st.td_stage, st.td_stage_n)
     return st._replace(svc_td=svc_td, td_stage=stage, td_stage_n=stage_n)
@@ -236,8 +212,6 @@ def td_flush_partial(cfg: EngineCfg, st: AggState) -> AggState:
     an in-graph ``lax.cond`` (a cond carrying the 128 MB stage forced
     whole-buffer copies every dispatch — measured 110 ms/dispatch at
     65k capacity even when the branch was NOT taken)."""
-    if "tdigest" in _ABLATE:
-        return st
     svc_td, stage, stage_n = tdigest.flush_staged_topm(
         st.svc_td, st.td_stage, st.td_stage_n, cfg.td_flush_m)
     return st._replace(svc_td=svc_td, td_stage=stage, td_stage_n=stage_n)
@@ -275,28 +249,23 @@ def ingest_resp_flat(cfg: EngineCfg, st: AggState, flat) -> AggState:
     ok = valid & (rows >= 0)
     n_unknown = jnp.sum(valid & (rows < 0)).astype(jnp.float32)
     rowz = jnp.where(ok, rows, 0)
-    resp_win = st.resp_win
-    if "loghist" not in _ABLATE:
-        with scope("resp.loghist"):
-            cur = loghist.update_entities(
-                st.resp_win.cur, cfg.resp_spec, rowz, flat.resp_us,
-                valid=ok)
-        resp_win = st.resp_win._replace(cur=cur)
-    stage, stage_n = st.td_stage, st.td_stage_n
-    n_over = jnp.int32(0)
-    if "tdigest" not in _ABLATE:
-        # duty-cycled digest sampling (the reference samples response
-        # events at the source, RESP_SAMPLING ~50%, common/gy_ebpf.h:29):
-        # the loghist above folds EVERY sample (lossless counts); the
-        # digest — a tail-quantile estimator — takes a strided 1-in-N
-        # subsample, shrinking the routing sort and flush cadence N×.
-        # Static stride keeps shapes fixed; lane order is arrival order,
-        # uncorrelated with service identity.
-        k = max(1, cfg.td_sample_stride)
-        with scope("resp.td_stage"):
-            stage, stage_n, n_over = tdigest.stage_samples(
-                stage, stage_n, jnp.where(ok, rows, -1)[::k],
-                flat.resp_us[::k])
+    with scope("resp.loghist"):
+        cur = loghist.update_entities(
+            st.resp_win.cur, cfg.resp_spec, rowz, flat.resp_us,
+            valid=ok)
+    resp_win = st.resp_win._replace(cur=cur)
+    # duty-cycled digest sampling (the reference samples response
+    # events at the source, RESP_SAMPLING ~50%, common/gy_ebpf.h:29):
+    # the loghist above folds EVERY sample (lossless counts); the
+    # digest — a tail-quantile estimator — takes a strided 1-in-N
+    # subsample, shrinking the routing sort and flush cadence N×.
+    # Static stride keeps shapes fixed; lane order is arrival order,
+    # uncorrelated with service identity.
+    k = max(1, cfg.td_sample_stride)
+    with scope("resp.td_stage"):
+        stage, stage_n, n_over = tdigest.stage_samples(
+            st.td_stage, st.td_stage_n, jnp.where(ok, rows, -1)[::k],
+            flat.resp_us[::k])
     return st._replace(
         resp_win=resp_win, td_stage=stage, td_stage_n=stage_n,
         n_resp=st.n_resp + jnp.sum(valid).astype(jnp.float32),
@@ -563,7 +532,7 @@ def ingest_delta(cfg: EngineCfg, st: AggState, dep, db, tick):
     flow_topk = topk.update(ftk, db.flow_hi, db.flow_lo, db.flow_val,
                             valid=vhot, est=est,
                             budget=cfg.topk_budget)
-    if "hh" in _ABLATE or cfg.hh_width <= 0:
+    if cfg.hh_width <= 0:
         inv = st.inv
     else:
         inv = invertible.update(st.inv, db.flow_hi, db.flow_lo,
@@ -800,11 +769,10 @@ def jit_fold_many(cfg: EngineCfg):
 
 
 # --------------------------------------------------------- fused megakernel
-# Canonical sub-fold order inside fold_all — the SAME order the legacy
-# per-subsystem dispatch sequence applies (decode.drain_chunks yields
-# device kinds in this order, and the runtimes fold conn/resp slabs
-# after the chunk loop), so a fused dispatch is bit-identical to the
-# dispatch sequence it replaces (tests/test_fusedfold.py fuzzes this).
+# Canonical sub-fold order inside fold_all: the sections of one dispatch
+# fold as if each ``ingest_*`` above had been dispatched on its own in
+# this order, conn/resp slab last (tests/test_fusedfold.py holds every
+# dispatch of a Runtime to exactly that composition, bit for bit).
 FOLD_ALL_ORDER = ("listener", "host", "task", "cpumem", "trace", "ping",
                   "delta", "connresp")
 
@@ -823,13 +791,11 @@ def fold_all(cfg: EngineCfg, st: AggState, dep, tick, *, listener=None,
     key their jit cache on the presence tuple; in practice two or three
     variants exist per process.
 
-    Replaces 6+ separate donated dispatches per feed batch (one per
-    subsystem + ``_fold_many_dep`` + the ``stage_pressure`` readback
-    dispatch) with one jit-call overhead and one host→device transfer,
-    and returns the pressure scalar as a graph OUTPUT so the hot loop
-    never issues a second dispatch just to observe it (the lagged
-    host-side flush trigger reads a scalar that is already
-    materialized).
+    One jit-call overhead and one host→device transfer per feed batch
+    where a dispatch per subsystem would pay 6+, and the pressure
+    scalar is a graph OUTPUT so the hot loop never issues a second
+    dispatch just to observe it (the lagged host-side flush trigger
+    reads a scalar that is already materialized).
 
     Returns ``(state, dep, pressure)``.
     """

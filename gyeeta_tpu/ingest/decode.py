@@ -192,8 +192,8 @@ class DeltaBatch(NamedTuple):
     evicted_add: np.ndarray   # (1,) float32
 
 
-# default per-dispatch SKETCH_DELTA record lanes (drain_chunks chunk
-# size; GYT_SLAB_DELTA_LANES must stay >= this)
+# per-dispatch SKETCH_DELTA record lanes: the drain_chunks chunk size
+# and the fold slab's delta section capacity (runtime._SLAB_LANES)
 DELTA_LANES_DEFAULT = 256
 
 

@@ -20,8 +20,8 @@ wire.py dtypes and executed in C++ — ``ingest/decode.py`` keeps the
 bit-identical NumPy reference implementations as the fallback.
 
 Setting ``GYT_PY_INGEST=1`` forces the pure-Python path everywhere (a
-``GYT_BENCH_ABLATE``-style debug knob; see OPERATIONS.md) — checked on
-every load so tests can toggle it per-process.
+debug knob; see OPERATIONS.md) — checked on every load so tests can
+toggle it per-process.
 """
 
 from __future__ import annotations

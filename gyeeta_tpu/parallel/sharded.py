@@ -129,16 +129,16 @@ def fold_step_sharded(cfg: aggstate.EngineCfg, mesh):
 
 def fold_step_dep_sharded(cfg: aggstate.EngineCfg, mesh,
                           cap_per_dest: int):
-    """The sharded fused slab dispatch: engine fold + dependency-graph
-    fold (incl. the cross-shard pairing ``all_to_all``) + the global
+    """The sharded slab dispatch: engine fold + dependency-graph fold
+    (incl. the cross-shard pairing ``all_to_all``) + the global
     digest-stage pressure scalar in ONE shard_map'd jit with state AND
-    dep donation — replacing the legacy three-dispatch sequence
-    (``fold_step_sharded`` + ``td_pressure_sharded`` + ``dep_step_fn``)
-    with one jit-call overhead per slab. The pressure scalar is a graph
-    OUTPUT (replicated ()), so the hot loop never issues a dispatch
-    just to observe it. ``cap_per_dest`` is the pairing dispatch
-    capacity — instantiate once per slab width (chunk vs fold_k-deep),
-    like the legacy ``dep_step_fn`` pair."""
+    dep donation — what ``fold_step_sharded`` + ``td_pressure_sharded``
+    + ``dep_step_fn`` compute in three dispatches
+    (tests/test_fusedfold.py holds it to that composition), for one
+    jit-call overhead per slab. The pressure scalar is a graph OUTPUT
+    (replicated ()), so the hot loop never issues a dispatch just to
+    observe it. ``cap_per_dest`` is the pairing dispatch capacity —
+    instantiate once per slab width (chunk vs fold_k-deep)."""
     from gyeeta_tpu.parallel import depgraph as dg
 
     n = mesh.devices.size

@@ -36,6 +36,8 @@ import numpy as np
 
 from gyeeta_tpu.alerts import AlertManager
 from gyeeta_tpu.engine.aggstate import EngineCfg
+from gyeeta_tpu.hostingest import SECTION_COUNTERS, HostIngest, \
+    section_builders
 from gyeeta_tpu.ingest import decode, native, wire
 from gyeeta_tpu.obs import health as obs_health
 from gyeeta_tpu.obs import xlamon
@@ -52,7 +54,7 @@ from gyeeta_tpu.utils.intern import InternTable
 from gyeeta_tpu.utils.selfstats import Stats
 
 
-class ShardedRuntime:
+class ShardedRuntime(HostIngest):
     def __init__(self, cfg: Optional[EngineCfg] = None, mesh=None,
                  opts: Optional[RuntimeOpts] = None, clock=None):
         from gyeeta_tpu.parallel.mesh import make_mesh
@@ -180,14 +182,10 @@ class ShardedRuntime:
                 dg.init(self.opts.dep_pair_capacity,
                         self.opts.dep_edge_capacity)))
 
-        self._fold = sharded.fold_step_sharded(self.cfg, self.mesh)
         self._td_flush = sharded.td_flush_sharded(self.cfg, self.mesh)
         self._td_pressure = sharded.td_pressure_sharded(self.mesh)
-        # fused slab dispatch (default): engine fold + dep fold +
-        # pressure scalar in ONE shard_map'd jit — the legacy three-
-        # dispatch sequence stays selectable via GYT_FUSED_FOLD=0
-        from gyeeta_tpu.runtime import fused_fold_enabled
-        self._fused = fused_fold_enabled()
+        # the slab dispatch: engine fold + dep fold + pressure scalar
+        # in ONE shard_map'd jit
         self._fold_dep_slab = sharded.fold_step_dep_sharded(
             self.cfg, self.mesh,
             cap_per_dest=self.cfg.conn_batch * self.cfg.fold_k)
@@ -195,35 +193,33 @@ class ShardedRuntime:
             self.cfg, self.mesh, cap_per_dest=self.cfg.conn_batch)
         self._td_dirty = False
         self._pressure = None         # device scalar from last dispatch
-        self._fold_lst = sharded.ingest_listener_sharded(self.cfg,
-                                                         self.mesh)
-        # edge pre-aggregation fold (state + dep donated; delta records
-        # route per shard by host_id like every raw stream)
-        self._fold_delta = sharded.ingest_delta_sharded(self.cfg,
-                                                        self.mesh)
-        self._delta_dims = dict(
-            resp_nbuckets=self.cfg.resp_spec.nbuckets,
-            hll_m_svc=1 << self.cfg.hll_p_svc,
-            hll_m_glob=1 << self.cfg.hll_p_global)
-        self._fold_host = sharded.ingest_host_sharded(self.cfg, self.mesh)
-        self._fold_task = sharded.ingest_task_sharded(self.cfg, self.mesh)
-        self._fold_ping = sharded.ping_tasks_sharded(self.cfg, self.mesh)
-        self._fold_cm = sharded.ingest_cpumem_sharded(self.cfg, self.mesh)
-        self._fold_trace = sharded.ingest_trace_sharded(self.cfg,
-                                                        self.mesh)
+        # sweep subsystems: one stacked dispatch each (ROADMAP D1):
+        # kind → (sharded fold, lanes per shard); the delta fold takes
+        # and returns dep too (edge pre-aggregation, both donated)
+        cfg, mesh = self.cfg, self.mesh
+        self._sect_builders = section_builders(cfg)
+        self._sect_folds = {
+            "listener": (sharded.ingest_listener_sharded(cfg, mesh),
+                         cfg.listener_batch),
+            "host": (sharded.ingest_host_sharded(cfg, mesh),
+                     wire.MAX_HOSTS_PER_BATCH),
+            "task": (sharded.ingest_task_sharded(cfg, mesh),
+                     wire.MAX_TASKS_PER_BATCH),
+            "ping": (sharded.ping_tasks_sharded(cfg, mesh),
+                     wire.MAX_PINGS_PER_BATCH),
+            "cpumem": (sharded.ingest_cpumem_sharded(cfg, mesh),
+                       wire.MAX_CPUMEM_PER_BATCH),
+            "trace": (sharded.ingest_trace_sharded(cfg, mesh),
+                      wire.MAX_TRACE_PER_BATCH),
+            "delta": (sharded.ingest_delta_sharded(cfg, mesh),
+                      decode.DELTA_LANES_DEFAULT),
+        }
         self._classify = sharded.classify_sharded(self.cfg, self.mesh)
         self._tick = sharded.tick_5s_sharded(self.cfg, self.mesh)
         self._age_tasks = sharded.age_tasks_sharded(
             self.cfg, self.mesh, self.opts.task_max_age_ticks)
         self._age_apis = sharded.age_apis_sharded(
             self.cfg, self.mesh, self.opts.api_max_age_ticks)
-        self._dep_step = dg.dep_step_fn(
-            self.mesh, cap_per_dest=self.cfg.conn_batch)
-        # slab-width dep step: the a2a capacity scales with the wider
-        # dispatch so a burst of one-sided halves isn't dropped
-        self._dep_slab = dg.dep_step_fn(
-            self.mesh,
-            cap_per_dest=self.cfg.conn_batch * self.cfg.fold_k)
         self._rollup = rollup.rollup_fn(self.cfg, self.mesh)
         self._edge_roll = dg.edge_rollup_fn(
             self.mesh, out_capacity=self.opts.dep_edge_capacity)
@@ -279,19 +275,9 @@ class ShardedRuntime:
         # jitted copy of the stacked (state, dep) per publish — output
         # shardings follow the inputs, so collectives (rollup, edge
         # rollup) run on the frozen copy unchanged. See Runtime.
-        # GYT_SNAP_PINGPONG=1 donates the RETIRED snapshot's buffers as
-        # the copy's destination (runtime.snap_pingpong_enabled — the
-        # ROADMAP item (a) prototype, refcount-guarded).
         self._snap_copy = sharded.memo_sharded(
             ("snap_copy",),
             lambda: jax.jit(lambda t: jax.tree.map(jnp.copy, t)))
-        from gyeeta_tpu.runtime import make_pingpong_copy, \
-            snap_pingpong_enabled
-        self._snap_pingpong = snap_pingpong_enabled()
-        self._snap_copy_pp = sharded.memo_sharded(
-            ("snap_copy_pp",), make_pingpong_copy) \
-            if self._snap_pingpong else None
-        self._snap_old = None     # the retired (N-2) snapshot candidate
         self.snapshot = None
         self._snap_version = 0
         # registry renders on query worker threads vs updates on the
@@ -329,13 +315,9 @@ class ShardedRuntime:
         }
 
     # ------------------------------------------------------------- ingest
-    def _stack(self, builder, recs, lanes, count_path: bool = True):
-        # the *_fast builders take a stats kwarg for the native-vs-
-        # fallback decode counters; trace_batch (python-only) does not
-        b = (lambda r, sz: builder(r, sz, stats=self.stats)) \
-            if count_path else builder
+    def _stack(self, builder, recs, lanes):
         return sharded.put_sharded(self.mesh, sharded.shard_batches(
-            self.cfg, self.mesh, (b, lanes), recs, recs["host_id"]))
+            self.cfg, self.mesh, (builder, lanes), recs, recs["host_id"]))
 
     def feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
         """Byte stream → routed stacked batches → sharded folds."""
@@ -372,16 +354,8 @@ class ShardedRuntime:
         arrays go STRAIGHT into that shard's staging bucket (no
         re-hash, no argsort — the pre-routed fast path the per-shard
         rings exist for)."""
-        n = 0
         self._cols.bump()
-        # sweep-seq marks → per-host high-water mark (WAL dedup)
-        sw = recs.pop(wire.NOTIFY_SWEEP_SEQ, None)
-        if sw is not None and len(sw):
-            for h, s in zip(sw["host_id"].tolist(), sw["seq"].tolist()):
-                if s > self._sweep_last_seq.get(h, 0):
-                    self._sweep_last_seq[h] = s
-            self.stats.bump("sweep_marks", len(sw))
-            n += len(sw)
+        n = self._ingest_sweep_marks(recs.pop(wire.NOTIFY_SWEEP_SEQ, None))
         # conn/resp hot path: hash each record's host to its shard ONCE
         # and stage into per-shard buckets; a shard whose bucket fills a
         # slab (fold_k microbatches' worth) triggers ONE stacked
@@ -400,9 +374,7 @@ class ShardedRuntime:
             n += len(conn)
         resp = recs.pop(wire.NOTIFY_RESP_SAMPLE, None)
         if resp is not None and len(resp):
-            hid = resp["host_id"]
-            self._host_resp_tick[hid[hid < self.cfg.n_hosts]] = \
-                self._tick_no
+            self._note_native_resp(resp)
             if shard is None:
                 self._stage_raw(self._resp_raw, self._resp_staged, resp)
             else:
@@ -416,120 +388,36 @@ class ShardedRuntime:
         while (max(self._conn_staged) >= slab_c
                or max(self._resp_staged) >= slab_r):
             self._dispatch_slab(slab_c, slab_r)
-        for kind, *chunks in decode.drain_chunks(
+        for kind, chunk in decode.drain_chunks(
                 recs, self.cfg.conn_batch, self.cfg.resp_batch,
                 self.cfg.listener_batch):
-            if kind == "listener":
-                self.state = self._fold_lst(self.state, self._stack(
-                    decode.listener_batch_fast, chunks[0],
-                    self.cfg.listener_batch))
-                n += len(chunks[0])
-                self.stats.bump("listener_records", len(chunks[0]))
-            elif kind == "host":
-                self.state = self._fold_host(self.state, self._stack(
-                    decode.host_batch_fast, chunks[0],
-                    wire.MAX_HOSTS_PER_BATCH))
-                n += len(chunks[0])
-                self.stats.bump("host_records", len(chunks[0]))
-            elif kind == "task":
-                self.state = self._fold_task(self.state, self._stack(
-                    decode.task_batch_fast, chunks[0],
-                    wire.MAX_TASKS_PER_BATCH))
-                n += len(chunks[0])
-                self.stats.bump("task_records", len(chunks[0]))
-            elif kind == "ping":
-                self.state = self._fold_ping(self.state, self._stack(
-                    decode.ping_batch, chunks[0],
-                    wire.MAX_PINGS_PER_BATCH))
-                n += len(chunks[0])
-                self.stats.bump("task_pings", len(chunks[0]))
-            elif kind == "delta":
-                bd = lambda r, sz: decode.delta_batch(  # noqa: E731
-                    r, sz, stats=self.stats, **self._delta_dims)
-                db = self._stack(bd, chunks[0],
-                                 decode.DELTA_LANES_DEFAULT,
-                                 count_path=False)
-                self.state, self.dep = self._fold_delta(
-                    self.state, self.dep, db,
-                    np.int32(self._tick_no))
-                n += len(chunks[0])
-                self.stats.bump("preagg_delta_records",
-                                len(chunks[0]))
-            elif kind == "cpumem":
-                self.state = self._fold_cm(self.state, self._stack(
-                    decode.cpumem_batch_fast, chunks[0],
-                    wire.MAX_CPUMEM_PER_BATCH))
-                n += len(chunks[0])
-                self.stats.bump("cpumem_records", len(chunks[0]))
-            elif kind == "trace":
-                with self._reg_lock:
-                    self.traceconns.observe(chunks[0])
-                self.state = self._fold_trace(self.state, self._stack(
-                    decode.trace_batch, chunks[0],
-                    wire.MAX_TRACE_PER_BATCH, count_path=False))
-                n += len(chunks[0])
-                self.stats.bump("trace_records", len(chunks[0]))
-                if self.opts.trace_resp_bridge:
-                    rs = decode.resp_from_trace(chunks[0])
-                    # per-host precedence (see Runtime.feed): RECENT
-                    # native resp streams win; the bridge fills gaps
-                    from gyeeta_tpu.runtime import _RESP_FRESH_TICKS
-                    hid = rs["host_id"]
-                    fresh = (self._tick_no - self._host_resp_tick[
-                        np.minimum(hid, self.cfg.n_hosts - 1)]
-                        <= _RESP_FRESH_TICKS)
-                    rs = rs[(hid >= self.cfg.n_hosts) | ~fresh]
-                    if len(rs):
-                        self._stage_raw(self._resp_raw,
-                                        self._resp_staged, rs)
-                        self._n_resp_raw += len(rs)
-                        self.stats.bump("resp_from_trace", len(rs))
-            elif kind == "listener_info":
-                # registry updates under the registry lock — their
-                # columns render on query worker threads in snapshot
-                # mode (see Runtime.ingest_records)
-                with self._reg_lock:
-                    self.stats.bump("listener_infos",
-                                    self.svcreg.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "host_info":
-                with self._reg_lock:
-                    self.stats.bump("host_infos",
-                                    self.hostinfo.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "mount":
-                with self._reg_lock:
-                    self.stats.bump("mount_records",
-                                    self.mounts.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "netif":
-                with self._reg_lock:
-                    self.stats.bump("netif_records",
-                                    self.netifs.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "cgroup":
-                with self._reg_lock:
-                    self.stats.bump("cgroup_records",
-                                    self.cgroups.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "agent_stats":
-                # agent delivery-continuity deltas → server counters
-                # (same fold as Runtime.ingest_records)
-                a = chunks[0]
-                for fld, ctr in (
-                        ("spool_dropped", "spool_dropped"),
-                        ("spool_dropped_records",
-                         "spool_dropped_records"),
-                        ("spool_resent", "spool_resent"),
-                        ("connect_timeouts", "agent_connect_timeouts")):
-                    tot = int(a[fld].sum())
-                    if tot:
-                        self.stats.bump(ctr, tot)
-            elif kind == "names":
-                with self._reg_lock:
-                    self.stats.bump("names_interned",
-                                    self.names.update(chunks[0]))
+            if kind in SECTION_COUNTERS:
+                n += self._fold_section(kind, chunk)
+            else:
+                n += self._ingest_host_kind(kind, chunk)
         return n
+
+    def _fold_section(self, kind: str, recs) -> int:
+        """One sweep-subsystem chunk: routed by host, stacked, folded in
+        a dispatch of its own."""
+        if kind == "trace":
+            self._observe_trace(recs)
+        fold, lanes = self._sect_folds[kind]
+        builder = self._sect_builders[kind]
+        batch = self._stack(lambda r, sz: builder(r, sz, self.stats),
+                            recs, lanes)
+        if kind == "delta":
+            self.state, self.dep = fold(self.state, self.dep, batch,
+                                        np.int32(self._tick_no))
+        else:
+            self.state = fold(self.state, batch)
+        self.stats.bump(SECTION_COUNTERS[kind], len(recs))
+        return len(recs)
+
+    def _stage_bridged_resp(self, rs) -> None:
+        """Trace→resp bridge samples join their shards' resp buckets."""
+        self._stage_raw(self._resp_raw, self._resp_staged, rs)
+        self._n_resp_raw += len(rs)
 
     def _stage_raw(self, buckets: list, counts: list, recs) -> None:
         """Hash each record's host to its shard (the layout's stable
@@ -598,24 +486,16 @@ class ShardedRuntime:
                         self.state = self._td_flush(self.state)
                         self.stats.bump("td_partial_flushes")
             with span("fold_enqueue", nrec=nc + nr, annotate=True):
-                if self._fused:
-                    # ONE fused dispatch: fold + dep (a2a pairing) +
-                    # pressure output — no observation dispatch
-                    fn = self._fold_dep_slab \
-                        if lanes_c > self.cfg.conn_batch \
-                        else self._fold_dep_chunk
-                    self.state, self.dep, self._pressure = fn(
-                        self.state, self.dep, cbs, rbs,
-                        np.int32(self._tick_no))
-                    self.stats.bump("fold_dispatches")
-                else:
-                    self.state = self._fold(self.state, cbs, rbs)
+                # ONE dispatch: fold + dep (a2a pairing) + pressure
+                # output — no observation dispatch
+                fn = self._fold_dep_slab \
+                    if lanes_c > self.cfg.conn_batch \
+                    else self._fold_dep_chunk
+                self.state, self.dep, self._pressure = fn(
+                    self.state, self.dep, cbs, rbs,
+                    np.int32(self._tick_no))
+                self.stats.bump("fold_dispatches")
         self._td_dirty = True
-        if not self._fused:
-            self._pressure = self._td_pressure(self.state)
-            dep_fn = self._dep_slab if lanes_c > self.cfg.conn_batch \
-                else self._dep_step
-            self.dep = dep_fn(self.dep, cbs, np.int32(self._tick_no))
 
     def flush(self) -> int:
         """Fold staged raw leftovers (chunk-width dispatches) — state
@@ -1014,18 +894,14 @@ class ShardedRuntime:
         pipeline and the rollup collectives serve the frozen view
         unchanged)."""
         from gyeeta_tpu.query.snapshot import EngineSnapshot
-        from gyeeta_tpu.runtime import snapshot_copy
         with self.spans.span("snapshot_publish", annotate=True):
-            state, dep = snapshot_copy(self, (self.state, self.dep))
+            state, dep = self._snap_copy((self.state, self.dep))
         self._snap_version += 1
         snap = EngineSnapshot(
             self, state, dep, tick=self._tick_no,
             published_at=self._clock(), version=self._snap_version,
             result_cache_max=int(os.environ.get(
                 "GYT_QUERY_CACHE_MAX", "1024")))
-        # ping-pong donation candidate (see Runtime.publish_snapshot —
-        # only retained when the flag is on)
-        self._snap_old = self.snapshot if self._snap_pingpong else None
         self.snapshot = snap
         if self._tick_p0 is not None:     # see Runtime.publish_snapshot
             self.spans.interval("tick_visible", self._tick_p0,

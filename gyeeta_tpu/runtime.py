@@ -25,6 +25,8 @@ from gyeeta_tpu.alerts import AlertManager
 from gyeeta_tpu.engine import aggstate, compact, step
 from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.history import open_store
+from gyeeta_tpu.hostingest import SECTION_COUNTERS, HostIngest, \
+    section_builders
 from gyeeta_tpu.obs import health as obs_health
 from gyeeta_tpu.obs import xlamon
 from gyeeta_tpu.obs.spans import SpanTracer
@@ -37,11 +39,6 @@ from gyeeta_tpu.utils import dnsmap as _dnsmap
 from gyeeta_tpu.utils.config import RuntimeOpts
 from gyeeta_tpu.utils.intern import InternTable
 from gyeeta_tpu.utils.selfstats import Stats
-
-
-# a native resp stream is "live" for bridge-suppression purposes if it
-# reported within this many base ticks (2 min at 5s)
-_RESP_FRESH_TICKS = 24
 
 
 _JIT_MEMO: dict = {}
@@ -65,120 +62,30 @@ def _memo_jit(key: tuple, make):
     return fn
 
 
-def snap_pingpong_enabled(env=None) -> bool:
-    """Snapshot ping-pong prototype (ROADMAP query item (a)): donate
-    the retired (N-2) snapshot's buffers back as the next tree-copy's
-    destination. ~12x cheaper publish at the 32k geometry in an earlier
-    CPU-backend run (bench.py ``snap_pingpong`` row; not measured on a
-    chip — the plain copy pays full-state alloc+free every publish).
-    Default OFF
-    because the win has a sharp edge: on CPU the merged-column renders
-    are ZERO-COPY numpy views of snapshot buffers, so an off-tick
-    consumer (history writer queue, alert delivery) more than two
-    ticks behind could still hold views of the N-2 snapshot when its
-    buffers are donated — reading reused memory SILENTLY. The refcount
-    guard in :func:`snapshot_copy` protects the snapshot OBJECT only,
-    not loose views. Enable when those consumers provably drain within
-    the tick (OPERATIONS.md "Fleet-scale deployment")."""
-    env = os.environ if env is None else env
-    return str(env.get("GYT_SNAP_PINGPONG", "0")).strip().lower() \
-        in ("1", "true", "yes")
-
-
-def make_pingpong_copy():
-    """The donating tree-copy: output buffers may alias the retired
-    snapshot's leaves (same shapes/dtypes every publish).
-    ``keep_unused`` keeps the donated pytree in the compiled signature
-    — jax would otherwise prune the unused arg and donation could
-    never alias."""
-    return jax.jit(lambda old, t: jax.tree.map(jnp.copy, t),
-                   donate_argnums=(0,), keep_unused=True)
-
-
-def snapshot_copy(rt, tree):
-    """(state, dep) copy for snapshot publication. With ping-pong on,
-    the N-2 snapshot — retired at the LAST publish and provably
-    unreferenced now (refcount guard: queries in flight still hold the
-    object if any are reading it) — donates its buffers as the copy's
-    destination. Counted either way (``gyt_snapshot_pingpong_*``) so
-    the hit rate is observable."""
-    import sys as _sys
-
-    old = getattr(rt, "_snap_old", None)
-    rt._snap_old = None
-    pp = getattr(rt, "_snap_copy_pp", None)
-    if pp is None:
-        return rt._snap_copy(tree)
-    if old is not None and _sys.getrefcount(old) == 2:
-        try:
-            out = pp((old.state, old.dep), tree)
-            rt.stats.bump("snapshot_pingpong_donations")
-            return out
-        except Exception:              # noqa: BLE001 — prototype guard
-            rt.stats.bump("snapshot_pingpong_errors")
-            return rt._snap_copy(tree)
-    rt.stats.bump("snapshot_pingpong_fallbacks")
-    return rt._snap_copy(tree)
-
-
-def fused_fold_enabled(env=None) -> bool:
-    """The fused ``fold_all`` megakernel is the default fold path;
-    ``GYT_FUSED_FOLD=0`` selects the legacy per-subsystem dispatch
-    sequence (the escape hatch — kept selectable and parity-tested,
-    tests/test_fusedfold.py)."""
-    env = os.environ if env is None else env
-    return str(env.get("GYT_FUSED_FOLD", "1")).strip().lower() \
-        not in ("0", "false", "no")
-
-
-def _slab_lanes(env=None) -> dict:
-    """Per-subsystem staging-slab lane capacities of the fused fold
-    slab (fixed → one compiled shape per presence combination). Sized
-    at 1-2 wire-max batches per section: sweep subsystems arrive at 5s
-    cadence, so a deeper slab only adds padding cost to the fused
-    dispatch. ``GYT_SLAB_<KIND>_LANES`` overrides (OPERATIONS.md
-    "Fold-path tuning")."""
-    env = os.environ if env is None else env
-    base = {
-        "listener": 2 * wire.MAX_LISTENERS_PER_BATCH,
-        "host": wire.MAX_HOSTS_PER_BATCH,
-        "task": 2 * wire.MAX_TASKS_PER_BATCH,
-        "cpumem": wire.MAX_CPUMEM_PER_BATCH,
-        "trace": wire.MAX_TRACE_PER_BATCH,
-        "ping": wire.MAX_PINGS_PER_BATCH,
-        # SKETCH_DELTA records per dispatch (each expands into its
-        # per-family payload lanes host-side); must stay >= the
-        # drain_chunks chunk size (decode.DELTA_LANES_DEFAULT)
-        "delta": decode.DELTA_LANES_DEFAULT,
-    }
-    lanes = {k: int(env.get(f"GYT_SLAB_{k.upper()}_LANES", v))
-             for k, v in base.items()}
-    lanes["delta"] = max(lanes["delta"], decode.DELTA_LANES_DEFAULT)
-    return lanes
-
-
-# fused-slab section plumbing: selfstats counter, wire subtype (for the
-# raw-backlog concat dtype) and columnar builder per device-fold kind
-_SECTION_COUNTERS = {
-    "listener": "listener_records", "host": "host_records",
-    "task": "task_records", "ping": "task_pings",
-    "cpumem": "cpumem_records", "trace": "trace_records",
-    "delta": "preagg_delta_records",
+# Per-subsystem staging-slab lane capacities of the fold slab (fixed →
+# one compiled shape per presence combination). Sized at 1-2 wire-max
+# batches per section: sweep subsystems arrive at 5s cadence, so a
+# deeper slab only adds padding cost to the dispatch.
+_SLAB_LANES = {
+    "listener": 2 * wire.MAX_LISTENERS_PER_BATCH,
+    "host": wire.MAX_HOSTS_PER_BATCH,
+    "task": 2 * wire.MAX_TASKS_PER_BATCH,
+    "cpumem": wire.MAX_CPUMEM_PER_BATCH,
+    "trace": wire.MAX_TRACE_PER_BATCH,
+    "ping": wire.MAX_PINGS_PER_BATCH,
+    # SKETCH_DELTA records per dispatch (each expands into its
+    # per-family payload lanes host-side): the drain_chunks chunk size
+    "delta": decode.DELTA_LANES_DEFAULT,
 }
+
+
+# wire subtype per device-fold kind (the raw-backlog concat dtype);
+# hostingest holds their selfstats counters and columnar builders
 _SECTION_SUBTYPES = {
     "listener": wire.NOTIFY_LISTENER_STATE, "host": wire.NOTIFY_HOST_STATE,
     "task": wire.NOTIFY_AGGR_TASK_STATE, "ping": wire.NOTIFY_TASK_PING,
     "cpumem": wire.NOTIFY_CPU_MEM_STATE, "trace": wire.NOTIFY_REQ_TRACE,
     "delta": wire.NOTIFY_SKETCH_DELTA,
-}
-_SECTION_BUILDERS = {
-    "listener": lambda r, sz, st: decode.listener_batch_fast(r, sz,
-                                                             stats=st),
-    "host": lambda r, sz, st: decode.host_batch_fast(r, sz, stats=st),
-    "task": lambda r, sz, st: decode.task_batch_fast(r, sz, stats=st),
-    "ping": lambda r, sz, st: decode.ping_batch(r, sz, stats=st),
-    "cpumem": lambda r, sz, st: decode.cpumem_batch_fast(r, sz, stats=st),
-    "trace": lambda r, sz, st: decode.trace_batch(r, sz),
 }
 
 
@@ -191,7 +98,7 @@ def fold_all_name(names: tuple) -> str:
     return "_".join([head] + rest)
 
 
-class Runtime:
+class Runtime(HostIngest):
     def __init__(self, cfg: Optional[EngineCfg] = None,
                  opts: Optional[RuntimeOpts] = None,
                  clock=None):
@@ -272,13 +179,8 @@ class Runtime:
         self._resp_raw: list = []
         self._n_conn_raw = 0
         self._n_resp_raw = 0
-        # last tick each host sent a native RESP_SAMPLE: the trace→resp
-        # bridge skips hosts with a RECENT native stream (per-host
-        # precedence — no steady-state double counting when a host
-        # sends both; a dead resp stream un-suppresses after
-        # _RESP_FRESH_TICKS). Startup transient: trace frames arriving
-        # before the host's first resp frame are bridged and may
-        # overlap the first native window — bounded by one window.
+        # last tick each host sent a native RESP_SAMPLE (the trace→resp
+        # bridge's precedence rule, HostIngest._note_native_resp)
         self._host_resp_tick = np.full(self.cfg.n_hosts, -(10 ** 9),
                                        np.int64)
         self._td_dirty = False        # digest stage may be non-empty
@@ -292,25 +194,6 @@ class Runtime:
         cfg = self.cfg
         mj = lambda tag, make, *extra: _memo_jit(  # noqa: E731
             (tag, cfg, *extra), make)
-        self._fold = mj("fold", lambda: step.jit_fold_step(cfg))
-        self._fold_lst = mj("lst", lambda: jax.jit(
-            lambda s, b: step.ingest_listener(cfg, s, b),
-            donate_argnums=(0,)))
-        self._fold_host = mj("host", lambda: jax.jit(
-            lambda s, b: step.ingest_host(cfg, s, b),
-            donate_argnums=(0,)))
-        self._fold_task = mj("task", lambda: jax.jit(
-            lambda s, b: step.ingest_task(cfg, s, b),
-            donate_argnums=(0,)))
-        self._fold_ping = mj("ping", lambda: jax.jit(
-            lambda s, b: step.ping_tasks(cfg, s, b),
-            donate_argnums=(0,)))
-        self._fold_cm = mj("cm", lambda: jax.jit(
-            lambda s, b: step.ingest_cpumem(cfg, s, b),
-            donate_argnums=(0,)))
-        self._fold_trace = mj("trace", lambda: jax.jit(
-            lambda s, b: step.ingest_trace(cfg, s, b),
-            donate_argnums=(0,)))
         _api_age = self.opts.api_max_age_ticks
         self._age_apis = mj("age_apis", lambda: jax.jit(
             lambda s: step.age_apis(cfg, s, _api_age),
@@ -353,15 +236,6 @@ class Runtime:
         # snapshot N on worker threads while the fold builds N+1)
         self._snap_copy = mj("snap_copy", lambda: jax.jit(
             lambda t: jax.tree.map(jnp.copy, t)))
-        # GYT_SNAP_PINGPONG=1: donate the RETIRED snapshot's buffers as
-        # the next copy's destination (ROADMAP query item (a) — halves
-        # HBM churn per publish where the backend implements donation;
-        # see snapshot_copy for the refcount guard and the CPU-view
-        # caveat, exercised by bench.py's snap_pingpong phase)
-        self._snap_pingpong = snap_pingpong_enabled()
-        self._snap_copy_pp = mj("snap_copy_pp", make_pingpong_copy) \
-            if self._snap_pingpong else None
-        self._snap_old = None         # retired-snapshot donation pool
         self.snapshot = None          # last published EngineSnapshot
         self._snap_version = 0
         # host-side registry renders (snapshot aux views) run on query
@@ -382,41 +256,15 @@ class Runtime:
         # own stacked DepGraph — see parallel/depgraph.py)
         self.dep = dg.init(self.opts.dep_pair_capacity,
                            self.opts.dep_edge_capacity)
-        self._dep_step = mj("dep_step", lambda: jax.jit(
-            dg.dep_step, donate_argnums=(0,)))
-        # slab hot path: engine fold + dep fold in ONE dispatch — one
-        # host→device transfer of the slab tree, one jit-call overhead,
-        # and XLA can schedule the two independent folds together
-        self._fold_many_dep = mj("fold_many_dep", lambda: jax.jit(
-            lambda st, dep, cbs, rbs, tick: (
-                step.fold_many(cfg, st, cbs, rbs),
-                dg.dep_fold_many(dep, cbs, tick)),
-            donate_argnums=(0, 1)))
         _pttl = self.opts.dep_pair_ttl_ticks
         _ettl = self.opts.dep_edge_ttl_ticks
         self._dep_age = mj("dep_age", lambda: jax.jit(
             lambda d, t: dg.age(d, t, _pttl, _ettl),
             donate_argnums=(0,)), _pttl, _ettl)
-        # edge pre-aggregation fold (NOTIFY_SKETCH_DELTA): one donated
-        # dispatch folding a DeltaBatch into state AND dep (legacy
-        # path; the fused path folds deltas inside fold_all)
-        self._fold_delta = mj("delta", lambda: jax.jit(
-            lambda s, d, b, t: step.ingest_delta(cfg, s, d, b, t),
-            donate_argnums=(0, 1)))
-        # delta decode geometry: payload indices outside it are
-        # dropped + counted at decode, never scattered out of range
-        self._delta_dims = dict(
-            resp_nbuckets=cfg.resp_spec.nbuckets,
-            hll_m_svc=1 << cfg.hll_p_svc,
-            hll_m_glob=1 << cfg.hll_p_global)
-        # ---- fused fold path (the default; GYT_FUSED_FOLD=0 keeps the
-        # legacy per-subsystem dispatch sequence above selectable) ----
-        self._fused = fused_fold_enabled()
-        self._slab_lanes_cfg = _slab_lanes()
-        self._sect_builders = dict(_SECTION_BUILDERS)
-        self._sect_builders["delta"] = \
-            lambda r, sz, st: decode.delta_batch(r, sz, stats=st,
-                                                 **self._delta_dims)
+        # ---- the fold path: every staged section and the conn/resp
+        # slab ride ONE fold_all dispatch ----
+        self._slab_lanes_cfg = _SLAB_LANES
+        self._sect_builders = section_builders(cfg)
         # per-subsystem staging sections: raw record-array backlogs that
         # ride the NEXT fold_all dispatch (drained at the end of every
         # ingest_records call, so they never outlive a feed batch)
@@ -506,7 +354,7 @@ class Runtime:
         ``server/gy_mconnhdlr.h:350``): raw conn/resp record arrays are
         STAGED host-side as-is and, once ``cfg.fold_k`` microbatches'
         worth accumulate, decoded in one flat native columnar pass and
-        dispatched through ``_fold_many_dep`` (engine fold + dep fold,
+        dispatched through ``step.fold_all`` (engine fold + dep fold,
         flattened to a single (K·B,)-lane batch — no ``lax.scan``) —
         no device readbacks anywhere in this path. Partial backlogs stay
         staged until the next ``feed``/``flush()``; ``run_tick``/
@@ -547,18 +395,9 @@ class Runtime:
         """Fold a drained {subtype: record array} dict (the post-
         deframe half of :meth:`feed` — the feed pipeline's decode
         worker hands these over, ``ingest/pipeline.py``)."""
-        n = 0
-        # sweep-seq marks: advance the per-host high-water mark (max is
-        # order-insensitive, so the concatenated drain order is fine)
-        sw = recs.pop(wire.NOTIFY_SWEEP_SEQ, None)
-        if sw is not None and len(sw):
-            for h, s in zip(sw["host_id"].tolist(), sw["seq"].tolist()):
-                if s > self._sweep_last_seq.get(h, 0):
-                    self._sweep_last_seq[h] = s
-            self.stats.bump("sweep_marks", len(sw))
-            n += len(sw)
+        n = self._ingest_sweep_marks(recs.pop(wire.NOTIFY_SWEEP_SEQ, None))
         # conn/resp hot path: stage the raw record arrays as-is — the
-        # per-slab decode in _dispatch_slab is the only decode they get
+        # per-slab decode in _dispatch_fused is the only decode they get
         conn = recs.pop(wire.NOTIFY_TCP_CONN, None)
         if conn is not None and len(conn):
             with self._reg_lock:
@@ -569,162 +408,46 @@ class Runtime:
             n += len(conn)
         resp = recs.pop(wire.NOTIFY_RESP_SAMPLE, None)
         if resp is not None and len(resp):
-            hid = resp["host_id"]
-            self._host_resp_tick[hid[hid < self.cfg.n_hosts]] = \
-                self._tick_no
+            self._note_native_resp(resp)
             self._resp_raw.append(resp)
             self._n_resp_raw += len(resp)
             self.stats.bump("resp_events", len(resp))
             n += len(resp)
-        for kind, *chunks in decode.drain_chunks(
+        for kind, chunk in decode.drain_chunks(
                 recs, self.cfg.conn_batch, self.cfg.resp_batch,
                 self.cfg.listener_batch):
-            if self._fused and kind in _SECTION_COUNTERS:
-                n += self._stage_section(kind, chunks[0])
-            elif kind == "listener":
-                lb = decode.listener_batch_fast(chunks[0],
-                                                self.cfg.listener_batch,
-                                                stats=self.stats)
-                self.state = self._fold_lst(self.state, lb)
-                n += len(chunks[0])
-                self.stats.bump("listener_records", len(chunks[0]))
-            elif kind == "host":
-                hb = decode.host_batch_fast(chunks[0], stats=self.stats)
-                self.state = self._fold_host(self.state, hb)
-                n += len(chunks[0])
-                self.stats.bump("host_records", len(chunks[0]))
-            elif kind == "task":
-                tb = decode.task_batch_fast(chunks[0], stats=self.stats)
-                self.state = self._fold_task(self.state, tb)
-                n += len(chunks[0])
-                self.stats.bump("task_records", len(chunks[0]))
-            elif kind == "ping":
-                pb = decode.ping_batch(chunks[0], stats=self.stats)
-                self.state = self._fold_ping(self.state, pb)
-                n += len(chunks[0])
-                self.stats.bump("task_pings", len(chunks[0]))
-            elif kind == "delta":
-                db = decode.delta_batch(
-                    chunks[0], self._slab_lanes_cfg["delta"],
-                    stats=self.stats, **self._delta_dims)
-                self.state, self.dep = self._fold_delta(
-                    self.state, self.dep, db,
-                    np.int32(self._tick_no))
-                n += len(chunks[0])
-                self.stats.bump("preagg_delta_records",
-                                len(chunks[0]))
-            elif kind == "cpumem":
-                cmb = decode.cpumem_batch_fast(chunks[0],
-                                               stats=self.stats)
-                self.state = self._fold_cm(self.state, cmb)
-                n += len(chunks[0])
-                self.stats.bump("cpumem_records", len(chunks[0]))
-            elif kind == "trace":
-                self._observe_trace(chunks[0])
-                trb = decode.trace_batch(chunks[0])
-                self.state = self._fold_trace(self.state, trb)
-                n += len(chunks[0])
-                self.stats.bump("trace_records", len(chunks[0]))
-            elif kind == "listener_info":
-                # registry updates run under the registry lock: their
-                # columns render on query worker threads in snapshot
-                # mode (query/snapshot.py) and dict iteration must not
-                # race a structural mutation
-                with self._reg_lock:
-                    self.stats.bump("listener_infos",
-                                    self.svcreg.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "host_info":
-                with self._reg_lock:
-                    self.stats.bump("host_infos",
-                                    self.hostinfo.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "cgroup":
-                with self._reg_lock:
-                    self.stats.bump("cgroup_records",
-                                    self.cgroups.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "mount":
-                with self._reg_lock:
-                    self.stats.bump("mount_records",
-                                    self.mounts.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "netif":
-                with self._reg_lock:
-                    self.stats.bump("netif_records",
-                                    self.netifs.update(chunks[0]))
-                n += len(chunks[0])
-            elif kind == "agent_stats":
-                # agent delivery-continuity deltas → server counters
-                # (the only process that can see a spool drop is the
-                # agent; the server is where /metrics renders)
-                a = chunks[0]
-                for fld, ctr in (
-                        ("spool_dropped", "spool_dropped"),
-                        ("spool_dropped_records",
-                         "spool_dropped_records"),
-                        ("spool_resent", "spool_resent"),
-                        ("connect_timeouts", "agent_connect_timeouts")):
-                    tot = int(a[fld].sum())
-                    if tot:
-                        self.stats.bump(ctr, tot)
-            elif kind == "names":
-                # names don't count into n (not telemetry events) but
-                # DO invalidate cached columns: resolved name strings
-                # are part of every snapshot view
-                with self._reg_lock:
-                    self.stats.bump("names_interned",
-                                    self.names.update(chunks[0]))
-                self._cols.bump()
-        if self._fused:
-            self._dispatch_fused_pending()
-        else:
-            self._dispatch_full_slabs()
+            if kind in SECTION_COUNTERS:
+                n += self._stage_section(kind, chunk)
+            else:
+                n += self._ingest_host_kind(kind, chunk)
+        self._dispatch_fused_pending()
         if n:
             self._cols.bump()
         return n
 
-    def _observe_trace(self, recs) -> None:
-        """Host-side half of the trace fold (registry observe + the
-        trace→resp bridge with per-host native-stream precedence) —
-        shared by the fused staging path and the legacy dispatch."""
-        with self._reg_lock:
-            self.traceconns.observe(recs)
-        if self.opts.trace_resp_bridge:
-            rs = decode.resp_from_trace(recs)
-            # per-host precedence: hosts with a RECENT native resp
-            # stream are not bridged (no double counting; a dead
-            # native stream un-suppresses)
-            hid = rs["host_id"]
-            fresh = (self._tick_no - self._host_resp_tick[
-                np.minimum(hid, self.cfg.n_hosts - 1)]
-                <= _RESP_FRESH_TICKS)
-            rs = rs[(hid >= self.cfg.n_hosts) | ~fresh]
-            if len(rs):
-                self._resp_raw.append(rs)
-                self._n_resp_raw += len(rs)
-                self.stats.bump("resp_from_trace", len(rs))
+    def _stage_bridged_resp(self, rs) -> None:
+        """Trace→resp bridge samples join the native resp backlog."""
+        self._resp_raw.append(rs)
+        self._n_resp_raw += len(rs)
 
-    # ------------------------------------------------- fused fold path
+    # ------------------------------------------------------ fold path
     def _stage_section(self, kind: str, recs) -> int:
-        """Stage one drained subsystem chunk into the fused-fold slab
-        section; dispatches the pending slab first when the section
-        would overflow its fixed lane capacity."""
+        """Stage one drained subsystem chunk into its slab section;
+        dispatches the pending slab first when the section would
+        overflow its fixed lane capacity."""
         if kind == "trace":
             self._observe_trace(recs)
         if self._stage_n[kind] + len(recs) > self._slab_lanes_cfg[kind]:
             self._dispatch_fused()
         self._stage_recs[kind].append(recs)
         self._stage_n[kind] += len(recs)
-        self.stats.bump(_SECTION_COUNTERS[kind], len(recs))
+        self.stats.bump(SECTION_COUNTERS[kind], len(recs))
         return len(recs)
 
     def _dispatch_fused_pending(self) -> None:
         """End-of-ingest fold boundary: one fused dispatch folds every
         staged subsystem section plus (when full) the conn/resp K-slab;
-        extra full K-slabs drain in follow-up connresp-only dispatches.
-        Same fold boundaries as the legacy sequence — grouped into one
-        device dispatch per boundary instead of one per subsystem."""
+        extra full K-slabs drain in follow-up connresp-only dispatches."""
         K = self.cfg.fold_k
         nc, nr = K * self.cfg.conn_batch, K * self.cfg.resp_batch
         while (any(self._stage_n.values())
@@ -764,8 +487,8 @@ class Runtime:
         return jitted
 
     def _dispatch_fused(self, connresp=None) -> None:
-        """ONE fused device dispatch: staged subsystem sections (in the
-        legacy drain order) + optionally the conn/resp slab + the dep
+        """ONE fused device dispatch: staged subsystem sections (folded
+        in ``step.FOLD_ALL_ORDER``) + optionally the conn/resp slab + the dep
         fold + the digest-stage pressure scalar, with full state
         donation. ``connresp``: None (sections only), "slab" (a (K, B)
         double-buffered slab take) or "single" (one (1, B) microbatch —
@@ -880,42 +603,6 @@ class Runtime:
                 self.state = self._td_flush_partial(self.state)
                 self.stats.bump("td_partial_flushes")
 
-    def _dispatch_full_slabs(self) -> None:
-        """Fold every full K-slab of staged raw records. JAX dispatch is
-        async — the device computes slab N while the host decodes slab
-        N+1, so the feed loop never blocks between slabs."""
-        K = self.cfg.fold_k
-        nc, nr = K * self.cfg.conn_batch, K * self.cfg.resp_batch
-        while self._n_conn_raw >= nc or self._n_resp_raw >= nr:
-            self._dispatch_slab()
-
-
-    def _dispatch_slab(self) -> None:
-        """One K-deep device dispatch: flat native columnar decode of up
-        to K·B staged records straight into the stacked (K, B) layout
-        (reshape, no copy), then the scan'd fold — no per-chunk decode,
-        no np.stack (VERDICT r3 #2). Staged chunks decode into the slab
-        buffers at their lane offsets — no staging concatenate either."""
-        K = self.cfg.fold_k
-        crecs, nc = decode.take_raw_chunks(self._conn_raw,
-                                           K * self.cfg.conn_batch)
-        rrecs, nr = decode.take_raw_chunks(self._resp_raw,
-                                           K * self.cfg.resp_batch)
-        self._n_conn_raw -= nc
-        self._n_resp_raw -= nr
-        self._td_flush_on_pressure()
-        with self.spans.span("fold_dispatch", nrec=nc + nr,
-                             path=native.decode_path()):
-            cbs = decode.conn_slab(crecs, K, self.cfg.conn_batch,
-                                   stats=self.stats)
-            rbs = decode.resp_slab(rrecs, K, self.cfg.resp_batch,
-                                   stats=self.stats)
-            self.state, self.dep = self._fold_many_dep(
-                self.state, self.dep, cbs, rbs, self._tick_no)
-        self._pressures.append(self._stage_pressure(self.state))
-        self._td_dirty = True
-        self.stats.bump("slab_dispatches")
-
     def flush(self) -> int:
         """Fold all staged raw records (single-microbatch path when they
         fit one, padded partial slab otherwise). Called at every
@@ -925,30 +612,10 @@ class Runtime:
         Returns records folded."""
         n = self._n_conn_raw + self._n_resp_raw
         while (self._n_conn_raw or self._n_resp_raw
-               or (self._fused and any(self._stage_n.values()))):
-            if not self._fused:
-                if (self._n_conn_raw <= self.cfg.conn_batch
-                        and self._n_resp_raw <= self.cfg.resp_batch):
-                    crecs, _ = decode.take_raw_chunks(
-                        self._conn_raw, self.cfg.conn_batch)
-                    rrecs, _ = decode.take_raw_chunks(
-                        self._resp_raw, self.cfg.resp_batch)
-                    self._n_conn_raw = self._n_resp_raw = 0
-                    cb = decode.conn_batch_parts(
-                        crecs, self.cfg.conn_batch, stats=self.stats)
-                    rb = decode.resp_batch_parts(
-                        rrecs, self.cfg.resp_batch, stats=self.stats)
-                    self.state = self._fold(self.state, cb, rb)
-                    self.dep = self._dep_step(self.dep, cb,
-                                              self._tick_no)
-                    self._td_dirty = True     # resp samples staged
-                else:
-                    self._dispatch_slab()
-            elif (self._n_conn_raw <= self.cfg.conn_batch
+               or any(self._stage_n.values())):
+            if (self._n_conn_raw <= self.cfg.conn_batch
                     and self._n_resp_raw <= self.cfg.resp_batch):
-                # boundary leftovers: one fused (1, B) dispatch — the
-                # same single-microbatch shape the legacy flush uses,
-                # with dep fold + pressure riding the same graph
+                # boundary leftovers: one (1, B) microbatch dispatch
                 self._dispatch_fused(
                     connresp="single"
                     if (self._n_conn_raw or self._n_resp_raw) else None)
@@ -1071,17 +738,13 @@ class Runtime:
         PRE-WARMS the columns dashboards then reuse."""
         from gyeeta_tpu.query.snapshot import EngineSnapshot
         with self.spans.span("snapshot_publish", annotate=True):
-            state, dep = snapshot_copy(self, (self.state, self.dep))
+            state, dep = self._snap_copy((self.state, self.dep))
         self._snap_version += 1
         snap = EngineSnapshot(
             self, state, dep, tick=self._tick_no,
             published_at=self._clock(), version=self._snap_version,
             result_cache_max=int(os.environ.get(
                 "GYT_QUERY_CACHE_MAX", "1024")))
-        # the snapshot being replaced becomes the NEXT publish's
-        # donation candidate — retained ONLY in ping-pong mode (with
-        # the flag off it would just pin an extra full copy in memory)
-        self._snap_old = self.snapshot if self._snap_pingpong else None
         self.snapshot = snap
         if self._tick_p0 is not None:
             # the part of a tick that delays visibility: run_tick entry

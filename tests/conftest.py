@@ -31,18 +31,12 @@ def rng():
     return np.random.default_rng(12345)
 
 
-# CI tiering: the heavy suites — 8-device mesh programs, socket e2e,
-# full-runtime flows — carry the `slow` marker; `ci.sh fast` and the
-# driver's tier-1 command run everything else. Marked by module so a
-# new test in a heavy module inherits the tier automatically
-# (ROADMAP D7 re-tiers).
-_SLOW_MODULES = {
-    "test_shardedrt", "test_mesh2d", "test_mesh_skew", "test_parallel",
-    "test_shardfeed", "test_net",
-    "test_subsystems2", "test_collect", "test_recovery", "test_query",
-    "test_runtime", "test_replay", "test_tracedef", "test_scale",
-    "test_tcpconn", "test_taskproc", "test_semantic", "test_depgraph",
-}
+# CI tiering: `ci.sh fast` and the driver's tier-1 command run
+# everything without the `slow` marker — the two runtimes' own modules
+# (mesh programs, socket e2e, full-runtime flows) included, since they
+# guard every PR that edits the dispatch path. Slow by module: the
+# 131k-row scale test; a few long tests elsewhere are marked by name.
+_SLOW_MODULES = {"test_scale"}
 
 
 def pytest_collection_modifyitems(items):

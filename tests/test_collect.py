@@ -14,6 +14,7 @@ from gyeeta_tpu.net.agent import NetAgent, QueryClient
 from gyeeta_tpu.net.server import GytServer
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.utils.intern import InternTable
+from waiting import send_sweep_fed
 
 needs_proc = pytest.mark.skipif(not os.path.exists("/proc/stat"),
                                 reason="no /proc")
@@ -81,8 +82,7 @@ def test_collect_agent_end_to_end():
         a = NetAgent(seed=0, collect=True)
         await a.connect(host, port)
         await asyncio.sleep(0.3)       # real delta window
-        await a.send_sweep(n_conn=64, n_resp=64)
-        await asyncio.sleep(0.3)
+        await send_sweep_fed(rt, a, n_conn=64, n_resp=64)
         rt.run_tick()
         qc = QueryClient()
         await qc.connect(host, port)
@@ -151,10 +151,9 @@ def test_mount_netif_end_to_end():
         agent = NetAgent(collect=True, n_svcs=2, n_groups=2)
         try:
             await agent.connect(host, port)
-            await agent.send_sweep(n_conn=64, n_resp=64)
-            await asyncio.sleep(0.3)
-            await agent.send_sweep(n_conn=64, n_resp=64)
-            await asyncio.sleep(0.1)
+            await send_sweep_fed(rt, agent, n_conn=64, n_resp=64)
+            await asyncio.sleep(0.3)   # a real window for the rates
+            await send_sweep_fed(rt, agent, n_conn=64, n_resp=64)
             rt.flush()
             qc = QueryClient()
             await qc.connect(host, port)

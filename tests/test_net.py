@@ -19,6 +19,7 @@ from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.ingest import wire
 from gyeeta_tpu.net import GytServer, NetAgent, QueryClient
 from gyeeta_tpu.runtime import Runtime
+from waiting import sweeps_fed
 
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=256, task_capacity=256,
@@ -39,11 +40,11 @@ async def _fleet_session(n_agents: int, hostmap_path=None):
     hids = []
     for a in agents:
         hids.append(await a.connect(host, port))
-    for _ in range(3):
+    for i in range(3):
         for a in agents:
             await a.send_sweep(n_conn=128, n_resp=256)
-        # let the event loops drain the socket before folding
-        await asyncio.sleep(0.05)
+        # the server reads the sockets on this loop: fold what it read
+        await sweeps_fed(rt, (i + 1) * n_agents)
         rt.flush()
         rt.run_tick()
     qc = QueryClient()
@@ -180,7 +181,7 @@ def test_event_frames_fold_into_engine():
         a = NetAgent(seed=0, n_svcs=2)
         await a.connect(host, port)
         await a.send_sweep(n_conn=64, n_resp=128)
-        await asyncio.sleep(0.05)
+        await sweeps_fed(rt, 1)
         rt.flush()
         await a.close()
         await srv.stop()

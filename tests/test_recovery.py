@@ -21,6 +21,7 @@ from gyeeta_tpu.net import GytServer, NetAgent, QueryClient
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.server_main import latest_checkpoint
 from gyeeta_tpu.utils import checkpoint as ckpt
+from waiting import sweeps_fed
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=256, task_capacity=256,
                 conn_batch=256, resp_batch=512, listener_batch=64,
@@ -46,10 +47,10 @@ async def _recovery(tmp_path):
     host, port = await srv1.start()
     agents = [NetAgent(seed=i, n_svcs=2, n_groups=3) for i in range(3)]
     hids1 = [await a.connect(host, port) for a in agents]
-    for _ in range(3):
+    for i in range(3):
         for a in agents:
             await a.send_sweep(n_conn=128, n_resp=256)
-        await asyncio.sleep(0.05)
+        await sweeps_fed(rt1, (i + 1) * len(agents))
         rt1.flush()
         rt1.run_tick()
     pre = await _query(host, port, {"subsys": "svcstate",
@@ -79,10 +80,10 @@ async def _recovery(tmp_path):
     for a in agents:
         hids2.append(await a.connect(host2, port2))
     assert hids2 == hids1                       # sticky placement
-    for _ in range(2):
+    for i in range(2):
         for a in agents:
             await a.send_sweep(n_conn=128, n_resp=256)
-        await asyncio.sleep(0.05)
+        await sweeps_fed(rt2, (i + 1) * len(agents))
         rt2.flush()
         rt2.run_tick()
 

@@ -11,6 +11,7 @@ from gyeeta_tpu.net.server import GytServer
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.sim.partha import ParthaSim
 from gyeeta_tpu.utils import replay
+from waiting import counter, sweeps_fed, until
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=64, conn_batch=64, resp_batch=64,
                 fold_k=2)
@@ -56,7 +57,8 @@ def test_interleaved_fragmented_conns():
                 a2._writer.write(b2[i:i + step])
                 await a2._writer.drain()
             await asyncio.sleep(0)
-        await asyncio.sleep(0.3)
+        await until(lambda: counter(rt, "conn_events") >= 2 * n_ev,
+                    what="both conns' frames")
         rt.flush()
         assert rt.stats.counters.get("frames_bad", 0) == 0
         assert rt.stats.counters["conn_events"] == 2 * n_ev
@@ -80,7 +82,7 @@ def test_record_replay_equivalence(tmp_path):
         for a in agents:
             await a.connect(host, port)
             await a.send_sweep(n_conn=64, n_resp=64)
-        await asyncio.sleep(0.3)
+        await sweeps_fed(rt, 2)
         rt.run_tick()
         qc = QueryClient()
         await qc.connect(host, port)

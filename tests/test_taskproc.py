@@ -21,6 +21,7 @@ from gyeeta_tpu.net import GytServer, NetAgent, QueryClient
 from gyeeta_tpu.net.taskproc import ProcTaskCollector
 from gyeeta_tpu.net.tcpconn import aggr_task_id_of
 from gyeeta_tpu.runtime import Runtime
+from waiting import sweeps_fed
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=256, task_capacity=512,
                 conn_batch=256, resp_batch=512, listener_batch=64,
@@ -73,9 +74,9 @@ async def _real_task_session():
     try:
         await agent.connect(host, port)
         await agent.send_sweep()
-        await asyncio.sleep(0.3)
+        await asyncio.sleep(0.3)           # a real window for cpu deltas
         await agent.send_sweep()           # second sweep: cpu deltas
-        await asyncio.sleep(0.1)
+        await sweeps_fed(rt, 2)
         rt.flush()
         rt.run_tick()
         qc = QueryClient()

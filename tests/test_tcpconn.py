@@ -27,23 +27,22 @@ from gyeeta_tpu.net.tcpconn import (TcpConnCollector, aggr_task_id_of,
                                     list_tcp_netlink, list_tcp_proc,
                                     listener_glob_id)
 from gyeeta_tpu.runtime import Runtime
+from waiting import sweeps_fed
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=256, task_capacity=256,
                 conn_batch=256, resp_batch=512, listener_batch=64,
                 fold_k=2)
 
-ECHO_PORT = 45913
-
-
 class _EchoServer:
     """Tiny local TCP service generating REAL kernel socket state."""
 
-    def __init__(self, port: int = ECHO_PORT):
+    def __init__(self):
         self.srv = socket.socket()
-        self.srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        self.srv.bind(("127.0.0.1", port))
+        # a port the kernel picks: other test workers' sockets live in
+        # the same port space
+        self.srv.bind(("127.0.0.1", 0))
         self.srv.listen(16)
-        self.port = port
+        self.port = self.srv.getsockname()[1]
         threading.Thread(target=self._accept_loop, daemon=True).start()
 
     def _accept_loop(self):
@@ -117,8 +116,12 @@ def test_collector_observes_real_traffic():
         assert len(mine) == 3
         # loopback traffic carries the loopback flag (127/8 both ends)
         assert ((mine["flags"] & 4) != 0).all()
-        # the listener→comm join map names this (python) listener
-        assert gid in d["listener_of_comm"].values()
+        # the listener→comm join map names a listener of this process's
+        # comm: the lowest id among them, and other test workers listen
+        # under the same comm
+        comm = next(c for g, c in col._known_listeners.values()
+                    if g == gid)
+        assert 0 < d["listener_of_comm"][comm] <= gid
         # byte DELTAS: exactly what the clients wrote since baseline
         assert int(mine["bytes_sent"].sum()) == 1500
         # outbound halves carry the owning process group
@@ -138,7 +141,7 @@ def test_collector_observes_real_traffic():
 
 
 def test_idle_conns_emit_nothing_new():
-    echo = _EchoServer(port=ECHO_PORT + 1)
+    echo = _EchoServer()
     try:
         col = TcpConnCollector(host_id=3, machine_id=0x99)
         c = socket.create_connection(("127.0.0.1", echo.port))
@@ -166,12 +169,12 @@ async def _real_session():
     rt = Runtime(CFG)
     srv = GytServer(rt, tick_interval=None)
     host, port = await srv.start()
-    echo = _EchoServer(port=ECHO_PORT + 2)
+    echo = _EchoServer()
     agent = NetAgent(collect=False, real=True)
     try:
         await agent.connect(host, port)
         await agent.send_sweep()          # baseline sweep
-        await asyncio.sleep(0.1)
+        await sweeps_fed(rt, 1)
         clis = []
         for _ in range(4):
             c = socket.create_connection(("127.0.0.1", echo.port))
@@ -180,7 +183,7 @@ async def _real_session():
             clis.append(c)
         await asyncio.sleep(0.2)
         await agent.send_sweep()
-        await asyncio.sleep(0.1)
+        await sweeps_fed(rt, 2)
         rt.flush()
         rt.run_tick()
         qc = QueryClient()

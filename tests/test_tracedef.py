@@ -11,6 +11,7 @@ from gyeeta_tpu.net.server import GytServer
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.sim.partha import ParthaSim
 from gyeeta_tpu.trace.defs import TraceDef, TraceDefs
+from waiting import counter, sweeps_fed, until
 
 CFG = EngineCfg(n_hosts=8, svc_capacity=64, conn_batch=64, resp_batch=64,
                 api_capacity=512, fold_k=2)
@@ -97,7 +98,7 @@ def test_trace_control_end_to_end():
         for a in agents:
             await a.connect(host, port)
             await a.send_sweep(n_conn=64, n_resp=64)
-        await asyncio.sleep(0.2)
+        await sweeps_fed(rt, 2)
         qc = QueryClient()
         await qc.connect(host, port)
 
@@ -111,13 +112,18 @@ def test_trace_control_end_to_end():
         assert out["ok"]
         rt.run_tick()
         await srv.push_trace_control()
-        await asyncio.sleep(0.2)
         # agents received enablement for their services
-        assert all(len(a.trace_enabled) == a.n_svcs for a in agents)
+        await until(lambda: all(len(a.trace_enabled) == a.n_svcs
+                                for a in agents), what="TRACE_SET enable")
 
         for a in agents:
             await a.send_sweep(n_conn=64, n_resp=256)
-        await asyncio.sleep(0.3)
+        await sweeps_fed(rt, 4)
+        assert counter(rt, "trace_records") == 2 * 256
+        # queries over the wire read the published snapshot: what the
+        # sweep folded is visible from the next tick on, as it is for a
+        # dashboard (the server ticks every 5 s; here by hand)
+        rt.run_tick()
         q = await qc.query({"subsys": "tracereq", "maxrecs": 100})
         assert q["nrecs"] > 0
         st = await qc.query({"subsys": "tracestatus"})
@@ -130,8 +136,8 @@ def test_trace_control_end_to_end():
         assert (await qc.query({"op": "delete", "objtype": "tracedef",
                                 "name": "all-svcs"}))["ok"]
         await srv.push_trace_control()
-        await asyncio.sleep(0.2)
-        assert all(not a.trace_enabled for a in agents)
+        await until(lambda: all(not a.trace_enabled for a in agents),
+                    what="TRACE_SET disable")
 
         await qc.close()
         for a in agents:
