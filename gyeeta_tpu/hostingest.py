@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from gyeeta_tpu.ingest import decode
+from gyeeta_tpu.ingest import decode, native, wire
 
 
 # a native resp stream is "live" for bridge-suppression purposes if it
@@ -74,11 +74,79 @@ _AGENT_STAT_COUNTERS = (
 )
 
 
+def owned(buf, stats) -> bytes:
+    """``buf`` as bytes that outlive the buffer they lie in: ``bytes``
+    as they are; a view of a conn's receive buffer copied once, and
+    counted (``edge_owned_copies``: 0 while nothing that keeps bytes —
+    the journal, the decode pipeline, the shard feeder, the reference
+    adapter — is on)."""
+    if isinstance(buf, bytes):
+        return buf
+    stats.bump("edge_owned_copies")
+    return bytes(buf)
+
+
 class HostIngest:
     """Mixin over ``stats``, ``cfg``, ``opts``, ``_reg_lock``, ``_cols``,
     ``_tick_no``, ``_sweep_last_seq``, ``_host_resp_tick``, the
-    registries named in ``HOST_KINDS``, ``traceconns`` and
-    ``_stage_bridged_resp(recs)``."""
+    registries named in ``HOST_KINDS``, ``traceconns``,
+    ``_stage_bridged_resp(recs)`` and, for :meth:`feed`, ``spans``,
+    ``journal``, ``_journal_replaying``, ``_pending`` and
+    ``ingest_records(recs)``."""
+
+    def feed(self, buf, hid: int = 0, conn_id: int = 0) -> int:
+        """Ingest a byte stream (any number of frames, any mix of types).
+
+        Returns records accepted. Trailing partial frames are buffered for
+        the next call (epoll partial-read resume semantics). ``hid`` /
+        ``conn_id`` attribute the bytes in the write-ahead journal (the
+        serving edge passes them; direct feeds default to 0).
+
+        ``buf`` is any contiguous buffer object. The serving edge
+        (``net/server.py``) hands in a VIEW of a conn's receive buffer,
+        which it overwrites as soon as this returns: ``drain2`` copies
+        the records out, and whoever keeps bytes past the call — the
+        partial-frame resume buffer, the journal's writer queue — takes
+        an owned copy here, counted as ``edge_owned_copies`` (``bytes``
+        in are kept as they are: no copy, no count).
+
+        What happens to the records is the runtime's
+        ``ingest_records``: staged host-side and folded a slab at a
+        time, with no device readback anywhere in this path;
+        ``run_tick`` / ``query`` flush first, so staged events are never
+        invisible at a cadence or query boundary."""
+        with self.spans.span("feed", nrec=len(buf)):
+            return self._feed(buf, hid, conn_id)
+
+    def _feed(self, buf, hid: int, conn_id: int) -> int:
+        # no resume bytes pending (the common case): skip the big-buffer
+        # bytes concat — at slab geometry it copies ~9MB per feed
+        data = (self._pending + buf) if self._pending else buf
+        try:
+            with self.spans.span("deframe", nrec=len(data),
+                                 path=native.decode_path(),
+                                 annotate=True):
+                recs, consumed, unknown = native.drain2(data)
+        except wire.FrameError:
+            self.stats.bump("frames_bad")
+            self._pending = b""       # poison frame: drop buffer, resync
+            raise
+        self._pending = owned(data[consumed:], self.stats) \
+            if consumed < len(data) else b""
+        # WAL append AFTER validation, BEFORE the fold: exactly the
+        # bytes drain2 accepted (a pending partial frame journals in
+        # the call that completes it — each byte exactly once). Replay
+        # suppresses the append (chunks are already in the WAL).
+        if (consumed and self.journal is not None
+                and not self._journal_replaying):
+            self.journal.append(owned(data[:consumed], self.stats),
+                                hid=hid, conn_id=conn_id,
+                                tick=self._tick_no)
+        if unknown:
+            # skipped unknown-subtype frames (version skew / corrupted
+            # subtype byte): accounted loss, never silent loss
+            self.stats.bump("records_unknown_subtype", unknown)
+        return self.ingest_records(recs)
 
     def _ingest_sweep_marks(self, sw) -> int:
         """NOTIFY_SWEEP_SEQ: advance the per-host high-water mark (the

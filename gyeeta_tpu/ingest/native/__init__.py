@@ -340,7 +340,19 @@ def decode_conn(recs, size: int):
     return D.ConnBatch(**cols)
 
 
-def drain(buf: bytes) -> tuple[dict, int]:
+def _buf_ptr(buf):
+    """→ (keepalive, ``c_char_p``, nbytes) of a contiguous buffer
+    object: the C side reads the bytes where they lie (``bytes`` pass
+    as they are; a ``bytearray`` or a ``memoryview``, offset or
+    read-only, through the address numpy resolves for it; numpy
+    refuses one that is not contiguous)."""
+    if isinstance(buf, bytes):
+        return buf, buf, len(buf)
+    a = np.frombuffer(buf, np.uint8)
+    return a, ctypes.c_char_p(a.ctypes.data), a.size
+
+
+def drain(buf) -> tuple[dict, int]:
     """byte stream → ({subtype: structured record array}, consumed).
     Thin wrapper over :func:`drain2` for callers that don't need the
     unknown-subtype record count."""
@@ -348,8 +360,14 @@ def drain(buf: bytes) -> tuple[dict, int]:
     return out, consumed
 
 
-def drain2(buf: bytes) -> tuple[dict, int, int]:
+def drain2(buf) -> tuple[dict, int, int]:
     """byte stream → ({subtype: record array}, consumed, unknown_recs).
+
+    ``buf`` is any contiguous buffer object (``bytes``, ``bytearray``,
+    ``memoryview``): the records are COPIED out into arrays of their
+    own, and nothing of ``buf`` is referenced once this returns — the
+    serving edge hands in a view of a conn's receive buffer and
+    overwrites it right after.
 
     Native path when built; identical semantics to the Python decoder
     (validation errors raise wire.FrameError either way). Two passes
@@ -366,7 +384,8 @@ def drain2(buf: bytes) -> tuple[dict, int, int]:
     counts = (ctypes.c_int64 * n)()
     consumed = ctypes.c_int64()
     unknown = ctypes.c_int64()
-    rc = lib.gyt_scan2(buf, len(buf), counts, ctypes.byref(consumed),
+    _keep, ptr, nbytes = _buf_ptr(buf)      # _keep: alive until return
+    rc = lib.gyt_scan2(ptr, nbytes, counts, ctypes.byref(consumed),
                        ctypes.byref(unknown))
     if rc != 0:
         raise wire.FrameError(f"native scan: {_ERRNAMES.get(rc, rc)}",
@@ -387,7 +406,7 @@ def drain2(buf: bytes) -> tuple[dict, int, int]:
     if not nonempty:
         return out, int(consumed.value), int(unknown.value)
     c2 = ctypes.c_int64()
-    rc = lib.gyt_extract_multi(buf, len(buf), outs, caps, nrec,
+    rc = lib.gyt_extract_multi(ptr, nbytes, outs, caps, nrec,
                                ctypes.byref(c2))
     if rc != 0:
         raise wire.FrameError(f"native extract: {_ERRNAMES.get(rc, rc)}",

@@ -10,12 +10,13 @@ query clients multiplex JSON queries over the same framing (``QUERY_CMD``/
 ``QUERY_RESPONSE``, :502,536).
 
 Connection roles commit at registration (the CLI_TYPE_E discipline,
-``gy_comm_proto.h:91-99``): an event conn switches to bulk reads — every
-``read()`` hands whatever bytes arrived to ``Runtime.feed``, which owns
-framing, partial-frame resume and the staged K-slab fold path, so the
-per-frame work stays in the native deframer, not in Python. A query conn
-stays frame-at-a-time and answers each ``QUERY_CMD`` with a framed JSON
-response (seqid echoed).
+``gy_comm_proto.h:91-99``): an event conn leaves the stream API — the
+transport receives straight into a buffer the conn owns
+(:class:`_EventConn`, ``recv_into``) and every read hands the run of
+complete frames to ``Runtime.feed`` as a VIEW of that buffer, so a
+socket byte is copied once, by the native deframer that extracts the
+records. A query conn stays frame-at-a-time and answers each
+``QUERY_CMD`` with a framed JSON response (seqid echoed).
 
 Concurrency model: one asyncio loop owns the Runtime — the TPU device
 pipeline is the parallelism (no L2 worker pools).
@@ -33,6 +34,7 @@ from typing import Optional
 import numpy as np
 
 from gyeeta_tpu import version
+from gyeeta_tpu.hostingest import owned
 from gyeeta_tpu.ingest import refproto, refquery, wire
 from gyeeta_tpu.net.qexec import Overloaded, ReqClock
 from gyeeta_tpu.runtime import Runtime
@@ -40,6 +42,7 @@ from gyeeta_tpu.runtime import Runtime
 log = logging.getLogger("gyeeta_tpu.net")
 
 _HSZ = wire.HEADER_DT.itemsize
+# an event conn's receive buffer, before any growth (_EventConn)
 _READ_SZ = 1 << 20
 
 
@@ -50,6 +53,209 @@ class _ConnReaped(Exception):
     def __init__(self, kind: str):
         super().__init__(f"conn reaped ({kind} deadline)")
         self.kind = kind
+
+
+class _EventConn(asyncio.BufferedProtocol):
+    """Receive side of one registered event conn.
+
+    The transport fills ``_buf`` in place (``get_buffer`` →
+    ``recv_into``); each read finds the longest run of complete frames,
+    feeds it as a VIEW, and moves only the trailing partial frame (less
+    than one frame) to the front. A read is WORKED ON one turn of the
+    loop after it arrived (``call_soon``), in line with the tasks that
+    query conns' reads wake: done inside the read callback, the whole
+    backlog of every event conn would be folded ahead of a query that
+    became ready first (after a tick: 30-40 ms of it).
+
+    Partial-frame reassembly is per connection: the runtime decoder is
+    shared by every conn, so a conn's tail must never meet another
+    conn's bytes (the reference's per-conn recv buffers give the same
+    guarantee, ``common/gy_epoll_conntrack.h`` partial-read resume).
+    The buffer starts at ``_READ_SZ`` and doubles only while ONE frame
+    does not fit it; ``wire.complete_prefix`` refuses a frame of
+    ``MAX_COMM_DATA_SZ``, which bounds it.
+
+    A conn whose first bytes carry the REFERENCE's COMM_HEADER magics (a
+    stock partha / gy_comm_proto producer) is routed through the ingest
+    adapter (``ingest/refproto.py``): adapted GYT frames feed the same
+    runtime path, and the capture recorder sees the ADAPTED bytes
+    (recorded bytes are always replayable GYT frames).
+
+    The conn was opened by the stream API, and its ``StreamWriter``
+    stays in use for the reverse direction (trace control, throttle),
+    so write flow control and the close are passed on to the stream
+    protocol this one replaced. ``done`` resolves when the conn ends:
+    None at EOF or a lost conn, the exception after a framing error or
+    a reap."""
+
+    def __init__(self, srv: "GytServer", transport, host_id: int,
+                 conn_id: int, ref_session=None):
+        self.srv, self.transport = srv, transport
+        self.host_id, self.conn_id = host_id, conn_id
+        self._stream = transport.get_protocol()
+        self._buf = bytearray(_READ_SZ)
+        self._view = memoryview(self._buf)
+        self._fill = 0
+        self._due = False               # a read waits for _process
+        self._ref_mode = False
+        self._ref_session = ref_session or refproto.RefSession()
+        self.last_rx = time.monotonic()     # GytServer._reap_loop's
+        self._loop = asyncio.get_running_loop()
+        self.done: asyncio.Future = self._loop.create_future()
+
+    def adopt(self, reader) -> None:
+        """Take the transport over from the stream API and carry over,
+        in order, what the reader had buffered behind the handshake (a
+        producer may write REGISTER_REQ and its first frames in one
+        segment). No await between the switch and the carry-over, so no
+        byte can slip past or be fed twice."""
+        self.transport.set_protocol(self)
+        # the reader may have paused the transport at its limit
+        self.transport.resume_reading()
+        early = reader._buffer                   # noqa: SLF001
+        n = len(early)
+        if n:
+            self._reserve(n)
+            self._view[:n] = early
+            early.clear()
+            self.buffer_updated(n)
+        if reader.at_eof():
+            self.eof_received()
+        elif reader.exception() is not None:
+            self.finish()
+
+    # ------------------------------------------- asyncio.BufferedProtocol
+    def get_buffer(self, sizehint: int) -> memoryview:
+        self._process()     # never hand out a buffer a read still fills
+        return self._view[self._fill:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if self.done.done():
+            return
+        self.last_rx = time.monotonic()
+        stats = self.srv.rt.stats
+        stats.bump("edge_reads")
+        stats.bump("edge_bytes", nbytes)
+        self._fill += nbytes
+        if not self._due:
+            self._due = True
+            self._loop.call_soon(self._process)
+
+    def _process(self) -> None:
+        """Work on what the reads since the last call brought (a no-op
+        when there is none: EOF and the next read run it early)."""
+        if not self._due or self.done.done():
+            return
+        self._due = False
+        try:
+            self._received()
+        except Exception as e:      # a FrameError closes THIS conn only
+            self.finish(e)
+
+    def eof_received(self) -> None:
+        self._process()
+        if self._fill and not self.done.done():
+            # EOF mid-frame: the tail was truncated in flight — count
+            # it, don't just drop it on the floor
+            self.srv.rt.stats.bump("frames_rejected|reason=truncated")
+        self.finish()
+
+    def connection_lost(self, exc) -> None:
+        self.finish()
+        self._stream.connection_lost(exc)
+
+    def pause_writing(self) -> None:
+        self._stream.pause_writing()
+
+    def resume_writing(self) -> None:
+        self._stream.resume_writing()
+
+    def finish(self, exc: Optional[BaseException] = None) -> None:
+        """End the conn's receive side (first caller wins); whoever
+        awaits ``done`` closes the transport."""
+        if self.done.done():
+            return
+        if exc is None:
+            self.done.set_result(None)
+        else:
+            self.done.set_exception(exc)
+        self.transport.pause_reading()
+
+    # ------------------------------------------------------------ a read
+    def _received(self) -> None:
+        rt = self.srv.rt
+        fill = self._fill
+        view = self._view
+        if not self._ref_mode and fill >= 4 and int.from_bytes(
+                view[:4], "little") in refproto.REF_MAGICS:
+            self._ref_mode = True
+            rt.stats.bump("conns_ref_adapted")
+        if self._ref_mode:
+            k = self._adapt(view[:fill])
+        else:
+            # edge_rx times the edge's OWN work (the boundary scan
+            # here, the tail move below) and never contains the feed
+            with rt.spans.span("edge_rx", nrec=fill, annotate=True):
+                try:
+                    k = wire.complete_prefix(view[:fill])
+                except wire.FrameError:
+                    # poison header: close the conn — the agent
+                    # reconnects and resyncs (the reference closes on a
+                    # bad COMM_HEADER)
+                    rt.stats.bump("frames_bad")
+                    raise
+            if k:
+                self._ingest(view[:k])
+        tail = fill - k
+        if k and tail:
+            with rt.spans.span("edge_rx", nrec=tail, annotate=True):
+                view[:tail] = view[k:fill]
+            rt.stats.bump("edge_tail_bytes", tail)
+        self._fill = tail
+        if tail == len(self._buf):      # one frame larger than the buffer
+            self._reserve(tail + 1)
+
+    def _ingest(self, run) -> None:
+        """Feed one run of complete GYT frames, then record it: a run
+        that fails deep validation (nevents caps) must not poison the
+        capture file — recorded bytes are exactly the ingested bytes.
+        Pipeline mode records inside the pipeline (validated buffers
+        only). The recorder writes through before it returns and keeps
+        nothing, so it takes the view as it is."""
+        srv = self.srv
+        srv._feed(run, self.host_id, self.conn_id)
+        rec = srv._recorder
+        if rec is not None and srv._pipe is None:
+            rec.write(run)
+
+    def _adapt(self, run) -> int:
+        """Reference frames → GYT frames → feed; returns the bytes of
+        ``run`` consumed. The adapter's decoders slice and keep pieces
+        of what they are given, so it gets an owned copy."""
+        rt = self.srv.rt
+        try:
+            gyt, k = refproto.adapt(owned(run, rt.stats), self.host_id,
+                                    session=self._ref_session)
+        except wire.FrameError:
+            rt.stats.bump("frames_bad")
+            raise
+        if gyt:
+            self._ingest(gyt)
+        # drain AFTER the feed: domain payloads reference listeners
+        # whose LISTENER_INFO may ride the same batch
+        self.srv._drain_ref_session(self._ref_session)
+        return k
+
+    def _reserve(self, need: int) -> None:
+        """Grow the buffer (doubling) until it holds ``need`` bytes."""
+        size = len(self._buf)
+        if size >= need:
+            return
+        while size < need:
+            size *= 2
+        buf = bytearray(size)
+        buf[:self._fill] = self._view[:self._fill]
+        self._buf, self._view = buf, memoryview(buf)
 
 
 class GytServer:
@@ -146,6 +352,8 @@ class GytServer:
         # reference's CLI_TYPE_RESP_REQ conns carry this, gy_comm_proto.h)
         self._event_writers: dict[int, asyncio.StreamWriter] = {}
         self._open_conns: set = set()      # every live conn's writer
+        self._event_conns: set = set()     # live _EventConn (idle reaper)
+        self._reap_task: Optional[asyncio.Task] = None
         self._conn_seq = 0                 # dense conn ids (WAL
         #                                    attribution: torn tails
         #                                    name their conn)
@@ -401,22 +609,32 @@ class GytServer:
         self._pending_domains = nxt
 
     # ----------------------------------------------------------- feed path
-    def _feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
+    def _feed(self, buf, hid: int = 0, conn_id: int = 0) -> int:
         """Ingest complete-frame bytes: through the decode pipeline
         when enabled, else directly. ``hid``/``conn_id`` attribute the
-        bytes in the write-ahead journal."""
+        bytes in the write-ahead journal. ``buf`` may be a view of a
+        conn's receive buffer, overwritten once this returns: the shard
+        feeder and the pipeline hand it to another thread, so they get
+        an owned copy; ``rt.feed`` reads it in place."""
         if self._feeder is not None:
-            return self._feeder.submit(buf, hid=hid, conn_id=conn_id)
+            return self._feeder.submit(owned(buf, self.rt.stats),
+                                       hid=hid, conn_id=conn_id)
         if self._pipe is not None:
-            return self._pipe.feed(buf, hid=hid, conn_id=conn_id)
+            return self._pipe.feed(owned(buf, self.rt.stats),
+                                   hid=hid, conn_id=conn_id)
         return self.rt.feed(buf, hid=hid, conn_id=conn_id)
 
     def _feed_barrier(self) -> None:
-        """Make every submitted byte visible (pipeline / shard-queue /
-        ingest-ring barrier) before a tick or query reads state. With
+        """Make every submitted byte visible (event conns' booked reads,
+        pipeline / shard-queue / ingest-ring barrier) before a tick or
+        query reads state: a tick whose timer came due in the same loop
+        turn as a read must not close over it (after a stall of the
+        process that would be every byte the stall held up). With
         ingest workers this drains what the rings HOLD — bytes still
         inside a worker's deframe loop surface next barrier (the
         cross-process analogue of a conn's partial frame)."""
+        for conn in list(self._event_conns):
+            conn._process()     # a read booked but not yet worked on
         if self._ingest is not None:
             self._ingest.drain()
         if self._feeder is not None:
@@ -481,6 +699,8 @@ class GytServer:
             await self._relay.start()
         if self.tick_interval:
             self._tick_task = asyncio.create_task(self._tick_loop())
+        if self.idle_timeout:
+            self._reap_task = asyncio.create_task(self._reap_loop())
         log.info("gyt server on %s:%d", self.host, self.port)
         return self.host, self.port
 
@@ -511,9 +731,10 @@ class GytServer:
                 log.exception("ingest worker monitor failed")
 
     async def stop(self) -> None:
-        if self._tick_task:
-            self._tick_task.cancel()
-            self._tick_task = None
+        for t in (self._tick_task, self._reap_task):
+            if t:
+                t.cancel()
+        self._tick_task = self._reap_task = None
         if self._relay is not None:
             # stop accepting relay batches before the runtime winds
             # down (a batch landing mid-close would stage into a
@@ -552,6 +773,21 @@ class GytServer:
             self._pipe.close()           # barrier + worker shutdown
         self.qexec.close()   # query worker pool (no new snapshot reads)
         self.rt.close()      # alert delivery worker + history handle
+
+    async def _reap_loop(self) -> None:
+        """The idle deadline of every event conn, checked in ONE place
+        at the tick's cadence instead of a timer per read: an agent conn
+        that stops sweeping (half-open, wedged peer) is reaped on the
+        sweep-cadence budget, at most one period late."""
+        period = min(self.idle_timeout,
+                     self.tick_interval or self.idle_timeout)
+        while True:
+            await asyncio.sleep(period)
+            now = time.monotonic()
+            for conn in list(self._event_conns):
+                if now - conn.last_rx > self.idle_timeout:
+                    self.rt.stats.bump("conn_timeouts|kind=idle")
+                    conn.finish(_ConnReaped("idle"))
 
     async def _tick_loop(self) -> None:
         while True:
@@ -713,7 +949,9 @@ class GytServer:
         return n
 
     async def _tread(self, coro, kind: str):
-        """Await ``coro`` under the ``kind`` conn deadline. A fired
+        """Await ``coro`` under the ``kind`` conn deadline (handshake:
+        any conn before registration; idle: a query conn's next frame —
+        an event conn's idle deadline is ``_reap_loop``'s). A fired
         deadline bumps ``conn_timeouts|kind=...`` and raises
         :class:`_ConnReaped` so the conn unwinds and closes without
         ever blocking the tick loop."""
@@ -817,15 +1055,14 @@ class GytServer:
                     self.rt.stats.bump("conns_ref_rejected")
                     return
                 self.rt.stats.bump("ref_pm_connected")
-                # conns_ref_adapted is counted by the event loop when
+                # conns_ref_adapted is counted by the event conn when
                 # it sees the first reference-magic data (one count
                 # per adapted conn, same as direct-stream ref conns)
-                await self._event_loop(
-                    reader, host_id,
+                await self._serve_events(
+                    reader, writer, host_id, conn_id,
                     ref_session=refproto.RefSession(
                         region=req.get("region_name", ""),
-                        zone=req.get("zone_name", "")),
-                    conn_id=conn_id)
+                        zone=req.get("zone_name", "")))
                 return
             elif dtype == refquery.REF_COMM_NM_CONNECT_CMD:
                 # stock node webserver: the query edge (NM_CONNECT_CMD_S
@@ -931,8 +1168,8 @@ class GytServer:
                         await self._handoff_event_conn(
                             reader, writer, host_id, conn_id)
                     else:
-                        await self._event_loop(reader, host_id,
-                                               conn_id=conn_id)
+                        await self._serve_events(reader, writer,
+                                                 host_id, conn_id)
                 finally:
                     if self._event_writers.get(host_id) is writer:
                         del self._event_writers[host_id]
@@ -989,78 +1226,20 @@ class GytServer:
         self.rt.stats.bump("ingest_conns_handed_off")
         await death.wait()
 
-    async def _event_loop(self, reader, host_id: int = 0,
-                          ref_session=None, conn_id: int = 0) -> None:
-        """Bulk ingest: socket bytes → Runtime.feed.
-
-        Partial-frame reassembly happens HERE, per connection: the
-        runtime decoder is shared by every conn, so each conn's
-        trailing partial frame must be held back or another conn's
-        bytes would splice into the middle of it (the reference's
-        per-conn recv buffers give the same guarantee,
-        ``common/gy_epoll_conntrack.h`` partial-read resume).
-
-        A conn whose frames carry the REFERENCE's COMM_HEADER magics
-        (a stock partha / gy_comm_proto producer) is detected by its
-        first complete header and routed through the ingest adapter
-        (``ingest/refproto.py``) — adapted GYT frames feed the same
-        runtime path, and the capture recorder sees the ADAPTED bytes
-        (recorded bytes are always replayable GYT frames)."""
-        pending = b""
-        ref_mode = False
-        if ref_session is None:               # per-conn adapter state
-            ref_session = refproto.RefSession()
-        while True:
-            # idle deadline: an agent conn that stops sweeping (half-
-            # open, wedged peer) is reaped on the sweep-cadence budget
-            data = await self._tread(reader.read(_READ_SZ), "idle")
-            if not data:
-                if pending:
-                    # EOF mid-frame: the tail was truncated in flight —
-                    # count it, don't just drop it on the floor
-                    self.rt.stats.bump(
-                        "frames_rejected|reason=truncated")
-                return
-            data = pending + data
-            if not ref_mode and len(data) >= 4 and int.from_bytes(
-                    data[:4], "little") in refproto.REF_MAGICS:
-                ref_mode = True
-                self.rt.stats.bump("conns_ref_adapted")
-            if ref_mode:
-                try:
-                    gyt, k = refproto.adapt(data, host_id,
-                                            session=ref_session)
-                except wire.FrameError:
-                    self.rt.stats.bump("frames_bad")
-                    raise
-                pending = data[k:]
-                if gyt:
-                    self._feed(gyt, host_id, conn_id)
-                    # pipeline mode records inside the pipeline (only
-                    # validated buffers)
-                    rec = self._recorder
-                    if rec is not None and self._pipe is None:
-                        rec.write(gyt)
-                # drain AFTER the feed: domain payloads reference
-                # listeners whose LISTENER_INFO may ride the same batch
-                self._drain_ref_session(ref_session)
-                continue
-            try:
-                k = wire.complete_prefix(data)
-            except wire.FrameError:
-                # poison header: close the conn — the agent reconnects
-                # and resyncs (the reference closes on bad COMM_HEADER)
-                self.rt.stats.bump("frames_bad")
-                raise
-            pending = data[k:]
-            if k:
-                # feed FIRST: a chunk that fails deep validation
-                # (nevents caps) must not poison the capture file —
-                # recorded bytes are exactly the ingested bytes
-                self._feed(data[:k], host_id, conn_id)
-                rec = self._recorder   # no await between check & write
-                if rec is not None and self._pipe is None:
-                    rec.write(data[:k])
+    async def _serve_events(self, reader, writer, host_id: int = 0,
+                            conn_id: int = 0, ref_session=None) -> None:
+        """Bulk ingest of one registered event conn: socket bytes →
+        ``Runtime.feed`` through its :class:`_EventConn`, until the
+        conn ends. A framing error or an idle reap comes out of
+        ``done`` as the exception ``_handle_conn`` accounts for."""
+        conn = _EventConn(self, writer.transport, host_id, conn_id,
+                          ref_session)
+        self._event_conns.add(conn)
+        try:
+            conn.adopt(reader)
+            await conn.done
+        finally:
+            self._event_conns.discard(conn)
 
     async def _query_loop(self, reader, writer) -> None:
         try:

@@ -319,32 +319,6 @@ class ShardedRuntime(HostIngest):
         return sharded.put_sharded(self.mesh, sharded.shard_batches(
             self.cfg, self.mesh, (builder, lanes), recs, recs["host_id"]))
 
-    def feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
-        """Byte stream → routed stacked batches → sharded folds."""
-        with self.spans.span("feed", nrec=len(buf)):
-            return self._feed(buf, hid, conn_id)
-
-    def _feed(self, buf: bytes, hid: int, conn_id: int) -> int:
-        data = (self._pending + buf) if self._pending else buf
-        try:
-            with self.spans.span("deframe", nrec=len(data),
-                                 path=native.decode_path(),
-                                 annotate=True):
-                recs, consumed, unknown = native.drain2(data)
-        except wire.FrameError:
-            self.stats.bump("frames_bad")
-            self._pending = b""
-            raise
-        self._pending = data[consumed:]
-        # WAL append post-validation / pre-fold (see Runtime.feed)
-        if (consumed and self.journal is not None
-                and not self._journal_replaying):
-            self.journal.append(data[:consumed], hid=hid,
-                                conn_id=conn_id, tick=self._tick_no)
-        if unknown:
-            self.stats.bump("records_unknown_subtype", unknown)
-        return self.ingest_records(recs)
-
     def ingest_records(self, recs: dict, shard=None) -> int:
         """Fold a drained ``{subtype: record array}`` dict — the
         post-deframe half of :meth:`feed`. The multi-process ingest

@@ -342,55 +342,6 @@ class Runtime(HostIngest):
         self._classify = derive.jit_classify_pass(self.cfg)
 
     # ------------------------------------------------------------- ingest
-    def feed(self, buf: bytes, hid: int = 0, conn_id: int = 0) -> int:
-        """Ingest a byte stream (any number of frames, any mix of types).
-
-        Returns records accepted. Trailing partial frames are buffered for
-        the next call (epoll partial-read resume semantics). ``hid`` /
-        ``conn_id`` attribute the bytes in the write-ahead journal (the
-        serving edge passes them; direct feeds default to 0).
-
-        Hot-path discipline (the DB_WRITE_ARR batching of the reference,
-        ``server/gy_mconnhdlr.h:350``): raw conn/resp record arrays are
-        STAGED host-side as-is and, once ``cfg.fold_k`` microbatches'
-        worth accumulate, decoded in one flat native columnar pass and
-        dispatched through ``step.fold_all`` (engine fold + dep fold,
-        flattened to a single (K·B,)-lane batch — no ``lax.scan``) —
-        no device readbacks anywhere in this path. Partial backlogs stay
-        staged until the next ``feed``/``flush()``; ``run_tick``/
-        ``query`` flush first, so staged events are never invisible at a
-        cadence or query boundary."""
-        with self.spans.span("feed", nrec=len(buf)):
-            return self._feed(buf, hid, conn_id)
-
-    def _feed(self, buf: bytes, hid: int, conn_id: int) -> int:
-        # no resume bytes pending (the common case): skip the big-buffer
-        # bytes concat — at slab geometry it copies ~9MB per feed
-        data = (self._pending + buf) if self._pending else buf
-        try:
-            with self.spans.span("deframe", nrec=len(data),
-                                 path=native.decode_path(),
-                                 annotate=True):
-                recs, consumed, unknown = native.drain2(data)
-        except wire.FrameError:
-            self.stats.bump("frames_bad")
-            self._pending = b""       # poison frame: drop buffer, resync
-            raise
-        self._pending = data[consumed:]
-        # WAL append AFTER validation, BEFORE the fold: exactly the
-        # bytes drain2 accepted (a pending partial frame journals in
-        # the call that completes it — each byte exactly once). Replay
-        # suppresses the append (chunks are already in the WAL).
-        if (consumed and self.journal is not None
-                and not self._journal_replaying):
-            self.journal.append(data[:consumed], hid=hid,
-                                conn_id=conn_id, tick=self._tick_no)
-        if unknown:
-            # skipped unknown-subtype frames (version skew / corrupted
-            # subtype byte): accounted loss, never silent loss
-            self.stats.bump("records_unknown_subtype", unknown)
-        return self.ingest_records(recs)
-
     def ingest_records(self, recs: dict) -> int:
         """Fold a drained {subtype: record array} dict (the post-
         deframe half of :meth:`feed` — the feed pipeline's decode

@@ -414,3 +414,42 @@ def test_native_and_numpy_decoders_agree_through_the_block():
             assert getattr(got, f).tobytes() == \
                 getattr(want, f).tobytes(), f
     assert np.array_equal(block, pack.pack(list(b) + list(br)))
+
+
+# ------------------------------------------------- any buffer object in
+def _as_buffer(kind: str, buf: bytes):
+    """``buf`` as the serving edge may hand it in: the conn's receive
+    buffer is a bytearray, and a run of frames a view into it."""
+    if kind == "bytes":
+        return buf
+    if kind == "bytearray":
+        return bytearray(buf)
+    if kind == "memoryview":                # offset + writable backing
+        return memoryview(bytearray(b"\xa5" * 13 + buf + b"\x5a" * 7)
+                          )[13:13 + len(buf)]
+    return memoryview(b"\xa5" * 5 + buf)[5:]            # read-only
+
+
+@pytest.mark.parametrize("path", ["native", "python"])
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "memoryview",
+                                  "memoryview_ro"])
+def test_drain2_takes_any_buffer(kind, path):
+    """Equal records, ``consumed`` and ``unknown`` whatever object
+    carries the bytes, and nothing of it is referenced afterwards (the
+    edge overwrites its receive buffer right after the call)."""
+    if path == "native" and not native.available():
+        pytest.skip("libgytdeframe.so not built")
+    drain2 = native.drain2 if path == "native" else native._drain_py2
+    whole = (mixed_stream(n_conn=40, n_resp=90)
+             + wire.encode_frame(777, np.zeros(3, wire.RESP_SAMPLE_DT)))
+    buf = whole + mixed_stream(seed=8, n_conn=5, n_resp=0)[:-33]
+    want, consumed_w, unknown_w = drain2(buf)
+    assert len(whole) <= consumed_w < len(buf) and unknown_w == 3
+    obj = _as_buffer(kind, buf)
+    got, consumed, unknown = drain2(obj)
+    assert (consumed, unknown) == (consumed_w, unknown_w)
+    if kind in ("bytearray", "memoryview"):
+        obj[:] = bytes(len(obj))            # the edge's next read
+    assert set(got) == set(want)
+    for st in want:
+        assert got[st].tobytes() == want[st].tobytes(), st

@@ -35,6 +35,7 @@ from gyeeta_tpu.engine import step
 from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.ingest import wire
 from gyeeta_tpu.net import GytServer, QueryClient
+from gyeeta_tpu.net.agent import register
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.sim.partha import ParthaSim
 
@@ -46,6 +47,7 @@ CFG = EngineCfg(n_hosts=8, svc_capacity=64, conn_batch=64, resp_batch=64,
 # spans sit under whoever dispatched: a feed, or the tick's flush.
 _DISPATCH = ("feed", "tick.flush")
 SPANS = {
+    "edge_rx": (None,),     # a socket read's own work; never around feed
     "feed": (None,),
     "deframe": ("feed",),
     "slab_wait": _DISPATCH,
@@ -76,9 +78,9 @@ SPANS = {
     "query_encode": (None,),
 }
 # spans that also enter a TraceAnnotation: the leaves. Parents never do.
-LEAVES = {"deframe", "slab_wait", "slab_decode", "td_flush", "fold_h2d",
-          "fold_enqueue", "tick.flush", "tick.td_drain", "tick.classify",
-          "snapshot_publish", "tick.hh_recover", "tick.alerts",
+LEAVES = {"edge_rx", "deframe", "slab_wait", "slab_decode", "td_flush",
+          "fold_h2d", "fold_enqueue", "tick.flush", "tick.td_drain",
+          "tick.classify", "snapshot_publish", "tick.hh_recover", "tick.alerts",
           "tick.roll", "tick.history", "tick.health", "tick.close",
           "tick_push", "query", "query_render", "query_encode"}
 QUERY_SPANS = ("query_queue", "query", "query_prewarm", "query_render",
@@ -88,7 +90,8 @@ NEW_METRICS = (
     "slab_fold_device_ms", "section_fold_device_ms", "section_fold_share",
     "tick_visible_ms", "tick_flush_ms", "tick_drain_ms", "tick_roll_ms",
     "query_queue_ms", "query_reply_ms", "query_render_ms",
-    "query_cache_hit_share", "h2d_arrays_per_dispatch")
+    "query_cache_hit_share", "h2d_arrays_per_dispatch", "edge_ms_per_mev",
+    "feeds_per_slab")
 VARIANTS = (
     ("connresp",), ("listener",), ("host",), ("listener", "host"),
     ("listener", "connresp"), ("host", "connresp"),
@@ -110,14 +113,20 @@ def _reading(selfstats: dict) -> dict:
     return c
 
 
-async def _phase(rt, qc, sim) -> None:
-    """Two feeds of two slabs each with a listener sweep, the same query
-    twice (a result-cache miss, then a hit), one whole tick."""
+async def _phase(rt, qc, sim, agent) -> None:
+    """Two feeds of two slabs each with a listener sweep, a few conns
+    over an event conn's socket, the same query twice (a result-cache
+    miss, then a hit), one whole tick."""
     tick = rt.stats.gauges.get("tick", 0)
     for _ in range(2):
         rt.feed(sim.listener_frames() + sim.conn_frames(256)
                 + sim.resp_frames(256))
-    rt.feed(sim.conn_frames(16))    # staged: the tick's flush folds it
+    # through the serving edge; staged: the tick's flush folds it
+    want = rt.stats.counters.get("conn_events", 0) + 16
+    agent.write(sim.conn_frames(16))
+    await agent.drain()
+    while rt.stats.counters.get("conn_events", 0) < want:
+        await asyncio.sleep(0.005)
     for _ in range(2):
         await qc.query({"subsys": "svcstate", "maxrecs": 4})
     t0 = time.monotonic()
@@ -133,20 +142,24 @@ async def _toy_run(trace_dir: str) -> dict:
     sim = ParthaSim(n_hosts=8, n_svcs=2, seed=3)
     qc = QueryClient()
     await qc.connect(host, port)
+    _r, agent, status, _hid = await register(host, port, 0x59A9,
+                                             wire.CONN_EVENT)
+    assert status == wire.REG_OK
     rt.feed(sim.name_frames())
-    await _phase(rt, qc, sim)               # every shape compiles here
+    await _phase(rt, qc, sim, agent)        # every shape compiles here
     c0 = _reading(await qc.query({"subsys": "selfstats"}))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        await _phase(rt, qc, sim)
+        await _phase(rt, qc, sim, agent)
     finally:
         jax.profiler.stop_trace()
     c1 = _reading(await qc.query({"subsys": "selfstats"}))
     rows = rt.spans.rows(last=1 << 20)
     stages = {r["stage"]: r["count"] for r in rt.stats.timing_rows()}
     total, cap = rt.spans.total, len(rt.spans)
+    agent.close()
     await qc.close()
     await srv.stop()
     return {"rows": rows, "stages": stages, "c0": c0, "c1": c1,
