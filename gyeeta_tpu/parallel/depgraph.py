@@ -446,7 +446,8 @@ def _dispatch_halves(hv: Halves, axes, sizes, n: int, cap: int):
 
 # ------------------------------------------------------------ edge rollup
 class EdgeSet(NamedTuple):
-    """A dense merged edge view (replicated after rollup)."""
+    """A dense edge view: one shard's own slab, or the replicated merge
+    of every shard's after rollup."""
     tbl: table.Table
     cli_hi: jnp.ndarray
     cli_lo: jnp.ndarray
@@ -455,11 +456,14 @@ class EdgeSet(NamedTuple):
     ser_lo: jnp.ndarray
     nconn: jnp.ndarray
     byts: jnp.ndarray
+    n_dropped: jnp.ndarray   # () i32 — lanes a merge could not place
 
 
 def _edge_merge(cap: int, cli_hi, cli_lo, cli_svc, ser_hi, ser_lo,
                 nconn, byts, valid) -> EdgeSet:
-    """Merge flat edge lanes (counts additive) into a fresh dense slab."""
+    """Merge flat edge lanes (counts additive) into a fresh dense slab.
+    A lane whose 16 probe slots are all taken is left out (odds about
+    load^16 a key) and counted in ``n_dropped``."""
     khi, klo = edge_key(cli_hi, cli_lo, ser_hi, ser_lo)
     tbl, rows = table.upsert(table.init(cap), khi, klo, valid=valid)
     ok = valid & (rows >= 0)
@@ -477,16 +481,21 @@ def _edge_merge(cap: int, cli_hi, cli_lo, cli_svc, ser_hi, ser_lo,
             jnp.where(ok, nconn, 0.0), mode="drop"),
         byts=jnp.zeros((cap,), jnp.float32).at[lanes].add(
             jnp.where(ok, byts, 0.0), mode="drop"),
+        n_dropped=jnp.sum(valid & (rows < 0), dtype=jnp.int32),
     )
 
 
 def edges_local(dep: DepGraph) -> EdgeSet:
-    """Single-shard edge view (no collective) as an EdgeSet."""
-    live = table.live_mask(dep.edge_tbl)
-    cap = dep.e_nconn.shape[0]
-    return _edge_merge(cap, dep.e_cli_hi, dep.e_cli_lo, dep.e_cli_svc,
-                       dep.e_ser_hi, dep.e_ser_lo, dep.e_nconn,
-                       dep.e_bytes, live)
+    """Single-shard edge view as an EdgeSet: the edge slab's own columns
+    under its own key table. One shard has nothing to merge — the slab
+    already is the dense keyed table a merge would rebuild — so every
+    live edge is in the view and none can be dropped."""
+    return EdgeSet(
+        tbl=dep.edge_tbl,
+        cli_hi=dep.e_cli_hi, cli_lo=dep.e_cli_lo, cli_svc=dep.e_cli_svc,
+        ser_hi=dep.e_ser_hi, ser_lo=dep.e_ser_lo,
+        nconn=dep.e_nconn, byts=dep.e_bytes,
+        n_dropped=jnp.zeros((), jnp.int32))
 
 
 def edge_rollup_fn(mesh, out_capacity: int):

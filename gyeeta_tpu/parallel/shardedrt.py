@@ -679,16 +679,7 @@ class ShardedRuntime(HostIngest):
 
         if subsys in (fieldmaps.SUBSYS_ACTIVECONN,
                       fieldmaps.SUBSYS_CLIENTCONN):
-            snap = {
-                "e_live": np.asarray(table.live_mask(es.tbl)),
-                "e_cli_hi": np.asarray(es.cli_hi),
-                "e_cli_lo": np.asarray(es.cli_lo),
-                "e_ser_hi": np.asarray(es.ser_hi),
-                "e_ser_lo": np.asarray(es.ser_lo),
-                "e_nconn": np.asarray(es.nconn),
-                "e_bytes": np.asarray(es.byts),
-                "e_cli_svc": np.asarray(es.cli_svc),
-            }
+            snap = api.dep_edges_view(None, self, merged=es)
             if subsys == fieldmaps.SUBSYS_CLIENTCONN:
                 return api.clientconn_from_edges(
                     snap, self.names,
@@ -707,26 +698,10 @@ class ShardedRuntime(HostIngest):
                 "clustersize": np.asarray(sizes),
             }
             return cols, np.asarray(table.live_mask(ntbl))
-        live = np.asarray(table.live_mask(es.tbl))
-        cli_hi, cli_lo = np.asarray(es.cli_hi), np.asarray(es.cli_lo)
-        ser_hi, ser_lo = np.asarray(es.ser_hi), np.asarray(es.ser_lo)
-        cli_svc = np.asarray(es.cli_svc)
-        svc_names = api._names_of(self.names, wire.NAME_KIND_SVC,
-                                  cli_hi, cli_lo)
-        # task→svc callers resolve via the gathered task slabs (comm join)
-        task_names = self._gathered_task_names(cli_hi, cli_lo, state,
-                                               cache)
-        cols = {
-            "cliid": api._hex_id(cli_hi, cli_lo),
-            "cliname": np.where(cli_svc, svc_names, task_names),
-            "clisvc": cli_svc,
-            "serid": api._hex_id(ser_hi, ser_lo),
-            "sername": api._names_of(self.names, wire.NAME_KIND_SVC,
-                                     ser_hi, ser_lo),
-            "nconn": np.asarray(es.nconn),
-            "bytes": np.asarray(es.byts),
-        }
-        return cols, live
+        return api.dep_cols_from_edges(
+            api.dep_edges_view(None, self, merged=es), self.names,
+            lambda hi, lo: self._gathered_task_names(hi, lo, state,
+                                                     cache), obs=self)
 
     # -------------------------------------------------- heavy hitters
     def heavy_recover(self) -> dict:

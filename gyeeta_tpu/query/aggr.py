@@ -134,37 +134,122 @@ def aggregate_rows(rows: list, specs: list, groupby: tuple) -> list:
     return out
 
 
+# np.sum adds pairwise from this many addends on and left to right
+# below it (np.add.reduceat does neither: first + sum of the rest)
+_PAIRWISE_MIN = 8
+
+
+def _segments(keycols: list, n: int):
+    """Group ``n`` rows by their key columns → (order, starts, rank):
+    ``order`` sorts the rows group by group, each group's rows in their
+    given order; ``starts`` are the groups' first positions in it;
+    ``rank`` lists the groups by first occurrence, the order a dict of
+    key tuples would give. Numeric keys sort (one lexsort); anything
+    else (names, enums rendered as text) is grouped by that dict."""
+    if all(k.dtype.kind in "biuf" for k in keycols):
+        order = np.lexsort(tuple(reversed(keycols)))
+        new = np.zeros(n, bool)
+        new[:1] = True
+        for k in keycols:
+            ks = k[order]
+            new[1:] |= ks[1:] != ks[:-1]
+        starts = np.flatnonzero(new)
+        # the sort is stable: a group's first sorted row is its first row
+        return order, starts, np.argsort(order[starts], kind="stable")
+    seen: dict = {}
+    gid = np.fromiter(
+        (seen.setdefault(k, len(seen))
+         for k in zip(*[k.tolist() for k in keycols])), np.int64, n)
+    order = np.argsort(gid, kind="stable")
+    starts = np.searchsorted(gid[order], np.arange(len(seen)))
+    return order, starts, np.arange(len(seen))
+
+
+def _reduce(spec: AggrSpec, v, starts, ends) -> np.ndarray:
+    """One aggregation over every segment of ``v`` → float64 per
+    segment, bit-equal to :func:`_apply` on each segment."""
+    count = (ends - starts).astype(np.float64)
+    if spec.op == "count":
+        return count
+    if spec.op in ("sum", "avg"):
+        # np.sum's own order of additions: the k-th addend of every
+        # short segment in one pass, long segments one by one
+        out = v[starts]
+        for k in range(1, _PAIRWISE_MIN - 1):
+            at = np.flatnonzero((count > k) & (count < _PAIRWISE_MIN))
+            if not len(at):
+                break
+            out[at] += v[starts[at] + k]
+        for j in np.flatnonzero(count >= _PAIRWISE_MIN):
+            out[j] = np.sum(v[starts[j]:ends[j]])
+        return out if spec.op == "sum" else out / count
+    if spec.op == "min":
+        return np.minimum.reduceat(v, starts)
+    if spec.op == "max":
+        return np.maximum.reduceat(v, starts)
+    return np.array([_apply(spec, v[a:b]) for a, b in zip(starts, ends)],
+                    np.float64)
+
+
 def aggregate_columns(cols: dict, idx: np.ndarray, specs: list,
-                      groupby: tuple, fmap: dict) -> list:
-    """Columnar group-aggregate over selected row indices (live path)."""
+                      groupby: tuple, fmap: dict, sortcol=None,
+                      sortdesc: bool = True, maxrecs=None) -> tuple:
+    """Columnar group-aggregate over selected row indices (live path)
+    → (the first ``maxrecs`` group rows in ``sortcol`` order, the number
+    of groups). Groups come in first-occurrence order and the sort is
+    stable, as a dict of key tuples and ``list.sort`` would give them.
+    A lazy string column that names its key words (``LazyCols.keys_of``)
+    is grouped on those, and its label rendered for the groups returned
+    only (unless the sort itself is on that label)."""
+    from gyeeta_tpu.query.lazycols import rows_of
+
+    n = len(idx)
+    keys_of = getattr(cols, "keys_of", {})
     if groupby:
-        keycols = [np.asarray(cols[fmap[g].col])[idx] for g in groupby]
-        keys = list(zip(*[k.tolist() for k in keycols])) \
-            if keycols else [()] * len(idx)
+        keycols = [np.asarray(cols[c])[idx] for g in groupby
+                   for c in keys_of.get(fmap[g].col, (fmap[g].col,))]
+        order, starts, rank = _segments(keycols, n)
     else:
-        keys = [()] * len(idx)
-    groups = collections.defaultdict(list)
-    for pos, k in enumerate(keys):
-        groups[k].append(pos)
-    if not groups and not groupby:
-        # one zero row for a global aggregate over zero matches — the SQL
-        # path and aggregate_rows agree on this shape
-        groups[()] = []
-    out = []
-    for key, members in groups.items():
-        rec = {}
-        for g, kv in zip(groupby, key):
-            fd = fmap[g]
-            rec[g] = fd.to_json(kv) if fd.to_json else kv
-        sel = idx[np.asarray(members, np.int64)]
-        for s in specs:
-            if s.field == "*":
-                rec[s.alias] = float(len(sel))
-                continue
-            vals = np.asarray(cols[fmap[s.field].col])[sel]
-            rec[s.alias] = _apply(s, vals.astype(np.float64))
-        out.append(rec)
-    return out
+        # a global aggregate over zero matches still yields one (zero)
+        # row — the SQL path and aggregate_rows agree on this shape
+        order, starts = np.arange(n), np.zeros(1, np.int64)
+        rank = np.zeros(1, np.int64)
+    ends = np.append(starts[1:], n)[:len(starts)]
+    sel = idx[order]
+    vals = {}
+    for s in specs:
+        if s.field == "*":
+            vals[s.alias] = (ends - starts).astype(np.float64)[rank]
+        elif n == 0:
+            vals[s.alias] = np.zeros(len(rank))
+        else:
+            v = np.asarray(cols[fmap[s.field].col])[sel]
+            vals[s.alias] = _reduce(s, v.astype(np.float64), starts,
+                                    ends)[rank]
+    first = sel[starts[rank]] if n else np.zeros(0, np.int64)
+
+    def labels(g, rows):
+        fd = fmap[g]
+        got = rows_of(cols, [fd.col], rows)[fd.col].tolist()
+        return [fd.to_json(kv) for kv in got] if fd.to_json else got
+
+    pick = np.arange(len(rank))
+    if sortcol in vals:
+        key = vals[sortcol]
+        pick = np.argsort(-key if sortdesc else key, kind="stable")
+    elif sortcol:
+        lab = labels(sortcol, first)
+        pick = np.asarray(sorted(pick, key=lab.__getitem__,
+                                 reverse=sortdesc), np.int64)
+    pick = pick[:maxrecs]
+    out = [{} for _ in pick]
+    for g in groupby:
+        for rec, lab in zip(out, labels(g, first[pick])):
+            rec[g] = lab
+    for alias, v in vals.items():
+        for rec, x in zip(out, v[pick].tolist()):
+            rec[alias] = x
+    return out, len(rank)
 
 
 def sql_pushdown(specs: list, groupby: tuple, step: Optional[float],

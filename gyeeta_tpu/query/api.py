@@ -457,73 +457,167 @@ def cluster_columns(cfg: EngineCfg, st: AggState, names=None) -> dict:
     return cols, np.ones(1, bool)
 
 
+def _sorted_task_comms(key, comm, live):
+    """Live task-slab rows as (sorted keys, their comm ids)."""
+    k, c = key[live], comm[live]
+    order = np.argsort(k, kind="stable")
+    return k[order], c[order]
+
+
+def _comm_names(names, skey, scomm, task_hi, task_lo):
+    """Process-group ids → comm names over sorted task-slab arrays (one
+    binary search a row); an unknown group renders as its hex id."""
+    from gyeeta_tpu.ingest import wire
+
+    fallback = _hex_id(task_hi, task_lo)
+    if names is None or not len(skey):
+        return fallback
+    want = ((task_hi.astype(np.uint64) << np.uint64(32))
+            | task_lo.astype(np.uint64))
+    pos = np.minimum(np.searchsorted(skey, want), len(skey) - 1)
+    comm_ids = np.where(skey[pos] == want, scomm[pos], np.uint64(0))
+    resolved = names.resolve_array(wire.NAME_KIND_COMM, comm_ids)
+    return np.where(comm_ids != 0, resolved, fallback)
+
+
 def task_comm_names_from(names, key, comm, live, task_hi, task_lo):
     """Resolve process-group ids → comm names given task-slab arrays
     (key/comm as u64, live mask) — shared by the single-node provider and
     the sharded runtime's gathered slabs."""
-    from gyeeta_tpu.ingest import wire
-
-    comm_of = dict(zip(key[live].tolist(), comm[live].tolist()))
-    want = ((task_hi.astype(np.uint64) << np.uint64(32))
-            | task_lo.astype(np.uint64))
-    comm_ids = np.array([comm_of.get(int(t), 0) for t in want], np.uint64)
-    if names is None:
-        return _hex_id(task_hi, task_lo)
-    resolved = names.resolve_array(wire.NAME_KIND_COMM, comm_ids)
-    fallback = _hex_id(task_hi, task_lo)
-    return np.where(comm_ids != 0, resolved, fallback)
+    return _comm_names(names, *_sorted_task_comms(key, comm, live),
+                       task_hi, task_lo)
 
 
 def _task_slab_arrays(st: AggState):
-    key = (np.asarray(st.task_tbl.key_hi).astype(np.uint64)
-           << np.uint64(32)) | np.asarray(st.task_tbl.key_lo)
+    hi, lo = np.asarray(st.task_tbl.key_hi), np.asarray(st.task_tbl.key_lo)
+    key = (hi.astype(np.uint64) << np.uint64(32)) | lo
     comm = (np.asarray(st.task_comm_hi).astype(np.uint64)
             << np.uint64(32)) | np.asarray(st.task_comm_lo)
-    live = np.asarray(
-        (st.task_tbl.key_hi != np.uint32(0xFFFFFFFF))
-        | (st.task_tbl.key_lo != np.uint32(0xFFFFFFFF)))
+    live = (hi != np.uint32(0xFFFFFFFF)) | (lo != np.uint32(0xFFFFFFFF))
     return key, comm, live
 
 
-def _task_comm_names(st: AggState, names, task_hi, task_lo):
-    """Resolve process-group ids → comm names via the live task slab (the
-    reference resolves DEPENDS entries through MAGGR_TASK)."""
-    key, comm, live = _task_slab_arrays(st)
-    return task_comm_names_from(names, key, comm, live, task_hi, task_lo)
+def dep_edges_view(dep, obs=None, merged=None) -> dict:
+    """One build of the dependency views' numeric edge columns on the
+    host: one shard's device program over its edge slab
+    (``readback.dep_edges_snapshot``) — or, for the mesh, its ``merged``
+    EdgeSet — and the readback, every leaf copied out together. ``obs``
+    (a runtime: its ``spans`` and ``stats``) times it as span
+    ``dep_view`` and counts it (``dep_view_builds``, ``dep_view_edges``,
+    ``dep_merge_dropped``: what a merge left out, 0 for one shard, which
+    merges nothing). Callers memoize the columns they derive per state
+    version, so a snapshot builds once whatever its dashboards ask."""
+    import contextlib
+
+    import jax
+
+    if merged is None and dep is None:
+        raise ValueError("the dependency views need a dependency graph "
+                         "(runtime not configured with one)")
+    with obs.spans.span("dep_view") if obs is not None \
+            else contextlib.nullcontext():
+        snap = jax.device_get(
+            readback.dep_edges_snapshot(dep) if merged is None
+            else readback.edge_cols(merged))
+    dropped = snap.pop("e_dropped")
+    if obs is not None:
+        obs.stats.bump("dep_view_builds")
+        obs.stats.gauge("dep_view_edges", float(snap["e_live"].sum()))
+        obs.stats.gauge("dep_merge_dropped", float(dropped))
+    return snap
+
+
+def dep_cols_from_edges(snap: dict, names=None, task_names_fn=None,
+                        obs=None):
+    """svcdependency columns over a dep-edge column snapshot (one
+    shard's slab or the mesh's merged set): a
+    :class:`~gyeeta_tpu.query.lazycols.LazyCols` over the LIVE edges, in
+    slab order. The numeric columns (and the four key words) are eager;
+    the hex ids and the names are rendered for the rows a query returns,
+    or at the live width when a filter or sort names one. A ``groupby``
+    on an id groups on its key words (``keys_of``).
+
+    ``task_names_fn(hi, lo) -> names`` resolves task-group callers
+    (single-node: the local task slab; sharded: gathered slabs); it is
+    asked once per projection, for the task rows only."""
+    from gyeeta_tpu.ingest import wire
+    from gyeeta_tpu.query.lazycols import LazyCols
+
+    live = np.nonzero(snap["e_live"])[0]
+    cli_hi, cli_lo = snap["e_cli_hi"][live], snap["e_cli_lo"][live]
+    ser_hi, ser_lo = snap["e_ser_hi"][live], snap["e_ser_lo"][live]
+    cli_svc = snap["e_cli_svc"][live]
+    eager = {
+        "clisvc": cli_svc,
+        "nconn": snap["e_nconn"][live],
+        "bytes": snap["e_bytes"][live],
+        "cli_hi": cli_hi, "cli_lo": cli_lo,
+        "ser_hi": ser_hi, "ser_lo": ser_lo,
+    }
+
+    def ids(idx=slice(None)):
+        return {"cliid": _hex_id(cli_hi[idx], cli_lo[idx]),
+                "serid": _hex_id(ser_hi[idx], ser_lo[idx])}
+
+    def cli_names(idx=slice(None)):
+        # caller name: listener name for svc→svc edges, comm (via the
+        # task slab) for task→svc edges
+        hi, lo, is_svc = cli_hi[idx], cli_lo[idx], cli_svc[idx]
+        out = np.empty(len(hi), object)
+        out[is_svc] = _names_of(names, wire.NAME_KIND_SVC,
+                                hi[is_svc], lo[is_svc])
+        task = ~is_svc
+        if task.any():
+            out[task] = (task_names_fn(hi[task], lo[task])
+                         if task_names_fn is not None
+                         else _hex_id(hi[task], lo[task]))
+        return out
+
+    def name_cols(idx=slice(None)):
+        return {"cliname": cli_names(idx),
+                "sername": _names_of(names, wire.NAME_KIND_SVC,
+                                     ser_hi[idx], ser_lo[idx])}
+
+    group_of = {"cliid": "id", "serid": "id",
+                "cliname": "name", "sername": "name"}
+    loaders = {"id": ids, "name": name_cols}
+    cols = LazyCols(
+        eager, group_of, loaders, loaders,
+        keys_of={"cliid": ("cli_hi", "cli_lo"),
+                 "serid": ("ser_hi", "ser_lo")},
+        on_rows=None if obs is None else (
+            lambda n: obs.stats.bump("dep_rows_materialised", n)))
+    return cols, np.ones(len(live), bool)
+
+
+class _TaskComms:
+    """Process-group id → comm name over one state's task slab, read
+    back and indexed at the first ask (a view's row loaders share it)."""
+
+    def __init__(self, st: AggState, names):
+        self._st, self._names = st, names
+        self._slab = None
+
+    def __call__(self, task_hi, task_lo):
+        if self._names is None:
+            return _hex_id(task_hi, task_lo)
+        slab = self._slab
+        if slab is None:      # workers may race here: both get the same
+            slab = self._slab = _sorted_task_comms(
+                *_task_slab_arrays(self._st))
+        return _comm_names(self._names, *slab, task_hi, task_lo)
 
 
 def dep_columns(cfg: EngineCfg, st: AggState, names=None,
-                dep=None) -> dict:
-    """svcdependency subsystem: one row per (caller → service) edge."""
-    from gyeeta_tpu.ingest import wire
-
-    if dep is None:
-        raise ValueError("svcdependency needs a dependency graph "
-                         "(runtime not configured with one)")
-    snap = {k: np.asarray(v)
-            for k, v in readback.dep_edges_snapshot(dep).items()}
-    cli_svc = snap["e_cli_svc"]
-    # caller name: listener name for svc→svc edges, comm (via the task
-    # slab) for task→svc edges
-    svc_names = _names_of(names, wire.NAME_KIND_SVC,
-                          snap["e_cli_hi"], snap["e_cli_lo"])
-    task_names = _task_comm_names(st, names, snap["e_cli_hi"],
-                                  snap["e_cli_lo"])
-    cols = {
-        "cliid": _hex_id(snap["e_cli_hi"], snap["e_cli_lo"]),
-        "cliname": np.where(cli_svc, svc_names, task_names),
-        "clisvc": cli_svc,
-        "serid": _hex_id(snap["e_ser_hi"], snap["e_ser_lo"]),
-        "sername": _names_of(names, wire.NAME_KIND_SVC,
-                             snap["e_ser_hi"], snap["e_ser_lo"]),
-        "nconn": snap["e_nconn"],
-        "bytes": snap["e_bytes"],
-    }
-    return cols, snap["e_live"]
+                dep=None, obs=None):
+    """svcdependency subsystem: one row per (caller → service) edge,
+    read straight from the shard's edge slab."""
+    return dep_cols_from_edges(dep_edges_view(dep, obs), names,
+                               _TaskComms(st, names), obs)
 
 
 def mesh_columns(cfg: EngineCfg, st: AggState, names=None,
-                 dep=None) -> dict:
+                 dep=None, obs=None) -> dict:
     """svcmesh subsystem: one row per service in the dependency mesh,
     labelled with its coalesced cluster (ref svc mesh clusters,
     ``server/gy_shconnhdlr.h:1301``)."""
@@ -596,14 +690,10 @@ def activeconn_from_edges(snap: dict, names=None):
 
 
 def activeconn_columns(cfg: EngineCfg, st: AggState, names=None,
-                       dep=None) -> dict:
+                       dep=None, obs=None) -> dict:
     """activeconn subsystem: per-service caller rollup of the dep edges
     (ref activeconn/clientconn views over DEPENDS maps)."""
-    if dep is None:
-        raise ValueError("activeconn needs a dependency graph")
-    snap = {k: np.asarray(v)
-            for k, v in readback.dep_edges_snapshot(dep).items()}
-    return activeconn_from_edges(snap, names)
+    return activeconn_from_edges(dep_edges_view(dep, obs), names)
 
 
 def svcinfo_columns(cfg: EngineCfg, st: AggState, names=None,
@@ -640,13 +730,9 @@ def clientconn_from_edges(snap: dict, names=None, task_names_fn=None):
 
 
 def clientconn_columns(cfg: EngineCfg, st: AggState, names=None,
-                       dep=None) -> dict:
-    if dep is None:
-        raise ValueError("clientconn needs a dependency graph")
-    snap = {k: np.asarray(v)
-            for k, v in readback.dep_edges_snapshot(dep).items()}
-    return clientconn_from_edges(
-        snap, names, lambda hi, lo: _task_comm_names(st, names, hi, lo))
+                       dep=None, obs=None) -> dict:
+    return clientconn_from_edges(dep_edges_view(dep, obs), names,
+                                 _TaskComms(st, names))
 
 
 def svcsumm_from_svc(cols, live, names=None):
@@ -886,19 +972,21 @@ _TOP_PRESETS = {
 
 
 def columns_for(cfg: EngineCfg, st: AggState, subsys: str, names=None,
-                dep=None, svcreg=None, aux=None):
+                dep=None, svcreg=None, aux=None, obs=None):
     """Resolve a subsystem to its (cols, base_mask) column source —
     the ONE dispatch over aux providers ≻ host-side registries ≻
     dep-graph views ≻ device-slab readbacks. Shared by query execution
     and realtime alertdef evaluation so a subsystem added to one is
-    automatically visible to the other."""
+    automatically visible to the other. ``obs`` (a runtime) lets the
+    dep-graph views time and count their builds."""
     if aux is not None and subsys in aux:
         return aux[subsys]()
     if subsys in _SVCREG_COLUMNS_OF:
         return _SVCREG_COLUMNS_OF[subsys](cfg, st, names=names,
                                           svcreg=svcreg)
     if subsys in _DEP_COLUMNS_OF:
-        return _DEP_COLUMNS_OF[subsys](cfg, st, names=names, dep=dep)
+        return _DEP_COLUMNS_OF[subsys](cfg, st, names=names, dep=dep,
+                                       obs=obs)
     return _COLUMNS_OF[subsys](cfg, st, names=names)
 
 
@@ -1017,16 +1105,15 @@ def execute(cfg: EngineCfg, st: AggState, opts: QueryOptions,
         specs = [A.parse_aggr(s, opts.subsys) for s in opts.aggr]
         gb = A.parse_groupby(opts.groupby, opts.subsys)
         fmap = fieldmaps.field_map(opts.subsys)
-        recs = A.aggregate_columns(cols, idx, specs, gb, fmap)
-        if opts.sortcol:
-            if opts.sortcol not in (tuple(s.alias for s in specs) + gb):
-                raise ValueError(
-                    f"sortcol {opts.sortcol!r} must be a groupby field "
-                    f"or aggregation alias")
-            recs.sort(key=lambda r: r[opts.sortcol],
-                      reverse=opts.sortdesc)
-        return {"recs": recs[: opts.maxrecs], "nrecs":
-                min(len(recs), opts.maxrecs), "ngroups": len(recs)}
+        if opts.sortcol and opts.sortcol not in (
+                tuple(s.alias for s in specs) + gb):
+            raise ValueError(
+                f"sortcol {opts.sortcol!r} must be a groupby field "
+                f"or aggregation alias")
+        recs, ngroups = A.aggregate_columns(
+            cols, idx, specs, gb, fmap, sortcol=opts.sortcol,
+            sortdesc=opts.sortdesc, maxrecs=opts.maxrecs)
+        return {"recs": recs, "nrecs": len(recs), "ngroups": ngroups}
 
     if opts.sortcol:
         fmap = fieldmaps.field_map(opts.subsys)

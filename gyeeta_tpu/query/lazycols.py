@@ -36,15 +36,26 @@ class LazyCols(dict):
     ``load``       — group key → ``fn() -> {col: array}`` (full width).
     ``load_rows``  — group key → ``fn(idx) -> {col: array}`` over just
                      the given row indices (optional per group).
+    ``keys_of``    — lazy column → the eager numeric columns that
+                     identify its value (a hex id's two key words): a
+                     ``groupby`` on it groups on those and renders the
+                     label for the groups returned (``aggr.py``).
+    ``on_rows``    — ``fn(n)`` told how many rows a lazy load rendered:
+                     the result rows of one projection (once, however
+                     many groups it touched), or a group's full width.
     """
 
     def __init__(self, eager: dict, group_of: dict,
-                 load: dict, load_rows: Optional[dict] = None):
+                 load: dict, load_rows: Optional[dict] = None,
+                 keys_of: Optional[dict] = None,
+                 on_rows: Optional[Callable] = None):
         super().__init__(eager)
         self._group_of = group_of
         self._load = load
         self._load_rows = load_rows or {}
         self._loaded: set = set()
+        self.keys_of = keys_of or {}
+        self._on_rows = on_rows
 
     # -------------------------------------------------- dict protocol
     def __missing__(self, key):
@@ -60,9 +71,13 @@ class LazyCols(dict):
     def _materialize(self, g: str) -> None:
         if g in self._loaded:
             return
+        width = 0
         for c, v in self._load[g]().items():
             dict.__setitem__(self, c, v)
+            width = len(v)
         self._loaded.add(g)
+        if self._on_rows is not None:
+            self._on_rows(width)
 
     def full(self) -> dict:
         """Materialize every group → plain dict (full-width joins)."""
@@ -81,6 +96,7 @@ class LazyCols(dict):
                 out[c] = np.asarray(dict.__getitem__(self, c))[idx]
             else:
                 want_by_group.setdefault(self._group_of[c], []).append(c)
+        by_row = False
         for g, cs in want_by_group.items():
             lr = self._load_rows.get(g)
             if lr is None or len(idx) > _ROWS_FULL_CUTOFF:
@@ -89,8 +105,11 @@ class LazyCols(dict):
                     out[c] = np.asarray(dict.__getitem__(self, c))[idx]
             else:
                 got = lr(idx)
+                by_row = True
                 for c in cs:
                     out[c] = np.asarray(got[c])
+        if by_row and self._on_rows is not None:
+            self._on_rows(len(idx))
         return out
 
 

@@ -245,20 +245,26 @@ def task_snapshot(cfg: EngineCfg, st: AggState):
     }
 
 
-@jax.jit
-def dep_edges_snapshot(dep):
-    """Dependency-edge columns (svcdependency): one device readback, no
-    clustering work (that is :func:`dep_mesh_snapshot`)."""
-    from gyeeta_tpu.parallel import depgraph as dg
-
-    es = dg.edges_local(dep)
+def edge_cols(es) -> dict:
+    """An ``EdgeSet`` as the dependency views' device-side columns."""
     return {
         "e_live": table.live_mask(es.tbl),
         "e_cli_hi": es.cli_hi, "e_cli_lo": es.cli_lo,
         "e_cli_svc": es.cli_svc,
         "e_ser_hi": es.ser_hi, "e_ser_lo": es.ser_lo,
         "e_nconn": es.nconn, "e_bytes": es.byts,
+        "e_dropped": es.n_dropped,
     }
+
+
+@jax.jit
+def dep_edges_snapshot(dep):
+    """One shard's dependency-edge columns (svcdependency), straight
+    from its edge slab: one device program, no clustering work (that is
+    :func:`dep_mesh_snapshot`) and no merge (that is the mesh's)."""
+    from gyeeta_tpu.parallel import depgraph as dg
+
+    return edge_cols(dg.edges_local(dep))
 
 
 @partial(jax.jit, static_argnums=(1,))

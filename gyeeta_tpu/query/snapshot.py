@@ -33,6 +33,7 @@ replace the current one (``on_mutation`` / re-publish).
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 
 import numpy as np
@@ -178,10 +179,19 @@ class EngineSnapshot:
         """A result-cache miss: column compute, device→host readback
         and row assembly (the first reader of a fresh snapshot also
         waits here for the device to finish the publish copy)."""
-        with self.rt.spans.span("query_render", annotate=True):
-            out = api.execute(self.rt.cfg, None,
-                              api.QueryOptions.from_json(req),
-                              names=self.rt.names, columns_fn=self.columns)
+        spans = self.rt.spans
+        opts = api.QueryOptions.from_json(req)
+        dep = opts.subsys == "svcdependency"
+        with spans.span("query_render", annotate=True):
+            if dep:
+                # the snapshot's one view build (span ``dep_view``)
+                # falls to whoever asks first, outside ``dep_render``
+                self.columns(opts.subsys)
+            with spans.span("dep_render") if dep \
+                    else contextlib.nullcontext():
+                out = api.execute(self.rt.cfg, None, opts,
+                                  names=self.rt.names,
+                                  columns_fn=self.columns)
         out["snaptick"] = self.tick
         return out
 
@@ -253,7 +263,7 @@ class EngineSnapshot:
         try:
             out = api.columns_for(rt.cfg, self.state, subsys,
                                   names=rt.names, dep=self.dep,
-                                  svcreg=rt.svcreg)
+                                  svcreg=rt.svcreg, obs=rt)
         except KeyError:
             # a subsystem with fields but no single-node provider
             # (e.g. shardlist) fails like the live path: clean error

@@ -953,7 +953,7 @@ class Runtime(HostIngest):
                 return api.columns_for(self.cfg, self.state, subsys,
                                        names=self.names, dep=self.dep,
                                        svcreg=self.svcreg,
-                                       aux=self._aux)
+                                       aux=self._aux, obs=self)
             except KeyError:
                 # a subsystem with fields but no single-node provider
                 # (e.g. shardlist) must fail like execute() without a

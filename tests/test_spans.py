@@ -74,6 +74,10 @@ SPANS = {
     "query": (None,),
     "query_prewarm": (None,),
     "query_render": ("query", "query_prewarm"),
+    # the service map: one view build a snapshot, by whoever asks first;
+    # rows → answer for each svcdependency render
+    "dep_view": ("query_render",),
+    "dep_render": ("query_render",),
     "query_reply": (None,),
     "query_encode": (None,),
 }
@@ -84,14 +88,19 @@ LEAVES = {"edge_rx", "deframe", "slab_wait", "slab_decode", "td_flush",
           "tick.roll", "tick.history", "tick.health", "tick.close",
           "tick_push", "query", "query_render", "query_encode"}
 QUERY_SPANS = ("query_queue", "query", "query_prewarm", "query_render",
-               "query_reply", "query_encode")
+               "dep_view", "dep_render", "query_reply", "query_encode")
+# what the dependency view counts beside its two spans (gauges and
+# counters of ``selfstats``)
+DEP_COUNTERS = ("dep_view_builds", "dep_view_edges", "dep_merge_dropped",
+                "dep_rows_materialised")
 NEW_METRICS = (
     "slab_wait_ms", "slab_decode_ms_per_mev", "h2d_ms", "loop_busy_share",
     "slab_fold_device_ms", "section_fold_device_ms", "section_fold_share",
     "tick_visible_ms", "tick_flush_ms", "tick_drain_ms", "tick_roll_ms",
     "query_queue_ms", "query_reply_ms", "query_render_ms",
     "query_cache_hit_share", "h2d_arrays_per_dispatch", "edge_ms_per_mev",
-    "feeds_per_slab")
+    "feeds_per_slab", "dep_view_ms", "dep_render_ms", "dep_builds_per_tick",
+    "dep_rows_per_query")
 VARIANTS = (
     ("connresp",), ("listener",), ("host",), ("listener", "host"),
     ("listener", "connresp"), ("host", "connresp"),
@@ -129,6 +138,8 @@ async def _phase(rt, qc, sim, agent) -> None:
         await asyncio.sleep(0.005)
     for _ in range(2):
         await qc.query({"subsys": "svcstate", "maxrecs": 4})
+        await qc.query({"subsys": "svcdependency", "maxrecs": 4,
+                        "sortcol": "nconn"})
     t0 = time.monotonic()
     while rt.stats.gauges.get("tick", 0) < tick + 2:
         assert time.monotonic() - t0 < 120.0, "the tick loop stopped"
@@ -137,7 +148,10 @@ async def _phase(rt, qc, sim, agent) -> None:
 
 async def _toy_run(trace_dir: str) -> dict:
     rt = Runtime(CFG)
-    srv = GytServer(rt, tick_interval=0.2)
+    # the first feeds compile on the loop's own thread: on a loaded
+    # machine that outlasts the default 30 s idle deadline of the query
+    # conn opened before them
+    srv = GytServer(rt, tick_interval=0.2, idle_timeout=600.0)
     host, port = await srv.start()
     sim = ParthaSim(n_hosts=8, n_svcs=2, seed=3)
     qc = QueryClient()
@@ -203,8 +217,9 @@ def test_query_spans_share_req(toy):
     # is a request of the server's own: nothing but the render under it
     ahead = [names for names in by_req.values()
              if "query_prewarm" in names]
-    assert ahead and all(names == {"query_prewarm", "query_render"}
-                         for names in ahead), by_req
+    assert ahead and all(
+        names - {"dep_view", "dep_render"}
+        == {"query_prewarm", "query_render"} for names in ahead), by_req
     asked = [names for names in by_req.values()
              if "query_prewarm" not in names]
     # every request asked waits, is encoded and replied to; a snapshot
@@ -213,8 +228,27 @@ def test_query_spans_share_req(toy):
     assert all(names >= {"query_queue", "query_encode", "query_reply"}
                for names in asked), by_req
     ran = [names for names in asked if "query" in names]
-    assert len(ran) == 4
-    assert 1 <= sum("query_render" in names for names in ran) < 4
+    assert len(ran) == 8
+    assert 2 <= sum("query_render" in names for names in ran) < 8
+    # the service map renders under ``dep_render``, never without it
+    assert all(("dep_render" in names) == ("query_render" in names)
+               for names in by_req.values() if "dep_view" in names)
+
+
+def test_dep_view_counters(toy):
+    """The four counters beside ``dep_view`` / ``dep_render``: one build
+    a snapshot asked, its live edges, nothing a one-shard read could
+    leave out, strings for the result rows only."""
+    c0, c1 = toy["c0"], toy["c1"]
+    assert all(k in c1 for k in DEP_COUNTERS), DEP_COUNTERS
+    builds = c1["dep_view_builds"] - c0["dep_view_builds"]
+    views = c1["_timings"]["dep_view"][0] - c0["_timings"]["dep_view"][0]
+    renders = (c1["_timings"]["dep_render"][0]
+               - c0["_timings"]["dep_render"][0])
+    assert builds == views >= 1 and renders >= builds
+    assert c1["dep_view_edges"] > 0 and c1["dep_merge_dropped"] == 0
+    rows = c1["dep_rows_materialised"] - c0["dep_rows_materialised"]
+    assert 0 < rows <= 4 * renders          # ``maxrecs`` 4
 
 
 def test_tick_children_in_program_order(toy):
