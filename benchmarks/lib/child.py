@@ -5,11 +5,13 @@
 ``gyeeta_tpu.cli.main(["serve", ...])`` in the main thread. Only the
 process that holds the chip can trace it, so a side thread reads commands
 from stdin: ``trace <delay_s> <seconds>`` brackets that interval of the
-measured window with ``jax.profiler`` (Python tracer off) and leaves
+measured window with ``jax.profiler`` (Python tracer off), marks the
+interval inside the trace with a ``bench_window`` host event, and leaves
 ``trace_done.json`` (monotonic start and stop) in DIR when the trace is
-written. When stdin closes the parent is gone, and the child ends itself. ``--bench-fault``
-plants one fault of ``lib/faults.py`` under the timed path; only the tests
-under ``benchmarks/tests`` pass it.
+written: the signal that the trace is done. When stdin closes the parent
+is gone, and the child ends itself. ``--bench-fault`` plants one fault of
+``lib/faults.py`` under the timed path; only the tests under
+``benchmarks/tests`` pass it.
 """
 
 from __future__ import annotations
@@ -44,13 +46,18 @@ def _stdin_thread(trace_dir) -> None:
 def _trace(trace_dir: str, delay_s: float, seconds: float) -> None:
     time.sleep(delay_s)
     import jax
+    from lib.trace_reduce import WINDOW_MARKER
     # the Python tracer records every call of the serving loop: it slowed
     # the flood tenfold and crowded the device events out of the trace
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     t0 = time.monotonic()
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    time.sleep(seconds)
+    # the window on the trace's own clock: lib/trace_reduce.py clips every
+    # device event to this event's interval (the profiler keeps recording
+    # past t1 while stop_trace collects)
+    with jax.profiler.TraceAnnotation(WINDOW_MARKER):
+        time.sleep(seconds)
     t1 = time.monotonic()
     jax.profiler.stop_trace()
     tmp = os.path.join(trace_dir, "trace_done.json.tmp")

@@ -1,4 +1,6 @@
-"""1 - (union of device-op intervals) / traced interval. Layer: device."""
+"""1 - (union of device-op intervals) / traced window, both over the
+``bench_window`` marker's interval on the trace's clock (lib/trace_reduce.py
+clips the ops to it). Layer: device."""
 
 
 def read(ctx):

@@ -687,7 +687,16 @@ def reduce_trace(trace_dir: str) -> dict:
     out = p.stdout.strip().splitlines()[-1]
     with open(os.path.join(trace_dir, "reduced.json"), "w") as f:
         f.write(out)
-    return json.loads(out)
+    tr = json.loads(out)
+    if "busy_s" in tr:
+        log(f"trace: window from the {tr['window_from']}, "
+            f"{tr['window_s']:.6f}s, busy {tr['busy_s']:.6f}s; device ops "
+            f"outside it {tr['busy_outside_s'][0]:.6f}s before, "
+            f"{tr['busy_outside_s'][1]:.6f}s after")
+        if tr["window_from"] != "marker":
+            log("WARNING: the trace holds no bench_window marker of "
+                "lib/child.py: its window is the device ops' own span")
+    return tr
 
 
 class MetricCtx:
@@ -731,13 +740,21 @@ class MetricCtx:
 
     def modules(self, prefix: str):
         """→ (executions, device seconds) of the traced programs whose
-        name starts with ``prefix``; None where the trace has none."""
+        name starts with ``prefix`` and that start inside the traced
+        window, each with its whole duration; None where the trace has
+        none."""
         if not self.trace or not self.trace.get("modules"):
             return None
         rows = [m for m in self.trace["modules"] if m[0].startswith(prefix)]
         if not rows:
             return None
         return sum(m[1] for m in rows), sum(m[2] for m in rows)
+
+    def module_window_s(self, prefix: str) -> float:
+        """Device seconds inside the traced window of the programs whose
+        name starts with ``prefix``: what a share of the window counts."""
+        inside = (self.trace or {}).get("module_window_s", {})
+        return sum(s for k, s in inside.items() if k.startswith(prefix))
 
 
 def read_metric(name: str, ctx: MetricCtx):
