@@ -468,6 +468,7 @@ def _phase_preagg() -> dict:
     # ---- fleet-view accuracy (state-level: the strongest form)
     sA, sB = rtA.state, rtB.state
     import jax.numpy as jnp
+    from gyeeta_tpu.sketch import hyperloglog as hll, windows
     from gyeeta_tpu.engine import table as T
     keys = glob_ids
     def rows_of(rt):
@@ -481,14 +482,15 @@ def _phase_preagg() -> dict:
     hll_equal = bool(
         np.array_equal(np.asarray(sA.glob_hll.regs),
                        np.asarray(sB.glob_hll.regs))
-        and np.array_equal(np.asarray(sA.svc_hll.regs)[ra],
-                           np.asarray(sB.svc_hll.regs)[rb]))
+        and np.array_equal(
+            np.asarray(hll.rows(sA.svc_hll, rtA.cfg.hll_p_svc).regs)[ra],
+            np.asarray(hll.rows(sB.svc_hll, rtB.cfg.hll_p_svc).regs)[rb]))
     # loghist: exact per-svc totals; samples ON a bucket boundary may
     # round into the neighbor bucket (host-numpy vs XLA 1-ulp
     # transcendental differences, ~1e-5 of samples, within the spec's
     # stated quantile error) — counted as flips, gated at 1e-4
-    ha_h = np.asarray(sA.resp_win.cur)[ra].astype(np.float64)
-    hb_h = np.asarray(sB.resp_win.cur)[rb].astype(np.float64)
+    ha_h = np.asarray(windows.cur_rows(sA.resp_win))[ra].astype(np.float64)
+    hb_h = np.asarray(windows.cur_rows(sB.resp_win))[rb].astype(np.float64)
     hist_totals_equal = bool(np.array_equal(ha_h.sum(axis=1),
                                             hb_h.sum(axis=1)))
     hist_flips = float(np.abs(ha_h - hb_h).sum()) / 2

@@ -158,10 +158,11 @@ class EngineCfg(NamedTuple):
 class AggState(NamedTuple):
     tbl: table.Table                  # service key slab (glob_id → row)
     resp_win: windows.MultiWindow     # (S, B) resp-time loghist, windowed
+    #                                   (its ``cur`` stored flat, S·B)
     ctr_win: windows.MultiWindow      # (S, NCTR) conn counters, windowed
-    svc_hll: hll.HLL                  # (S, m) distinct client endpoints
-    svc_td: tdigest.TDigest           # (S, C) per-svc resp digest
-    td_stage: jnp.ndarray             # (S, cap) staged raw samples
+    svc_hll: hll.HLL                  # (S·m,) distinct client endpoints
+    svc_td: tdigest.TDigest           # (S·C,) per-svc resp digest
+    td_stage: jnp.ndarray             # (S·cap,) staged raw samples
     td_stage_n: jnp.ndarray           # (S,) int32 staged fill counts
     svc_stats: jnp.ndarray            # (S, NSTAT) last listener-state gauges
     qps_hist: jnp.ndarray             # (S, Bq) learned QPS baseline hist
@@ -222,16 +223,33 @@ class AggState(NamedTuple):
     #                                   overflowed it (table.lookup_counted)
 
 
+def flat_widths(cfg: EngineCfg) -> dict:
+    """Row width W of each state leaf stored flat as (S·W,), by leaf
+    path: the per-service arrays that the slab fold (the HLL registers,
+    the resp histogram's current slab, the digest stage) and the digest
+    flush (the centroids) scatter into by row. Each is scattered at
+    ``row * W + col`` and never changes its tiling on the chip
+    (``sketch/flat.py``); readers take the (S, W) view."""
+    return {"resp_win.cur": cfg.resp_spec.nbuckets,
+            "svc_hll.regs": 1 << cfg.hll_p_svc,
+            "svc_td.means": cfg.td_capacity,
+            "svc_td.weights": cfg.td_capacity,
+            "td_stage": cfg.td_stage_cap}
+
+
 def init(cfg: EngineCfg) -> AggState:
     S = cfg.svc_capacity
     B = cfg.resp_spec.nbuckets
+    W = flat_widths(cfg)
+    resp_win = windows.init((S, B), cfg.levels)
     return AggState(
         tbl=table.init(S),
-        resp_win=windows.init((S, B), cfg.levels),
+        resp_win=resp_win._replace(
+            cur=jnp.zeros((S * W["resp_win.cur"],), jnp.float32)),
         ctr_win=windows.init((S, NCTR), cfg.levels),
         svc_hll=hll.init(p=cfg.hll_p_svc, entities=(S,)),
         svc_td=tdigest.init(capacity=cfg.td_capacity, entities=(S,)),
-        td_stage=jnp.zeros((S, cfg.td_stage_cap), jnp.float32),
+        td_stage=jnp.zeros((S * W["td_stage"],), jnp.float32),
         td_stage_n=jnp.zeros((S,), jnp.int32),
         svc_stats=jnp.zeros((S, decode.NSTAT), jnp.float32),
         qps_hist=jnp.zeros((S, cfg.qps_spec.nbuckets), jnp.float32),

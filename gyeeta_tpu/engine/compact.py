@@ -15,13 +15,21 @@ import jax
 import jax.numpy as jnp
 
 from gyeeta_tpu.engine import table
-from gyeeta_tpu.engine.aggstate import AggState, EngineCfg
+from gyeeta_tpu.engine.aggstate import AggState, EngineCfg, flat_widths
+from gyeeta_tpu.sketch import flat
+
+# the columns whose leaves are stored flat, (S·W,), by leaf path
+_FLAT = {"resp_cur": "resp_win.cur", "svc_hll": "svc_hll.regs",
+         "td_means": "svc_td.means", "td_weights": "svc_td.weights",
+         "td_stage": "td_stage"}
 
 
-def _rows_leading(st: AggState) -> dict:
+def _rows_leading(cfg: EngineCfg, st: AggState) -> dict:
     """Collect row-indexed arrays, moving the row axis to the front.
 
-    Window rings are (nslots, S, ...) — moveaxis to (S, nslots, ...)."""
+    Window rings are (nslots, S, ...) — moveaxis to (S, nslots, ...);
+    the leaves stored flat (``aggstate.flat_widths``) give their (S, W)
+    view."""
     cols = {
         "resp_cur": st.resp_win.cur,
         "resp_alltime": st.resp_win.alltime,
@@ -50,14 +58,19 @@ def _rows_leading(st: AggState) -> dict:
                                         st.ctr_win.totals)):
         cols[f"ctr_ring{i}"] = jnp.moveaxis(ring, 0, 1)
         cols[f"ctr_tot{i}"] = tot
+    W = flat_widths(cfg)
+    for k, path in _FLAT.items():
+        cols[k] = flat.rows(cols[k], W[path])
     return cols
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
 def compact_state(cfg: EngineCfg, st: AggState) -> AggState:
     """Rebuild the slab without tombstones; all per-row state follows."""
-    cols = _rows_leading(st)
+    cols = _rows_leading(cfg, st)
     new_tbl, new_cols = table.compact(st.tbl, cols)
+    for k in _FLAT:
+        new_cols[k] = new_cols[k].reshape(-1)
     live = table.live_mask(new_tbl)
 
     # non-additive resets for rows that are now empty

@@ -85,8 +85,8 @@ def ingest_conn(cfg: EngineCfg, st: AggState, cb) -> AggState:
             lambda col: col.at[lanes].set(cb.host_id, mode="drop"),
             lambda col: col, st.svc_host)
     with scope("conn.svc_hll"):
-        svc_hll = hll.update_entities(st.svc_hll, rowz, cb.cli_hi,
-                                      cb.cli_lo, valid=ok)
+        svc_hll = hll.update_entities(st.svc_hll, cfg.hll_p_svc, rowz,
+                                      cb.cli_hi, cb.cli_lo, valid=ok)
     with scope("conn.glob_hll"):
         glob_hll = hll.update(st.glob_hll, cb.flow_hi, cb.flow_lo,
                               valid=valid)
@@ -482,21 +482,24 @@ def ingest_delta(cfg: EngineCfg, st: AggState, dep, db, tick):
     n_conn_add = jnp.sum(jnp.where(ok_c, db.ctr_vals[:, 4], 0.0))
     n_resp_add = jnp.sum(jnp.where(ok_c, db.ctr_vals[:, 5], 0.0))
 
-    # ---- per-svc resp loghist bucket counts (exact scatter-add)
+    # ---- per-svc resp loghist bucket counts (exact scatter-add at the
+    # flat index; a bucket outside the row folds nothing, as before)
+    B = cfg.resp_spec.nbuckets
     rh = table.lookup(tbl, db.hist_hi, db.hist_lo, db.hist_valid)
-    ok_h = db.hist_valid & (rh >= 0)
+    ok_h = db.hist_valid & (rh >= 0) & (db.hist_bucket < B)
     roww = jnp.where(ok_h, rh, 0)
     w = jnp.where(ok_h, db.hist_w, 0.0)
     resp_win = st.resp_win._replace(
-        cur=st.resp_win.cur.at[roww, db.hist_bucket].add(w))
+        cur=st.resp_win.cur.at[roww * B + db.hist_bucket].add(w))
 
     # ---- per-svc distinct-client HLL register maxes (scatter-max)
+    m = 1 << cfg.hll_p_svc
     rs = table.lookup(tbl, db.shll_hi, db.shll_lo, db.shll_valid)
-    ok_s = db.shll_valid & (rs >= 0)
+    ok_s = db.shll_valid & (rs >= 0) & (db.shll_reg < m)
     rank_s = jnp.where(ok_s, db.shll_rank, 0)
     svc_hll = st.svc_hll._replace(
-        regs=st.svc_hll.regs.at[jnp.where(ok_s, rs, 0),
-                                db.shll_reg].max(rank_s))
+        regs=st.svc_hll.regs.at[jnp.where(ok_s, rs, 0) * m
+                                + db.shll_reg].max(rank_s))
 
     # ---- global flow HLL register maxes
     rank_g = jnp.where(db.ghll_valid, db.ghll_rank, 0)

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from gyeeta_tpu.alerts import AlertManager
-from gyeeta_tpu.engine.aggstate import EngineCfg
+from gyeeta_tpu.engine.aggstate import EngineCfg, flat_widths
 from gyeeta_tpu.hostingest import SECTION_COUNTERS, HostIngest, \
     section_builders
 from gyeeta_tpu.ingest import decode, native, wire
@@ -1134,7 +1134,9 @@ class ShardedRuntime(HostIngest):
         self._cols.clear()
         self._td_dirty = True
         self._pressure = None
-        state_np, extra = ckpt.restore(path, self.cfg, self.state)
+        state_np, extra = ckpt.restore(
+            path, self.cfg, self.state,
+            flat_widths=flat_widths(self.cfg))
         # re-shard every leaf with its live counterpart's sharding (the
         # checkpoint stores gathered host arrays; shapes were already
         # validated against this mesh's stacked geometry)

@@ -20,8 +20,8 @@ from gyeeta_tpu.engine.aggstate import (
     AggState, EngineCfg, CTR_BYTES_SENT, CTR_BYTES_RCVD, CTR_NCONN_CLOSED,
     CTR_DUR_SUM_US,
 )
-from gyeeta_tpu.sketch import countmin, hyperloglog as hll, loghist, \
-    tdigest, topk, windows
+from gyeeta_tpu.sketch import countmin, flat, hyperloglog as hll, \
+    loghist, tdigest, topk, windows
 
 DEFAULT_QS = (0.25, 0.5, 0.95, 0.99)
 
@@ -69,7 +69,8 @@ def svc_snapshot(cfg: EngineCfg, st: AggState, level: int = 0):
         "nconn_closed": ctr[:, CTR_NCONN_CLOSED],
         "mean_conn_dur_us": ctr[:, CTR_DUR_SUM_US]
         / jnp.maximum(ctr[:, CTR_NCONN_CLOSED], 1.0),
-        "distinct_clients": hll.estimate(st.svc_hll),
+        "distinct_clients": hll.estimate(hll.rows(st.svc_hll,
+                                                  cfg.hll_p_svc)),
         "stats": st.svc_stats,
     }
 
@@ -106,14 +107,15 @@ def svcstate_vol(cfg: EngineCfg, st: AggState):
     """Query volume from the current 5s slab (one (S, B) pass)."""
     from gyeeta_tpu.ingest.decode import STAT_NQRYS
 
-    nqrys = jnp.maximum(loghist.counts_total(st.resp_win.cur),
-                        st.svc_stats[:, STAT_NQRYS])
+    nqrys = jnp.maximum(
+        loghist.counts_total(windows.cur_rows(st.resp_win)),
+        st.svc_stats[:, STAT_NQRYS])
     return {"nqry5s": nqrys, "qps5s": nqrys / 5.0}
 
 
 @partial(jax.jit, static_argnums=(0,))
 def svcstate_cli(cfg: EngineCfg, st: AggState):
-    return {"nclients": hll.estimate(st.svc_hll)}
+    return {"nclients": hll.estimate(hll.rows(st.svc_hll, cfg.hll_p_svc))}
 
 
 @partial(jax.jit, static_argnums=(0, 2))
@@ -137,12 +139,11 @@ def svcstate_qlevel_rows(cfg: EngineCfg, st: AggState, idx, level: int):
     the (ring + cur) add, so cost scales with len(idx), not capacity.
     ``idx`` is a padded fixed-size int32 array (see api._pad_idx)."""
     qs = jnp.asarray(_QS3, jnp.float32)
-    if level == -1:
-        h = st.resp_win.cur[idx]
-    elif level < len(st.resp_win.totals):
-        h = st.resp_win.totals[level][idx] + st.resp_win.cur[idx]
-    else:
-        h = st.resp_win.alltime[idx] + st.resp_win.cur[idx]
+    h = flat.take_rows(st.resp_win.cur, idx, cfg.resp_spec.nbuckets)
+    if 0 <= level < len(st.resp_win.totals):
+        h = st.resp_win.totals[level][idx] + h
+    elif level >= len(st.resp_win.totals):
+        h = st.resp_win.alltime[idx] + h
     q = loghist.quantiles(h, cfg.resp_spec, qs)
     if level == -1:
         return {"resp5s_us": loghist.mean(h, cfg.resp_spec),
@@ -156,15 +157,17 @@ def svcstate_qlevel_rows(cfg: EngineCfg, st: AggState, idx, level: int):
 def svcstate_vol_rows(cfg: EngineCfg, st: AggState, idx):
     from gyeeta_tpu.ingest.decode import STAT_NQRYS
 
-    nqrys = jnp.maximum(loghist.counts_total(st.resp_win.cur[idx]),
-                        st.svc_stats[idx, STAT_NQRYS])
+    nqrys = jnp.maximum(
+        loghist.counts_total(flat.take_rows(st.resp_win.cur, idx,
+                                            cfg.resp_spec.nbuckets)),
+        st.svc_stats[idx, STAT_NQRYS])
     return {"nqry5s": nqrys, "qps5s": nqrys / 5.0}
 
 
 @partial(jax.jit, static_argnums=(0,))
 def svcstate_cli_rows(cfg: EngineCfg, st: AggState, idx):
     return {"nclients": hll.estimate(
-        st.svc_hll._replace(regs=st.svc_hll.regs[idx]))}
+        hll.take_rows(st.svc_hll, cfg.hll_p_svc, idx))}
 
 
 @partial(jax.jit, static_argnums=(0,))
@@ -174,7 +177,7 @@ def svcstate_snapshot(cfg: EngineCfg, st: AggState):
     (``server/gy_mnodehandle.cc``), one device program for the fleet."""
     spec = cfg.resp_spec
     qs = jnp.asarray((0.5, 0.95, 0.99), jnp.float32)
-    h5 = st.resp_win.cur
+    h5 = windows.cur_rows(st.resp_win)
     h5m = windows.read(st.resp_win, 0)
     h5d = windows.read(st.resp_win, 1)
     q5 = loghist.quantiles(h5, spec, qs)
@@ -198,7 +201,7 @@ def svcstate_snapshot(cfg: EngineCfg, st: AggState):
         "state": st.svc_state,
         "issue": st.svc_issue,
         "hostid": st.svc_host,
-        "nclients": hll.estimate(st.svc_hll),
+        "nclients": hll.estimate(hll.rows(st.svc_hll, cfg.hll_p_svc)),
         "stats": st.svc_stats,
     }
 

@@ -1134,7 +1134,9 @@ class Runtime(HostIngest):
         # drains are bounded): mark dirty so the tick cadence drains it
         self._td_dirty = True
         self._pressures.clear()
-        self.state, extra = ckpt.restore(path, self.cfg, self.state)
+        self.state, extra = ckpt.restore(
+            path, self.cfg, self.state,
+            flat_widths=aggstate.flat_widths(self.cfg))
         # the dep graph is not checkpointed: reset it (edges rebuild from
         # live traffic) and realign the host tick mirror so TTL deltas
         # never go negative

@@ -30,7 +30,7 @@ def signals(cfg: EngineCfg, st: AggState):
     """AggState → (SvcSignals, high_resp_now) over all service rows."""
     spec = cfg.resp_spec
     qs = jnp.asarray(_QS, jnp.float32)
-    h5 = st.resp_win.cur                       # current 5s slab
+    h5 = windows.cur_rows(st.resp_win)         # current 5s slab
     h300 = windows.read(st.resp_win, 0)        # 5 min
     h5day = windows.read(st.resp_win, 1)       # 5 days
     q5 = loghist.quantiles(h5, spec, qs)

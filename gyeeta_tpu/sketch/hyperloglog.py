@@ -6,8 +6,10 @@ sketch: cardinality of distinct peers/flows per service or per host with
 ~1.04/sqrt(2^p) standard error (p=14 → 0.8%).
 
 Register update is a scatter-max; cross-shard merge is elementwise max →
-roll-up over shards is ``lax.pmax``. Supports a leading entity axis so one
-tensor holds a sketch per tracked service row.
+roll-up over shards is ``lax.pmax``. One tensor can hold a sketch per
+tracked service row: such a per-entity sketch is stored flat, (E·m,), and
+its registers are updated at ``row * m + register`` (``sketch/flat.py``);
+readers take the (E, m) view with :func:`rows`.
 """
 
 from __future__ import annotations
@@ -17,18 +19,33 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from gyeeta_tpu.sketch import flat
 from gyeeta_tpu.utils import hashing as H
 
 _HLL_SALT = 0x1F123BB5
 
 
 class HLL(NamedTuple):
-    regs: jnp.ndarray  # (..., m) int32 registers (0..32-p+1)
+    regs: jnp.ndarray  # (m,) int32 registers (0..32-p+1); (E·m,) flat
+    #                    for E entities, (..., E, m) in a row view
 
 
 def init(p: int = 14, entities: tuple = ()) -> HLL:
+    """One sketch, or with ``entities`` = (E,) one per entity, stored
+    flat as (E·m,)."""
     m = 1 << p
-    return HLL(regs=jnp.zeros(entities + (m,), dtype=jnp.int32))
+    n = int(np.prod(entities, dtype=np.int64))
+    return HLL(regs=jnp.zeros((n * m,), dtype=jnp.int32))
+
+
+def rows(sk: HLL, p: int) -> HLL:
+    """The (..., E, m) row view of a flat per-entity sketch."""
+    return HLL(regs=flat.rows(sk.regs, 1 << p))
+
+
+def take_rows(sk: HLL, p: int, idx) -> HLL:
+    """The sketches of entities ``idx`` as (len(idx), m)."""
+    return HLL(regs=flat.take_rows(sk.regs, idx, 1 << p))
 
 
 def _idx_rank(key_hi, key_lo, p: int):
@@ -54,14 +71,15 @@ def update(sk: HLL, key_hi, key_lo, valid=None) -> HLL:
     return HLL(regs=sk.regs.at[idx].max(rank))
 
 
-def update_entities(sk: HLL, entity_row, key_hi, key_lo, valid=None) -> HLL:
-    """Per-entity update: scatter-max at (entity_row, register)."""
-    p = int(np.log2(sk.regs.shape[-1]))
+def update_entities(sk: HLL, p: int, entity_row, key_hi, key_lo,
+                    valid=None) -> HLL:
+    """Per-entity update of a flat (E·m,) sketch: scatter-max at
+    ``entity_row * m + register``."""
     idx, rank = _idx_rank(key_hi, key_lo, p)
     if valid is not None:
         rank = jnp.where(valid, rank, 0)
         entity_row = jnp.where(valid, entity_row, 0)
-    return HLL(regs=sk.regs.at[entity_row, idx].max(rank))
+    return HLL(regs=sk.regs.at[entity_row * (1 << p) + idx].max(rank))
 
 
 def estimate(sk: HLL):

@@ -80,13 +80,16 @@ def update(hist, spec: LogHistSpec, values, weights=None, valid=None):
 
 def update_entities(hist, spec: LogHistSpec, entity_row, values,
                     weights=None, valid=None):
-    """Per-entity scatter-add at (row, bucket)."""
+    """Per-entity scatter-add at (row, bucket) of an (E, B) hist, or at
+    ``row * B + bucket`` of one stored flat, (E·B,) (``sketch/flat.py``)."""
     b = bucket_of(spec, values)
     w = jnp.ones_like(values, dtype=hist.dtype) if weights is None \
         else weights.astype(hist.dtype)
     if valid is not None:
         w = jnp.where(valid, w, jnp.zeros_like(w))
         entity_row = jnp.where(valid, entity_row, 0)
+    if hist.ndim == 1:
+        return hist.at[entity_row * spec.nbuckets + b].add(w)
     return hist.at[entity_row, b].add(w)
 
 

@@ -14,6 +14,11 @@ axes, and runs on the VPU — no data-dependent loop like the CPU original.
 
 State merge is concat+recompress → shard roll-up uses gathered concat
 (all_gather of (C,2) tensors is tiny) rather than psum.
+
+A digest per entity stores its centroids flat, (E·C,), and so does the
+staging buffer, (E·cap,): the flush gathers and writes back the rows it
+compresses at flat indices (``sketch/flat.py``); readers take the row
+view with :func:`rows`.
 """
 
 from __future__ import annotations
@@ -24,21 +29,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gyeeta_tpu.sketch import flat
+
 
 class TDigest(NamedTuple):
-    means: jnp.ndarray    # (..., C) float32, sorted ascending among occupied
-    weights: jnp.ndarray  # (..., C) float32, 0 = empty slot
+    means: jnp.ndarray    # (C,) float32, sorted ascending among occupied;
+    #                       (E·C,) flat for E entities
+    weights: jnp.ndarray  # like means, 0 = empty slot
     vmin: jnp.ndarray     # (...,) float32 observed min (inf if empty)
     vmax: jnp.ndarray     # (...,) float32 observed max (-inf if empty)
 
 
 def init(capacity: int = 128, entities: tuple = ()) -> TDigest:
+    """One digest, or with ``entities`` = (E,) one per entity, its
+    centroids stored flat as (E·C,)."""
+    n = int(np.prod(entities, dtype=np.int64))
     return TDigest(
-        means=jnp.zeros(entities + (capacity,), jnp.float32),
-        weights=jnp.zeros(entities + (capacity,), jnp.float32),
+        means=jnp.zeros((n * capacity,), jnp.float32),
+        weights=jnp.zeros((n * capacity,), jnp.float32),
         vmin=jnp.full(entities, jnp.inf, jnp.float32),
         vmax=jnp.full(entities, -jnp.inf, jnp.float32),
     )
+
+
+def rows(sk: TDigest) -> TDigest:
+    """The (..., E, C) row view of a flat per-entity digest (a single
+    digest is its own view)."""
+    shape = sk.vmin.shape + (-1,)
+    return sk._replace(means=sk.means.reshape(shape),
+                       weights=sk.weights.reshape(shape))
 
 
 def _k1(q, delta):
@@ -89,7 +108,7 @@ def update(sk: TDigest, values, valid=None) -> TDigest:
 
 
 def stage_samples(stage_v, stage_n, rows, values, valid=None):
-    """Append a batch of per-entity samples into a (S, cap) staging
+    """Append a batch of per-entity samples into an S × cap staging
     buffer WITHOUT compressing — the amortization half of the buffered
     merging t-digest (Dunning's merging variant buffers inserts and
     compresses when the buffer fills; here the fold loop stages every
@@ -97,12 +116,14 @@ def stage_samples(stage_v, stage_n, rows, values, valid=None):
     vmapped sort in ``_compress`` is by far the most expensive op in
     the fold — measured 81%% of the full fold cost).
 
-    stage_v: (S, cap) float32 values; stage_n: (S,) int32 fill counts.
-    Returns (stage_v, stage_n, n_overflow). Overflowing samples (entity
-    buffer full) are dropped and counted — the loghist path remains the
-    lossless estimator.
+    stage_v: (S·cap,) float32 values stored flat, slot j of entity s at
+    ``s * cap + j`` (``sketch/flat.py``); stage_n: (S,) int32 fill
+    counts. Returns (stage_v, stage_n, n_overflow). Overflowing samples
+    (entity buffer full) are dropped and counted — the loghist path
+    remains the lossless estimator.
     """
-    S, cap = stage_v.shape
+    S = stage_n.shape[0]
+    cap = stage_v.shape[0] // S
     B = rows.shape[0]
     vals = values.astype(jnp.float32)
     ok = rows >= 0
@@ -122,27 +143,30 @@ def stage_samples(stage_v, stage_n, rows, values, valid=None):
     n_overflow = jnp.sum((r_s < S) & (slot >= cap)).astype(jnp.int32)
     tgt_row = jnp.where(keep, r_s, S)
     tgt_slot = jnp.where(keep, slot, 0)
-    stage_v = stage_v.at[tgt_row, tgt_slot].set(v_s, mode="drop")
+    stage_v = stage_v.at[tgt_row * cap + tgt_slot].set(v_s, mode="drop")
     added = jnp.zeros((S + 1,), jnp.int32).at[tgt_row].add(
         keep.astype(jnp.int32), mode="drop")[:S]
     return stage_v, stage_n + added, n_overflow
 
 
 def flush_staged(sk: TDigest, stage_v, stage_n):
-    """Fold a staging buffer into the per-entity digest in ONE vmapped
-    compression; returns (new_digest, zeroed stage_v, zeroed stage_n)."""
-    S, C = sk.means.shape
-    cap = stage_v.shape[1]
+    """Fold a flat staging buffer into the per-entity digest in ONE
+    vmapped compression over their row views; returns (new_digest,
+    zeroed stage_v, zeroed stage_n)."""
+    r = rows(sk)
+    S, C = r.means.shape
+    cap = stage_v.shape[0] // S
+    stage_r = flat.rows(stage_v, cap)                       # (S, cap)
     occ = jnp.arange(cap)[None, :] < stage_n[:, None]       # (S, cap)
     w_st = occ.astype(jnp.float32)
-    all_m = jnp.concatenate([sk.means, stage_v], axis=-1)
-    all_w = jnp.concatenate([sk.weights, w_st], axis=-1)
+    all_m = jnp.concatenate([r.means, stage_r], axis=-1)
+    all_w = jnp.concatenate([r.weights, w_st], axis=-1)
     new_m, new_w = jax.vmap(_compress, in_axes=(0, 0, None))(all_m, all_w,
                                                              C)
-    v_for_min = jnp.where(occ, stage_v, jnp.inf)
-    v_for_max = jnp.where(occ, stage_v, -jnp.inf)
+    v_for_min = jnp.where(occ, stage_r, jnp.inf)
+    v_for_max = jnp.where(occ, stage_r, -jnp.inf)
     return TDigest(
-        means=new_m, weights=new_w,
+        means=new_m.reshape(-1), weights=new_w.reshape(-1),
         vmin=jnp.minimum(sk.vmin, v_for_min.min(axis=-1)),
         vmax=jnp.maximum(sk.vmax, v_for_max.max(axis=-1)),
     ), jnp.zeros_like(stage_v), jnp.zeros_like(stage_n)
@@ -162,16 +186,22 @@ def flush_staged_topm(sk: TDigest, stage_v, stage_n, m: int):
     counts; rows with zero staged samples pass through untouched.
 
     Returns (new_digest, stage_v, stage_n) with the flushed rows' stage
-    cleared.
+    cleared. The flat digest and stage are gathered and written back
+    at the selected rows' own slots: a row view of the whole (the
+    stage alone is 128 MiB at north-star geometry) would relayout it
+    on every flush.
     """
-    S, C = sk.means.shape
-    cap = stage_v.shape[1]
+    S = stage_n.shape[0]
+    C = sk.means.shape[0] // S
+    cap = stage_v.shape[0] // S
     m = min(m, S)
     nsel, idx = jax.lax.top_k(stage_n, m)              # (m,)
     occ = jnp.arange(cap)[None, :] < nsel[:, None]     # (m, cap)
-    sel_means = sk.means[idx]
-    sel_weights = sk.weights[idx]
-    sel_stage = stage_v[idx]
+    cents = flat.row_lanes(idx, C)                     # (m, C)
+    sel_means = sk.means[cents]
+    sel_weights = sk.weights[cents]
+    lanes = flat.row_lanes(idx, cap)                   # (m, cap)
+    sel_stage = stage_v[lanes]
     all_m = jnp.concatenate([sel_means, sel_stage], axis=-1)
     all_w = jnp.concatenate([sel_weights, occ.astype(jnp.float32)],
                             axis=-1)
@@ -185,18 +215,19 @@ def flush_staged_topm(sk: TDigest, stage_v, stage_n, m: int):
     v_for_min = jnp.where(occ, sel_stage, jnp.inf)
     v_for_max = jnp.where(occ, sel_stage, -jnp.inf)
     return TDigest(
-        means=sk.means.at[idx].set(new_m),
-        weights=sk.weights.at[idx].set(new_w),
+        means=sk.means.at[cents].set(new_m),
+        weights=sk.weights.at[cents].set(new_w),
         vmin=sk.vmin.at[idx].min(v_for_min.min(axis=-1)),
         vmax=sk.vmax.at[idx].max(v_for_max.max(axis=-1)),
-    ), stage_v.at[idx].set(0.0), stage_n.at[idx].set(0)
+    ), stage_v.at[lanes].set(0.0), stage_n.at[idx].set(0)
 
 
 def merge(a: TDigest, b: TDigest) -> TDigest:
-    capacity = a.means.shape[-1]
-    all_m = jnp.concatenate([a.means, b.means], axis=-1)
-    all_w = jnp.concatenate([a.weights, b.weights], axis=-1)
-    if a.means.ndim == 1:
+    ra, rb = rows(a), rows(b)
+    capacity = ra.means.shape[-1]
+    all_m = jnp.concatenate([ra.means, rb.means], axis=-1)
+    all_w = jnp.concatenate([ra.weights, rb.weights], axis=-1)
+    if a.vmin.ndim == 0:
         new_m, new_w = _compress(all_m, all_w, capacity)
     else:
         flat_m = all_m.reshape(-1, all_m.shape[-1])
@@ -256,14 +287,16 @@ def quantiles(sk: TDigest, qs):
 
 
 def quantiles_entities(sk: TDigest, qs):
-    """Vmapped quantiles over a (S, C) entity-axis digest → (S, Q)."""
+    """Vmapped quantiles over a per-entity digest's rows → (S, Q)."""
+    r = rows(sk)
     return jax.vmap(
         lambda m, w, vn, vx: quantiles(TDigest(m, w, vn, vx), qs),
-        in_axes=(0, 0, 0, 0))(sk.means, sk.weights, sk.vmin, sk.vmax)
+        in_axes=(0, 0, 0, 0))(r.means, r.weights, r.vmin, r.vmax)
 
 
 def count(sk: TDigest):
-    return sk.weights.sum(axis=-1)
+    """Total weight, per entity for a per-entity digest."""
+    return rows(sk).weights.sum(axis=-1)
 
 
 # ---------------------------------------------------------------- numpy ref

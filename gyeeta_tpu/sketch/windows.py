@@ -50,7 +50,9 @@ LEVELS_DEFAULT: tuple[WindowSpec, ...] = (
 class MultiWindow(NamedTuple):
     """Windowed view of one additive state array of shape ``shape``.
 
-    cur:    (shape) slab being filled this base tick
+    cur:    (shape) slab being filled this base tick; may be stored flat
+            (a fold scatter target, ``sketch/flat.py``): ``cur_rows``
+            gives it in ``shape``
     rings:  tuple of (nslots, *shape) per level
     totals: tuple of (shape) rolling per-level totals
     alltime:(shape) lifetime accumulator
@@ -75,6 +77,11 @@ def init(shape: tuple, levels: Sequence[WindowSpec] = LEVELS_DEFAULT,
     )
 
 
+def cur_rows(win: MultiWindow):
+    """The current slab in the window's shape (a view of a flat ``cur``)."""
+    return win.cur.reshape(win.alltime.shape)
+
+
 def add(win: MultiWindow, delta) -> MultiWindow:
     """Accumulate into the current base slab (called per microbatch)."""
     return win._replace(cur=win.cur + delta)
@@ -84,6 +91,7 @@ def tick(win: MultiWindow, levels: Sequence[WindowSpec] = LEVELS_DEFAULT
          ) -> MultiWindow:
     """Close the current base slab: fold into every level, advance rings."""
     t = win.tick
+    cur = cur_rows(win)
     new_rings = []
     new_totals = []
     for lv, ring, total in zip(levels, win.rings, win.totals):
@@ -91,29 +99,30 @@ def tick(win: MultiWindow, levels: Sequence[WindowSpec] = LEVELS_DEFAULT
         boundary = (t % lv.stride_ticks) == 0
         # at a stride boundary the slab at `slot` expires and is replaced
         ring = ring.at[slot].set(
-            jnp.where(boundary, win.cur, ring[slot] + win.cur))
+            jnp.where(boundary, cur, ring[slot] + cur))
         # resync the rolling total from the ring at each boundary: float32
         # add/subtract drift would otherwise accumulate over the 5-day
         # level's 86,400 ticks (ADVICE r1). Off-boundary: cheap increment.
-        total = jnp.where(boundary, ring.sum(axis=0), total + win.cur)
+        total = jnp.where(boundary, ring.sum(axis=0), total + cur)
         new_rings.append(ring)
         new_totals.append(total)
     return MultiWindow(
         cur=jnp.zeros_like(win.cur),
         rings=tuple(new_rings),
         totals=tuple(new_totals),
-        alltime=win.alltime + win.cur,
+        alltime=win.alltime + cur,
         tick=t + 1,
     )
 
 
 def read(win: MultiWindow, level: int):
     """Windowed sum for a level: -1 = current base slab, len(levels) = all."""
+    cur = cur_rows(win)
     if level == -1:
-        return win.cur
+        return cur
     if level < len(win.totals):
-        return win.totals[level] + win.cur
-    return win.alltime + win.cur
+        return win.totals[level] + cur
+    return win.alltime + cur
 
 
 # ---------------------------------------------------------------- numpy ref

@@ -86,27 +86,36 @@ def sweep_stale_tmp(ckpt_dir) -> int:
     return n
 
 
-def restore(path, cfg, like):
+def restore(path, cfg, like, flat_widths: dict | None = None):
     """Read a checkpoint into the structure of ``like`` (same treedef).
 
-    Raises ValueError on config-fingerprint or leaf-shape mismatch.
+    ``flat_widths`` maps the path of each leaf stored flat, (..., S·W),
+    to its row width W (``aggstate.flat_widths``): a checkpoint that
+    holds such a leaf in its logical (..., S, W) shape, as one written
+    before the leaf was stored flat, is reshaped on load. Raises
+    ValueError on config-fingerprint or any other leaf-shape mismatch.
     Returns (state, extra_dict).
     """
     path = pathlib.Path(path)
+    flat_widths = flat_widths or {}
     with np.load(path) as z:
         meta = json.loads(bytes(z["__meta__"]).decode())
         if meta["cfg"] != _cfg_fingerprint(cfg):
             raise ValueError(
                 f"checkpoint config fingerprint {meta['cfg']} does not "
                 f"match engine config {_cfg_fingerprint(cfg)}")
-        leaves, treedef = jax.tree_util.tree_flatten(like)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(like)
         if meta["nleaves"] != len(leaves):
             raise ValueError(
                 f"checkpoint has {meta['nleaves']} leaves, engine state "
                 f"has {len(leaves)} — incompatible versions")
         new_leaves = []
-        for i, ref in enumerate(leaves):
+        for i, (kp, ref) in enumerate(leaves):
             arr = z[f"leaf_{i}"]
+            w = flat_widths.get(
+                jax.tree_util.keystr(kp, simple=True, separator="."))
+            if w and arr.shape == ref.shape[:-1] + (ref.shape[-1] // w, w):
+                arr = arr.reshape(ref.shape)
             if arr.shape != ref.shape:
                 raise ValueError(
                     f"leaf {i}: checkpoint shape {arr.shape} != "

@@ -25,7 +25,7 @@ from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.ingest import pack, wire
 from gyeeta_tpu.parallel import depgraph as dg
 from gyeeta_tpu.sim.partha import ParthaSim
-from gyeeta_tpu.sketch import loghist
+from gyeeta_tpu.sketch import loghist, windows
 
 
 def _small_cfg() -> EngineCfg:
@@ -364,7 +364,7 @@ def test_staging_buffer_not_rewritten_under_a_fold_in_flight():
         key = (np.asarray(rt.state.tbl.key_hi).astype(np.uint64)
                << np.uint64(32)) | np.asarray(rt.state.tbl.key_lo)
         row_of = {int(k): r for r, k in enumerate(key)}
-        got = np.asarray(rt.state.resp_win.cur).sum(axis=1)
+        got = np.asarray(windows.cur_rows(rt.state.resp_win)).sum(axis=1)
         assert [got[row_of[int(i)]] for i in ids] == want.tolist()
     finally:
         rt.close()

@@ -23,7 +23,7 @@ from gyeeta_tpu.ingest import wire
 from gyeeta_tpu.runtime import Runtime
 from gyeeta_tpu.sim.partha import ParthaSim
 from gyeeta_tpu.sketch import edgefold as EF
-from gyeeta_tpu.sketch import loghist
+from gyeeta_tpu.sketch import hyperloglog as hll, loghist, windows
 
 
 def _cfg(**over) -> EngineCfg:
@@ -75,15 +75,16 @@ def _assert_parity(rtA, rtB, keys64, resid: float, rtol=1e-5):
     # BIT-identical, both tiers
     assert np.array_equal(np.asarray(sA.glob_hll.regs),
                           np.asarray(sB.glob_hll.regs))
-    assert np.array_equal(np.asarray(sA.svc_hll.regs)[ra],
-                          np.asarray(sB.svc_hll.regs)[rb])
+    p = rtA.cfg.hll_p_svc
+    assert np.array_equal(np.asarray(hll.rows(sA.svc_hll, p).regs)[ra],
+                          np.asarray(hll.rows(sB.svc_hll, p).regs)[rb])
     # loghist bucket counts: integer scatter-adds — exact totals per
     # svc; individual samples sitting ON a bucket boundary may round
     # into the neighbor bucket (host-numpy vs XLA transcendental 1-ulp
     # differences in bucket_of; ~1e-5 of samples, within the spec's
     # stated quantile error), so allow a tiny flip budget
-    ha = np.asarray(sA.resp_win.cur)[ra]
-    hb = np.asarray(sB.resp_win.cur)[rb]
+    ha = np.asarray(windows.cur_rows(sA.resp_win))[ra]
+    hb = np.asarray(windows.cur_rows(sB.resp_win))[rb]
     np.testing.assert_array_equal(ha.sum(axis=1), hb.sum(axis=1))
     flips = float(np.abs(ha - hb).sum()) / 2
     assert flips <= max(2.0, 1e-4 * ha.sum()), flips

@@ -103,9 +103,9 @@ def test_hll_per_entity(rng):
         hi = rng.integers(0, 2**32, per * (e + 1), dtype=np.uint32)
         lo = rng.integers(0, 2**32, per * (e + 1), dtype=np.uint32)
         rows = np.full(hi.shape, e, np.int32)
-        sk = hll.update_entities(sk, jnp.asarray(rows), jnp.asarray(hi),
-                                 jnp.asarray(lo))
-    est = np.asarray(hll.estimate(sk))
+        sk = hll.update_entities(sk, 12, jnp.asarray(rows),
+                                 jnp.asarray(hi), jnp.asarray(lo))
+    est = np.asarray(hll.estimate(hll.rows(sk, 12)))
     for e in range(n_ent):
         true_n = per * (e + 1)
         assert abs(est[e] - true_n) / true_n < 0.1

@@ -9,7 +9,7 @@ from gyeeta_tpu.engine import aggstate, step
 from gyeeta_tpu.engine.aggstate import EngineCfg
 from gyeeta_tpu.ingest import decode
 from gyeeta_tpu.sim.partha import ParthaSim
-from gyeeta_tpu.sketch import exact, tdigest
+from gyeeta_tpu.sketch import exact, flat, tdigest
 
 
 def _np_stage(S, cap, stage_v, stage_n, rows, vals):
@@ -40,8 +40,9 @@ def test_stage_samples_matches_reference():
     vals = rng.random(B).astype(np.float32) * 50
 
     got_v, got_n, got_over = jax.jit(tdigest.stage_samples)(
-        jnp.asarray(stage_v), jnp.asarray(stage_n),
+        jnp.asarray(stage_v.reshape(-1)), jnp.asarray(stage_n),
         jnp.asarray(rows), jnp.asarray(vals))
+    got_v = flat.rows(np.asarray(got_v), cap)
     ref_v, ref_n, ref_over = _np_stage(S, cap, stage_v, stage_n,
                                        rows, vals)
     np.testing.assert_array_equal(np.asarray(got_n), ref_n)
@@ -50,7 +51,7 @@ def test_stage_samples_matches_reference():
     # lanes follows the sort); compare as per-entity multisets
     for s in range(S):
         np.testing.assert_allclose(
-            np.sort(np.asarray(got_v)[s, : ref_n[s]]),
+            np.sort(got_v[s, : ref_n[s]]),
             np.sort(ref_v[s, : ref_n[s]]), rtol=1e-6)
 
 
@@ -68,15 +69,16 @@ def test_flush_staged_quantiles_and_counts():
         stage_n[s] = n
         all_vals[s] = vals
     sk2, zv, zn = jax.jit(tdigest.flush_staged)(
-        sk, jnp.asarray(stage_v), jnp.asarray(stage_n))
+        sk, jnp.asarray(stage_v.reshape(-1)), jnp.asarray(stage_n))
     assert int(np.asarray(zn).sum()) == 0
     assert float(np.asarray(zv).sum()) == 0.0
     cnt = np.asarray(tdigest.count(sk2))
+    r2 = tdigest.rows(sk2)
     for s in range(S):
         assert cnt[s] == stage_n[s]
         q = np.asarray(tdigest.quantiles(
-            tdigest.TDigest(sk2.means[s], sk2.weights[s],
-                            sk2.vmin[s], sk2.vmax[s]),
+            tdigest.TDigest(r2.means[s], r2.weights[s],
+                            r2.vmin[s], r2.vmax[s]),
             jnp.array([0.5, 0.95])))
         ex = exact.quantiles(np.asarray(all_vals[s], np.float64),
                              (0.5, 0.95))
@@ -106,7 +108,8 @@ def test_flush_staged_topm_partial_and_iterative_drain():
         stage_n[s] = n
         vals_of[s] = v
     jfp = jax.jit(tdigest.flush_staged_topm, static_argnums=(3,))
-    sk1, sv1, sn1 = jfp(sk, jnp.asarray(stage_v), jnp.asarray(stage_n), m)
+    sk1, sv1, sn1 = jfp(sk, jnp.asarray(stage_v.reshape(-1)),
+                        jnp.asarray(stage_n), m)
     # exactly the m fullest entities flushed + cleared; others untouched
     fullest = sorted(active, key=lambda s: -stage_n[s])[:m]
     sn1 = np.asarray(sn1)
@@ -118,25 +121,27 @@ def test_flush_staged_topm_partial_and_iterative_drain():
         else:
             assert sn1[s] == stage_n[s]
             assert cnt1[s] == 0
-            np.testing.assert_array_equal(np.asarray(sv1)[s],
-                                          stage_v[s])
+            np.testing.assert_array_equal(
+                flat.rows(np.asarray(sv1), cap)[s], stage_v[s])
     # iterative drain (the td_drain loop) must converge and match the
     # one-shot full flush in mass and quantiles
-    sk_i, sv_i, sn_i = sk, jnp.asarray(stage_v), jnp.asarray(stage_n)
+    sk_i, sv_i, sn_i = (sk, jnp.asarray(stage_v.reshape(-1)),
+                        jnp.asarray(stage_n))
     iters = 0
     while int(jnp.max(sn_i)) > 0:
         sk_i, sv_i, sn_i = jfp(sk_i, sv_i, sn_i, m)
         iters += 1
         assert iters <= -(-len(active) // m) + 1
     sk_full, _, _ = jax.jit(tdigest.flush_staged)(
-        sk, jnp.asarray(stage_v), jnp.asarray(stage_n))
+        sk, jnp.asarray(stage_v.reshape(-1)), jnp.asarray(stage_n))
     np.testing.assert_allclose(np.asarray(tdigest.count(sk_i)),
                                np.asarray(tdigest.count(sk_full)),
                                rtol=1e-6)
+    r_i = tdigest.rows(sk_i)
     for s in active:
         q_i = np.asarray(tdigest.quantiles(
-            tdigest.TDigest(sk_i.means[s], sk_i.weights[s],
-                            sk_i.vmin[s], sk_i.vmax[s]),
+            tdigest.TDigest(r_i.means[s], r_i.weights[s],
+                            r_i.vmin[s], r_i.vmax[s]),
             jnp.array([0.5, 0.95])))
         ex = exact.quantiles(np.asarray(vals_of[s], np.float64),
                              (0.5, 0.95))
@@ -225,9 +230,10 @@ def test_fold_many_digest_accuracy(stride):
             st.tbl, np.array([gid >> np.uint64(32)], np.uint32),
             np.array([gid & np.uint64(0xFFFFFFFF)], np.uint32)))[0])
         assert row >= 0
+        td = tdigest.rows(st.svc_td)
         q = np.asarray(tdigest.quantiles(
-            tdigest.TDigest(st.svc_td.means[row], st.svc_td.weights[row],
-                            st.svc_td.vmin[row], st.svc_td.vmax[row]),
+            tdigest.TDigest(td.means[row], td.weights[row],
+                            td.vmin[row], td.vmax[row]),
             jnp.array([0.5, 0.95])))
         exs = exact.quantiles(svals, (0.5, 0.95))
         assert abs(q[0] - exs[0]) / exs[0] < 0.12, (gid, q[0], exs[0])

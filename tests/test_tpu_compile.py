@@ -26,6 +26,7 @@ held under the chip's 16 GB::
 """
 
 import os
+import re
 import time
 
 import jax
@@ -41,7 +42,8 @@ from gyeeta_tpu.ingest import decode, pack, wire
 from gyeeta_tpu.parallel import depgraph as dg
 from gyeeta_tpu.parallel import rollup, sharded
 from gyeeta_tpu.semantic import derive
-from gyeeta_tpu.sketch import countmin, hyperloglog as hll, loghist, topk
+from gyeeta_tpu.sketch import countmin, hyperloglog as hll, loghist, \
+    tdigest, topk
 
 # the fleet-50k geometry (BASELINE.json, ROADMAP R1, chip_smoke.py)
 FLEET = EngineCfg(svc_capacity=131072, n_hosts=50048,
@@ -51,9 +53,18 @@ DEP_PAIRS, DEP_EDGES = 65536, 524288
 # the edge capacity (which is also the roll-up's merge capacity) do not
 FLEET_SHARD = FLEET._replace(svc_capacity=FLEET.svc_capacity // 4,
                              task_capacity=FLEET.task_capacity // 4)
+# the benchmark's fleet-50k deployment: the service slab halved
+FLEET_50K = FLEET._replace(svc_capacity=65536)
 CONN_LANES = FLEET.fold_k * FLEET.conn_batch      # 32,768
 RESP_LANES = FLEET.fold_k * FLEET.resp_batch      # 65,536
 HBM_BYTES = 16e9                                  # one v5e chip
+# a copy, reshape or transpose this large in a fold program is a
+# relayout of per-service state: the fold scatters into flat targets
+# (sketch/flat.py) so that the chip's tiling of the state never changes
+RELAYOUT_BYTES = 8 << 20
+# the slab fold's temporaries at fleet-50k (compile-only: 176.8 MiB
+# with flat targets, 350.4 MiB with the 2-D scatter targets relaid out)
+SLAB_TEMP_BYTES = 200 << 20
 
 
 @pytest.fixture(scope="module")
@@ -144,14 +155,34 @@ def _resp(lanes, k=None):
 
 def _compile(fn, *args, donate=()):
     """Compile for the described chip → (device bytes the program needs
-    beyond what it aliases, seconds, memory analysis)."""
+    beyond what it aliases, seconds, memory analysis, the executable)."""
     t0 = time.perf_counter()
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     secs = time.perf_counter() - t0
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    return need, secs, m
+    return need, secs, m, compiled
+
+
+_ITEM = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+         "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+         "f64": 8}
+_LAYOUT_OP = re.compile(
+    r"(\S+) = (\w+)\[([\d,]*)\]\S* (copy|reshape|transpose)\(")
+
+
+def _relayouts(compiled):
+    """Every copy, reshape or transpose of the optimized program whose
+    result is ``RELAYOUT_BYTES`` or more, as "name dtype[shape]"."""
+    big = []
+    for name, dt, dims, _ in _LAYOUT_OP.findall(compiled.as_text()):
+        n = _ITEM.get(dt, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        if n >= RELAYOUT_BYTES:
+            big.append(f"{name} {dt}[{dims}]")
+    return big
 
 
 def _report(name, need, secs, m):
@@ -186,8 +217,18 @@ def _c_svc_hll(one):
     cb = _on(_conn(CONN_LANES), one)
     rows = jax.ShapeDtypeStruct((CONN_LANES,), jnp.int32, sharding=one)
     return (lambda s, r, hi, lo, ok: hll.update_entities(
-        s, r, hi, lo, valid=ok),
+        s, FLEET.hll_p_svc, r, hi, lo, valid=ok),
         (st.svc_hll, rows, cb.cli_hi, cb.cli_lo, cb.valid), (0,))
+
+
+def _c_td_stage(one):
+    # the digest's duty cycle stages one resp lane in td_sample_stride
+    st = _on(_state(FLEET_50K), one)
+    lanes = RESP_LANES // FLEET.td_sample_stride
+    rows = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one)
+    vals = jax.ShapeDtypeStruct((lanes,), jnp.float32, sharding=one)
+    return (tdigest.stage_samples,
+            (st.td_stage, st.td_stage_n, rows, vals), (0, 1))
 
 
 def _c_cms(one):
@@ -212,22 +253,30 @@ def _c_td_flush(one):
             (_on(_state(FLEET), one),), (0,))
 
 
+# the components that write per-service state the slab fold (or the
+# flush after it) scatters into: none may relayout that state
+_FLAT_TARGETS = (_c_loghist, _c_svc_hll, _c_td_stage, _c_td_flush)
+
+
 @pytest.mark.parametrize("build", [
-    _c_upsert, _c_loghist, _c_svc_hll, _c_cms, _c_topk, _c_td_flush],
-    ids=lambda f: f.__name__[3:])
+    _c_upsert, _c_loghist, _c_svc_hll, _c_cms, _c_topk, _c_td_flush,
+    _c_td_stage], ids=lambda f: f.__name__[3:])
 def test_fold_component_compiles_for_v5e(one_chip, tpu_branches, build):
     """The fold's components at fleet widths: the 131,072-row upsert,
     the loghist / per-service HLL / CMS scatters, the top-K grouping
     sort at ``topk_budget`` lanes (the accelerator branch), the partial
-    t-digest flush."""
+    t-digest flush, the digest stage at fleet-50k. The scatters into
+    per-service state, and the flush, relayout none of it."""
     fn, args, donate = build(one_chip)
-    need, secs, m = _compile(fn, *args, donate=donate)
+    need, secs, m, compiled = _compile(fn, *args, donate=donate)
     _report(build.__name__[3:], need, secs, m)
     assert need < HBM_BYTES
+    if build in _FLAT_TARGETS:
+        assert _relayouts(compiled) == []
 
 
 # --------------------------------------------- the served path, whole
-def _p_fold_slab(one):
+def _p_fold_slab(one, cfg=FLEET):
     # the production fused dispatch, as Runtime._get_fold_all builds it
     # for the conn/resp K-slab: the 22 columns arrive as one packed
     # word block and the fold's first ops take them apart
@@ -237,9 +286,9 @@ def _p_fold_slab(one):
     block = jax.ShapeDtypeStruct((pack.offsets(layout)[1],), np.uint32,
                                  sharding=one)
     return (lambda s, d, b: step.fold_all(
-        FLEET, s, d, 0, connresp=jax.tree.unflatten(
+        cfg, s, d, 0, connresp=jax.tree.unflatten(
             treedef, pack.unpack(b, layout))),
-        (_on(_state(FLEET), one), _on(_dep(), one), block), (0, 1))
+        (_on(_state(cfg), one), _on(_dep(), one), block), (0, 1))
 
 
 def _p_fold_sweep(one):
@@ -281,9 +330,25 @@ def test_served_program_fits_one_v5e(one_chip, tpu_branches, build):
     (One program at a time: what ELSE the process keeps on the device —
     the published snapshot — is not in this count; see PERF.md.)"""
     fn, args, donate = build(one_chip)
-    need, secs, m = _compile(fn, *args, donate=donate)
+    need, secs, m, _ = _compile(fn, *args, donate=donate)
     _report(build.__name__[3:], need, secs, m)
     assert need < HBM_BYTES
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cfg", [FLEET_50K, FLEET],
+                         ids=lambda c: f"S{c.svc_capacity}")
+def test_slab_fold_relayouts_no_state(one_chip, tpu_branches, cfg):
+    """The slab fold scatters into the per-service HLL registers, the
+    resp histogram's current slab and the digest stage at flat indices,
+    so the program copies, reshapes or transposes none of them (a 2-D
+    scatter target costs a relayout into the flat tiling and back on
+    every slab), and its temporaries stay small."""
+    fn, args, donate = _p_fold_slab(one_chip, cfg)
+    need, secs, m, compiled = _compile(fn, *args, donate=donate)
+    _report(f"fold_slab S={cfg.svc_capacity}", need, secs, m)
+    assert _relayouts(compiled) == []
+    assert m.temp_size_in_bytes < SLAB_TEMP_BYTES
 
 
 # ------------------------------------------------ four chips, one program
